@@ -1,13 +1,14 @@
 """B3 — data-plane kernel microbenchmarks.
 
-On this CPU container the Pallas kernels execute in interpret mode
-(correctness path); their wall time is NOT the TPU number. We therefore
+Off the chip (``JAX_PLATFORMS=cpu``) the Pallas kernel executes in
+interpret mode (the correctness path) and its wall time is NOT a TPU
+number; on a TPU ``ops`` compiles it. Off the chip we therefore
 benchmark (a) the jnp oracle under jit — the CPU stand-in whose data
-movement matches the kernel — at full size, and (b) the Pallas kernels in
+movement matches the kernel — at full size, and (b) the Pallas kernel in
 interpret mode at reduced size to document the validation cost. The
 structural VMEM analysis (the 2-D grid plan of ``kernels/gridplan.py``
-against the ~16 MiB budget) is printed alongside; TPU wall-clock belongs
-to the roofline table.
+against the ~16 MiB budget) is printed alongside; device time is only
+ever measured on the chip.
 
 Every bandwidth row is also scored against the AQP-kernel roofline
 (:func:`repro.launch.roofline.aqp_kernel_roofline`): these kernels do
@@ -31,7 +32,7 @@ import jax
 import numpy as np
 
 from repro.kernels import ops
-from repro.kernels.gridplan import (DEFAULT_BLOCK_ROWS, LANES, VMEM_BUDGET,
+from repro.kernels.gridplan import (DEFAULT_BLOCK_ROWS, VMEM_BUDGET,
                                     plan_cell_groups, vmem_bytes)
 from repro.launch.roofline import aqp_kernel_roofline
 
@@ -190,13 +191,11 @@ def main():
 
     # --- structural VMEM sizing of the 2-D grid plan ---
     group, n_groups, _ = plan_cell_groups(n_seg, 4)
-    vmem = vmem_bytes(DEFAULT_BLOCK_ROWS, group * 4,
-                      param_floats=group * 8)
+    vmem = vmem_bytes(DEFAULT_BLOCK_ROWS, group * 4)
     emit("fused_select_vmem_per_program", 0.0,
          f"bytes={vmem};group={group};n_groups={n_groups}"
          f";fits_16MiB={vmem < VMEM_BUDGET}")
-    vmem = 3 * DEFAULT_BLOCK_ROWS * LANES * 4 + 4 * DEFAULT_BLOCK_ROWS * \
-        LANES
+    vmem = vmem_bytes(DEFAULT_BLOCK_ROWS, 1, n_planes=3)
     emit("window_agg_vmem_per_step", 0.0,
          f"bytes={vmem};fits_16MiB={vmem < 16*2**20}")
     return None

@@ -26,6 +26,8 @@ import time
 
 
 def main(smoke: bool = False) -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     # smoke config must land BEFORE the benchmark modules bind their
     # imported constants
     from . import common

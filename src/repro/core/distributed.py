@@ -73,7 +73,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..kernels import fused_select as fsel
 from .bounds import (AccuracyPolicy, HeatmapResult, QueryResult,
@@ -313,8 +312,8 @@ def _init_state_raw(mesh: Mesh, cfg: DistConfig):
             vmin=vmin, vmax=vmax, n_tiles=jnp.int32(t))
 
     obj = P(axes)
-    return shard_map(local, mesh=mesh, in_specs=(obj, obj, obj, P()),
-                     out_specs=_state_specs(axes), check_rep=False)
+    return jax.shard_map(local, mesh=mesh, in_specs=(obj, obj, obj, P()),
+                         out_specs=_state_specs(axes), check_vma=False)
 
 
 def make_init_state(mesh: Mesh, cfg: DistConfig = DistConfig()):
@@ -402,10 +401,10 @@ def _session_query_raw(mesh: Mesh, cfg: DistConfig, fused: bool = True):
     obj = P(axes)
     keys = ("value", "lo", "hi", "bound", "budget_bound", "n_processed",
             "n_partial", "n_full", "objects_read", "sel")
-    return shard_map(local, mesh=mesh,
-                     in_specs=(_state_specs(axes), obj, obj, obj, P(),
-                               P()),
-                     out_specs={k: P() for k in keys}, check_rep=False)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(_state_specs(axes), obj, obj, obj, P(),
+                                   P()),
+                         out_specs={k: P() for k in keys}, check_vma=False)
 
 
 def make_session_query_step(mesh: Mesh, cfg: DistConfig = DistConfig(),
@@ -646,11 +645,12 @@ def _session_heatmap_raw(mesh: Mesh, cfg: DistConfig,
     keys = ("values", "lo", "hi", "bin_bound", "bound", "budget_bound",
             "bin_met", "bin_count", "n_processed", "n_partial",
             "n_cached", "n_full", "objects_read", "sel")
-    return shard_map(local, mesh=mesh,
-                     in_specs=(_state_specs(axes), _cache_specs(), obj,
-                               obj, obj, P(), P(), P(), P()),
-                     out_specs=({k: P() for k in keys}, _cache_specs()),
-                     check_rep=False)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(_state_specs(axes), _cache_specs(), obj,
+                                   obj, obj, P(), P(), P(), P()),
+                         out_specs=({k: P() for k in keys},
+                                    _cache_specs()),
+                         check_vma=False)
 
 
 def make_session_heatmap_step(mesh: Mesh, cfg: DistConfig,
@@ -716,17 +716,21 @@ def _refine_epoch_raw(mesh: Mesh, cfg: DistConfig,
 
         # shard-local cell-id rewrite: objects of split parents move to
         # their child's fresh table row (the cracking step)
-        eq = (state.cell[:, None] == parents[None, :]) & slot_ok[None, :]
-        has = eq.any(axis=1)
-        j = jnp.argmax(eq, axis=1)                  # parent slot per object
-        xe_j = xe[j]                                # (n, gx+1)
-        ye_j = ye[j]
+        # (per-slot loops over flat (n,) arrays: an (n, K) or (n, gx+1)
+        # intermediate pads its minor axis to 128 lanes on a TPU — 51 GB
+        # at 10^8 objects; parents are distinct, so ≤ 1 slot matches)
+        has = jnp.zeros(xs.shape, bool)
+        j = jnp.zeros(xs.shape, jnp.int32)          # parent slot per object
+        for s in range(kk):
+            m = (state.cell == parents[s]) & slot_ok[s]
+            has = has | m
+            j = jnp.where(m, s, j)
         cx = jnp.zeros(xs.shape, jnp.int32)
         for i in range(1, gx):
-            cx = cx + (xs >= xe_j[:, i]).astype(jnp.int32)
+            cx = cx + (xs >= xe[:, i][j]).astype(jnp.int32)
         cy = jnp.zeros(ys.shape, jnp.int32)
         for i in range(1, gy):
-            cy = cy + (ys >= ye_j[:, i]).astype(jnp.int32)
+            cy = cy + (ys >= ye[:, i][j]).astype(jnp.int32)
         child = cy * gx + cx
         new_cell = jnp.where(
             has, state.n_tiles + j * k + child, state.cell).astype(
@@ -784,12 +788,12 @@ def _refine_epoch_raw(mesh: Mesh, cfg: DistConfig,
         return new_state, info
 
     obj = P(axes)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(_state_specs(axes), obj, obj, obj, P(), P()),
         out_specs=(_state_specs(axes),
                    {"n_split": P(), "objects_reorganized": P()}),
-        check_rep=False)
+        check_vma=False)
 
 
 def make_refine_epoch(mesh: Mesh, cfg: DistConfig,

@@ -81,7 +81,8 @@ class IndexConfig:
 
     def max_split_cells(self) -> int:
         """Upper bound on children per split — sizes the packed split
-        kernels' static unroll budget (``MAX_UNROLL``) in the driver."""
+        kernels' per-program cell budget (``MAX_GROUP_CELLS``) in the
+        driver."""
         gx, gy = self.split_grid
         if self.bin_aligned_splits:
             gx = max(gx, self.max_split_span)
@@ -89,7 +90,7 @@ class IndexConfig:
         return gx * gy
 
     def __post_init__(self):
-        from ..kernels.segment_agg import MAX_UNROLL
+        from ..kernels.segment_agg import MAX_GROUP_CELLS
         gx, gy = self.split_grid
         if gx < 2 or gy < 2:
             raise ValueError(f"split_grid must be >= 2 per axis, got "
@@ -101,7 +102,7 @@ class IndexConfig:
             raise ValueError(
                 f"max_split_span={self.max_split_span} must be >= "
                 f"max(split_grid)={max(gx, gy)} (and >= 2)")
-        if self.max_split_cells() > MAX_UNROLL:
+        if self.max_split_cells() > MAX_GROUP_CELLS:
             # fail at construction, not as an AssertionError deep in a
             # packed split kernel mid-query (the batched driver's round
             # cap would also floor to 0 first)
@@ -109,7 +110,8 @@ class IndexConfig:
                 f"max split grid {self.max_split_cells()} cells "
                 f"(split_grid={self.split_grid}, max_split_span="
                 f"{self.max_split_span}) exceeds the packed split "
-                f"kernels' static unroll limit MAX_UNROLL={MAX_UNROLL}")
+                f"kernels' per-program cell limit "
+                f"MAX_GROUP_CELLS={MAX_GROUP_CELLS}")
 
 
 @dataclasses.dataclass
@@ -144,10 +146,9 @@ class TileIndex:
         self.ds = dataset
         self.cfg = config
         self.adapt_stats = AdaptStats()
-        # host control plane defaults to the numpy mirror of the kernels
-        # (data-dependent segment lengths would recompile XLA per shape);
-        # on-device bulk paths use the Pallas/jnp backends.
-        self._backend = config.backend or ops.host_backend()
+        # data plane: compiled Pallas kernels on a TPU, the f64 numpy
+        # mirror elsewhere (ops.index_backend); config.backend overrides
+        self._backend = config.backend or ops.index_backend()
         n = dataset.n
         cap = config.capacity
 
@@ -606,11 +607,12 @@ class TileIndex:
         refinement to exactly the tiles it folded, which keeps the index
         evolution bit-for-bit identical to the sequential reference path.
 
-        Precision contract: under the default host backend ("np") the
-        contributions are float64 with the same accumulation order as
-        :meth:`process` — bit-for-bit the sequential reference. A device
-        backend override ("jnp"/"pallas" — the TPU deploy data plane)
-        computes them in float32 and matches to f32 tolerance only.
+        Precision contract: under the "np" backend (the default off the
+        chip) the contributions are float64 with the same accumulation
+        order as :meth:`process` — bit-for-bit the sequential reference.
+        A device backend ("pallas", the default on a TPU, or "jnp")
+        computes counts and extrema exactly but sums in float32, so it
+        matches the reference to f32 tolerance only.
         """
         if self.ds.closed:
             return self._dead_batch(tile_ids, attr)
@@ -643,9 +645,9 @@ class TileIndex:
         ALWAYS computed with the f64 host mirror, even under a device
         backend override: the per-query path is the sequential parity
         reference, and its sums must keep the f64 accumulation order.
-        (Per-bin COUNTS are no longer the obstacle — the axis-index
-        binning contract of ``ref.window_bin_params`` makes device
-        binning bit-identical to ``window_bin_ids_np``, which is what
+        (Per-bin COUNTS are no longer the obstacle — the binning
+        contract of ``ref.window_bin_thresholds`` makes device binning
+        bit-identical to ``window_bin_ids_np``, which is what
         lets the serving tick's MULTI-window pass
         (``ops.segment_window_bin_select_multi``) run on the part's
         device backend without breaking the count cross-check.)

@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.segment_agg import MAX_SEGMENTS, MAX_UNROLL
+from ..kernels.segment_agg import MAX_SEGMENTS, MAX_GROUP_CELLS
 from . import query as query_mod
 from .bounds import EPS, AccuracyPolicy
 from .refine import HeatmapQueryAdapter
@@ -315,7 +315,7 @@ def prefetch_crack(index, window, attr: str, bins: Tuple[int, int],
     adapter = HeatmapQueryAdapter(index, window, attr, bins)
     order = adapter.score_order(acc, alpha)
     k = max(1, min(index.cfg.batch_k, MAX_SEGMENTS,
-                   MAX_UNROLL // adapter.max_split_cells()))
+                   MAX_GROUP_CELLS // adapter.max_split_cells()))
     budget = int(budget_rows)
     pos = 0
     while pos < len(order) and budget > 0:

@@ -306,8 +306,13 @@ def evaluate_heatmap_oracle(index, window, agg: str, attr: str,
     bx, by = bins
     nbins = bx * by
     ds = index.ds
-    m, cid = window_bin_ids_np(ds.x, ds.y, window, bx, by)
-    vals = ds.read_all_unaccounted(attr)
+    # the window mask of window_bin_ids_np first, so binning and the
+    # per-bin selections touch only the window's objects (same objects,
+    # same order as masking the whole file per bin)
+    x0, y0, x1, y1 = (float(v) for v in window)
+    pre = (ds.x >= x0) & (ds.x <= x1) & (ds.y >= y0) & (ds.y <= y1)
+    m, cid = window_bin_ids_np(ds.x[pre], ds.y[pre], window, bx, by)
+    vals = ds.read_all_unaccounted(attr)[pre]
     out = np.zeros(nbins, np.float64)
     if agg == "min":
         out[:] = np.inf

@@ -53,7 +53,7 @@ import dataclasses
 from typing import Callable, List, Optional, Sequence, Tuple
 
 from . import adapt
-from ..kernels.segment_agg import MAX_SEGMENTS, MAX_UNROLL
+from ..kernels.segment_agg import MAX_SEGMENTS, MAX_GROUP_CELLS
 
 
 def met(phi: float, bound: float) -> bool:
@@ -240,13 +240,14 @@ class RefinementDriver:
     def _run_batched(self, order, bound, batch_k: Optional[int]) -> int:
         acc, phi, index = self.acc, self.phi, self.index
         k = index.cfg.batch_k if batch_k is None else int(batch_k)
-        # packed kernels unroll statically over segments (and cells in
-        # the split kernel) — cap the round size at their limits, sized
+        # packed kernels take at most MAX_SEGMENTS segments and plan
+        # MAX_GROUP_CELLS (segment, cell) pairs per program — cap the
+        # round size at their limits, sized
         # by the LARGEST split grid this adapter's rounds may carry
         # (heatmap: bin-count-matched grids up to max_split_span per
         # axis; scalar: the even split_grid)
         k = max(1, min(k, MAX_SEGMENTS,
-                       MAX_UNROLL // self.adapter.max_split_cells()))
+                       MAX_GROUP_CELLS // self.adapter.max_split_cells()))
         # Round sizing under φ>0: the stopping rule can fire mid-round
         # and rows read past it are speculative. For sum/mean the needed
         # fold count has a certain lower bound (min_folds_needed) —

@@ -83,7 +83,7 @@ from typing import Dict, List, Optional, Tuple, Union
 import numpy as np
 
 from ..kernels import ops
-from ..kernels.segment_agg import MAX_SEGMENTS, MAX_UNROLL
+from ..kernels.segment_agg import MAX_SEGMENTS, MAX_GROUP_CELLS
 from . import query as query_mod
 from .bounds import AccuracyPolicy, HeatmapResult, QueryResult
 from .engine import AQPEngine, EngineTrace
@@ -240,7 +240,7 @@ class _QueryRun:
             k = (index.cfg.batch_k if tk.batch_k is None
                  else int(tk.batch_k))
             self.k = max(1, min(k, MAX_SEGMENTS,
-                                MAX_UNROLL // self.adapter.max_split_cells()))
+                                MAX_GROUP_CELLS // self.adapter.max_split_cells()))
             self.predictive = tk.phi > 0.0 and acc.agg in ("sum", "mean")
             self.size = 1 if tk.phi > 0.0 else self.k
         else:

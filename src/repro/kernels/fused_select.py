@@ -21,17 +21,12 @@ Three backends, per house style:
   :func:`suffix_residual`) — the jit oracle. ``core.distributed``'s
   fused session steps call these SAME primitives, so the SPMD
   classify→scatter→select chain and this oracle are one expression.
-- :func:`fused_table_pallas` / :func:`segment_window_bin_select_pallas`
-  — the TPU megakernel. Unlike the 1-D ``segment_agg`` ancestors it
-  runs a REAL 2-D grid ``(cell_groups, row_blocks)`` planned by
-  :mod:`repro.kernels.gridplan`: the outer axis walks groups of
-  segments, the minor axis streams double-buffered row tiles with the
-  group's ``(1, group·nb, 4)`` output block VMEM-resident and
-  accumulated in-kernel (``@pl.when(r == 0)`` init + read-modify-write)
-  — window mask, bin ids, grouped scatter all inside one kernel body,
-  no host-side partial reduction. The O(S·nb) selection epilogue
-  (suffix widths) is jnp inside the same jit, so the whole op is a
-  single dispatch.
+- :func:`segment_window_bin_select_pallas` — the TPU megakernel: the
+  one grouped kernel of :mod:`repro.kernels.segment_agg` (window mask,
+  bin ids, grouped reduction in one kernel body over a 2-D
+  ``(cell_groups, row_blocks)`` grid, no host-side partial reduction)
+  with the O(S·nb) selection epilogue (suffix widths) as jnp inside the
+  same jit, so the whole op is a single dispatch.
 
 Suffix-width contract: given per-segment sound value bounds
 ``vmin_s/vmax_s`` (the pending intervals of the tiles, in FOLD ORDER),
@@ -47,12 +42,13 @@ The MULTI-window family (``segment_window_bin_select_multi_*``) is the
 serving tick's variant: one dispatch where segment s is masked and
 binned by its OWN window (one packed pass answers many concurrent
 viewports) and the suffix widths are per-QUERY-SPAN
-(:func:`segmented_suffix`). Its device backends bin through the
-host-precomputed contract params (``ref.window_bin_params`` — f64-
-derived cell sizes rounded to f32, never recomputed in-kernel), which
-is what makes the device counts/extrema bit-identical to the f64 host
-mirror and lets the serving tick leave the host path without breaking
-the batched ≡ sequential guarantee.
+(:func:`segmented_suffix`). Its jnp oracle bins through the
+host-precomputed params of ``ref.window_bin_params`` and its Pallas
+backend through the compare thresholds of
+``ref.window_bin_thresholds``; both make the device counts/extrema
+bit-identical to the f64 host mirror, which lets the serving tick leave
+the host path without breaking the grouped accumulator's exact count
+cross-check.
 """
 from __future__ import annotations
 
@@ -61,11 +57,9 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
 from . import ref
-from .gridplan import plan_cell_groups
-from .segment_agg import LANES, DEFAULT_BLOCK_ROWS, MAX_SEGMENTS
+from .segment_agg import DEFAULT_BLOCK_ROWS, grouped_agg_pallas
 
 NEG = -3.4e38
 POS = 3.4e38
@@ -277,244 +271,28 @@ def segment_window_bin_select_multi_ref(xs, ys, vals, sids, params, grid,
 
 
 # --------------------------------------------------------------------- #
-# the Pallas megakernel (real 2-D grid, in-kernel accumulation)
+# the Pallas megakernel: grouped table + selection epilogue, one jit
 # --------------------------------------------------------------------- #
-
-def _make_fused_table_kernel(group: int, bx: int, by: int):
-    nb = bx * by
-
-    def kernel(win_ref, x_ref, y_ref, v_ref, sid_ref, valid_ref,
-               out_ref):
-        g = pl.program_id(0)    # cell group (outer)
-        r = pl.program_id(1)    # row block (minor) — out block resident
-
-        @pl.when(r == 0)
-        def _init():
-            shp = out_ref.shape[:-1]
-            out_ref[:, :, 0] = jnp.zeros(shp, jnp.float32)
-            out_ref[:, :, 1] = jnp.zeros(shp, jnp.float32)
-            out_ref[:, :, 2] = jnp.full(shp, jnp.inf, jnp.float32)
-            out_ref[:, :, 3] = jnp.full(shp, -jnp.inf, jnp.float32)
-
-        x0 = win_ref[0, 0]
-        y0 = win_ref[0, 1]
-        x1 = win_ref[0, 2]
-        y1 = win_ref[0, 3]
-        xs = x_ref[...]
-        ys = y_ref[...]
-        vs = v_ref[...]
-        sid = sid_ref[...]
-        valid = valid_ref[...] != 0
-        # fused classification: window mask + bin ids once per block,
-        # shared across the whole segment×bin unroll below
-        inw = (xs >= x0) & (xs <= x1) & (ys >= y0) & (ys <= y1) & valid
-        cw = jnp.maximum((x1 - x0) / bx, 1e-30)
-        ch = jnp.maximum((y1 - y0) / by, 1e-30)
-        cx = jnp.clip(jnp.floor((xs - x0) / cw).astype(jnp.int32),
-                      0, bx - 1)
-        cy = jnp.clip(jnp.floor((ys - y0) / ch).astype(jnp.int32),
-                      0, by - 1)
-        cid = cy * bx + cx
-        for t in range(group):  # static unroll over the GROUP's segments
-            s_glob = (g * group + t).astype(jnp.float32)
-            ms = inw & (sid == s_glob)
-            for c in range(nb):  # …and window bins: group·nb reductions
-                m = ms & (cid == c)
-                i = t * nb + c
-                out_ref[0, i, 0] = out_ref[0, i, 0] + jnp.sum(
-                    m.astype(jnp.float32))
-                out_ref[0, i, 1] = out_ref[0, i, 1] + jnp.sum(
-                    jnp.where(m, vs, 0.0))
-                out_ref[0, i, 2] = jnp.minimum(
-                    out_ref[0, i, 2], jnp.min(jnp.where(m, vs, jnp.inf)))
-                out_ref[0, i, 3] = jnp.maximum(
-                    out_ref[0, i, 3],
-                    jnp.max(jnp.where(m, vs, -jnp.inf)))
-    return kernel
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_seg", "bx", "by", "block_rows",
-                                    "seg_group", "interpret"))
-def fused_table_pallas(xs2d, ys2d, vals2d, sid2d, valid2d, window, *,
-                       n_seg, bx, by, block_rows=DEFAULT_BLOCK_ROWS,
-                       seg_group=None, interpret=True):
-    """The megakernel proper: per-(segment, window-bin) ``(count, sum,
-    min, max)`` in ONE kernel over a 2-D ``(cell_groups, row_blocks)``
-    grid.
-
-    Args mirror ``segment_agg.segment_window_bin_agg_pallas``; the
-    result is identical up to f32 sum accumulation order (counts and
-    extrema exact). The outer grid axis walks segment groups sized by
-    :func:`~repro.kernels.gridplan.plan_cell_groups` (``seg_group``
-    forces the group size — tests use it to cover the multi-group
-    path); the minor axis streams row blocks with the group's output
-    block VMEM-resident, accumulated in-kernel: no partial slab, no
-    host reduce. Returns float32 ``(n_seg, bx·by, 4)``."""
-    nb = bx * by
-    assert n_seg <= MAX_SEGMENTS, n_seg
-    rows = xs2d.shape[0]
-    assert rows % block_rows == 0, (rows, block_rows)
-    group, n_groups, _ = plan_cell_groups(n_seg, nb,
-                                          block_rows=block_rows,
-                                          group=seg_group)
-    win2d = window.reshape(1, 4).astype(jnp.float32)
-    valid2d = valid2d.astype(jnp.int8)
-
-    out = pl.pallas_call(
-        _make_fused_table_kernel(group, bx, by),
-        grid=(n_groups, rows // block_rows),
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda g, r: (0, 0)),    # window
-            pl.BlockSpec((block_rows, LANES), lambda g, r: (r, 0)),
-            pl.BlockSpec((block_rows, LANES), lambda g, r: (r, 0)),
-            pl.BlockSpec((block_rows, LANES), lambda g, r: (r, 0)),
-            pl.BlockSpec((block_rows, LANES), lambda g, r: (r, 0)),
-            pl.BlockSpec((block_rows, LANES), lambda g, r: (r, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, group * nb, 4),
-                               lambda g, r: (g, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_groups, group * nb, 4),
-                                       jnp.float32),
-        interpret=interpret,
-    )(win2d, xs2d.astype(jnp.float32), ys2d.astype(jnp.float32),
-      vals2d.astype(jnp.float32), sid2d.astype(jnp.float32), valid2d)
-
-    return out.reshape(n_groups * group, nb, 4)[:n_seg]
-
 
 @functools.partial(jax.jit,
                    static_argnames=("n_seg", "bx", "by", "block_rows",
                                     "seg_group", "interpret"))
 def segment_window_bin_select_pallas(xs2d, ys2d, vals2d, sid2d, valid2d,
-                                     window, vmin_s, vmax_s, *, n_seg,
+                                     params, vmin_s, vmax_s, *, n_seg,
                                      bx, by,
                                      block_rows=DEFAULT_BLOCK_ROWS,
-                                     seg_group=None, interpret=True):
-    """Single-dispatch fused select: the :func:`fused_table_pallas`
-    megakernel + the O(S·nb) jnp suffix-width epilogue in one jit.
+                                     seg_group=None, interpret=False):
+    """Single-dispatch fused select: the per-(segment, window-bin)
+    table of ``segment_agg.grouped_agg_pallas`` + the O(S·nb) jnp
+    suffix-width epilogue in one jit. ``params`` is the
+    ``ref.window_bin_thresholds`` row of the window, one per segment.
     Returns ``(agg (S, bx·by, 4), suffix_w (S+1, bx·by))`` float32."""
-    agg = fused_table_pallas(xs2d, ys2d, vals2d, sid2d, valid2d, window,
-                             n_seg=n_seg, bx=bx, by=by,
+    agg = grouped_agg_pallas(params, xs2d, ys2d, vals2d, sid2d, valid2d,
+                             n_seg=n_seg, window=True, gx=bx, gy=by,
                              block_rows=block_rows, seg_group=seg_group,
                              interpret=interpret)
     w = agg[:, :, 0] * (vmax_s - vmin_s)[:, None]
     return agg, suffix_residual(w, "sum")
-
-
-# --------------------------------------------------------------------- #
-# multi-window megakernel: per-segment OWN windows in one dispatch
-# --------------------------------------------------------------------- #
-
-def _make_fused_multi_kernel(group: int, bx: int, by: int):
-    nb = bx * by
-
-    def kernel(par_ref, x_ref, y_ref, v_ref, sid_ref, valid_ref,
-               out_ref):
-        g = pl.program_id(0)    # cell group (outer)
-        r = pl.program_id(1)    # row block (minor) — out block resident
-
-        @pl.when(r == 0)
-        def _init():
-            shp = out_ref.shape[:-1]
-            out_ref[:, :, 0] = jnp.zeros(shp, jnp.float32)
-            out_ref[:, :, 1] = jnp.zeros(shp, jnp.float32)
-            out_ref[:, :, 2] = jnp.full(shp, jnp.inf, jnp.float32)
-            out_ref[:, :, 3] = jnp.full(shp, -jnp.inf, jnp.float32)
-
-        xs = x_ref[...]
-        ys = y_ref[...]
-        vs = v_ref[...]
-        sid = sid_ref[...]
-        valid = valid_ref[...] != 0
-        for t in range(group):  # static unroll over the GROUP's segments
-            # per-segment window + HOST-derived cell sizes: the binning
-            # contract (ref.window_bin_params) — recomputing cw/ch from
-            # the f32 coords here would round differently than the host
-            # mirror and break the count cross-check
-            x0 = par_ref[t, 0]
-            y0 = par_ref[t, 1]
-            x1 = par_ref[t, 2]
-            y1 = par_ref[t, 3]
-            cw = par_ref[t, 4]
-            ch = par_ref[t, 5]
-            s_glob = (g * group + t).astype(jnp.float32)
-            inw = ((xs >= x0) & (xs <= x1) & (ys >= y0) & (ys <= y1)
-                   & valid & (sid == s_glob))
-            cx = jnp.clip(jnp.floor((xs - x0) / cw).astype(jnp.int32),
-                          0, bx - 1)
-            cy = jnp.clip(jnp.floor((ys - y0) / ch).astype(jnp.int32),
-                          0, by - 1)
-            cid = cy * bx + cx
-            for c in range(nb):  # …and window bins: group·nb reductions
-                m = inw & (cid == c)
-                i = t * nb + c
-                out_ref[0, i, 0] = out_ref[0, i, 0] + jnp.sum(
-                    m.astype(jnp.float32))
-                out_ref[0, i, 1] = out_ref[0, i, 1] + jnp.sum(
-                    jnp.where(m, vs, 0.0))
-                out_ref[0, i, 2] = jnp.minimum(
-                    out_ref[0, i, 2], jnp.min(jnp.where(m, vs, jnp.inf)))
-                out_ref[0, i, 3] = jnp.maximum(
-                    out_ref[0, i, 3],
-                    jnp.max(jnp.where(m, vs, -jnp.inf)))
-    return kernel
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_seg", "bx", "by", "block_rows",
-                                    "seg_group", "interpret"))
-def fused_table_multi_pallas(xs2d, ys2d, vals2d, sid2d, valid2d, params,
-                             *, n_seg, bx, by,
-                             block_rows=DEFAULT_BLOCK_ROWS,
-                             seg_group=None, interpret=True):
-    """Multi-window megakernel: per-(segment, bin) ``(count, sum, min,
-    max)`` where segment s is masked AND binned by its OWN window, in
-    ONE kernel over the 2-D ``(cell_groups, row_blocks)`` grid.
-
-    ``params`` is the ``(n_seg, 6)`` f32 contract-param table from
-    ``ref.window_bin_params`` — the group's rows stream in beside the
-    operand planes (the ``segment_window_agg_multi`` window-row idiom,
-    widened to 6 columns so the in-kernel binning is bit-compatible
-    with the host rule). Grid planning as in :func:`fused_table_pallas`
-    (``param_cols=6`` in the VMEM model). Returns float32
-    ``(n_seg, bx·by, 4)``."""
-    nb = bx * by
-    assert n_seg <= MAX_SEGMENTS, n_seg
-    rows = xs2d.shape[0]
-    assert rows % block_rows == 0, (rows, block_rows)
-    group, n_groups, n_pad = plan_cell_groups(n_seg, nb,
-                                              block_rows=block_rows,
-                                              param_cols=6,
-                                              group=seg_group)
-    par = params.astype(jnp.float32).reshape(n_seg, 6)
-    if n_pad > n_seg:
-        # padded segment rows are never matched by any object's sid;
-        # all-ones params keep their dead binning arithmetic finite
-        par = jnp.concatenate(
-            [par, jnp.ones((n_pad - n_seg, 6), jnp.float32)])
-    valid2d = valid2d.astype(jnp.int8)
-
-    out = pl.pallas_call(
-        _make_fused_multi_kernel(group, bx, by),
-        grid=(n_groups, rows // block_rows),
-        in_specs=[
-            pl.BlockSpec((group, 6), lambda g, r: (g, 0)),  # params
-            pl.BlockSpec((block_rows, LANES), lambda g, r: (r, 0)),
-            pl.BlockSpec((block_rows, LANES), lambda g, r: (r, 0)),
-            pl.BlockSpec((block_rows, LANES), lambda g, r: (r, 0)),
-            pl.BlockSpec((block_rows, LANES), lambda g, r: (r, 0)),
-            pl.BlockSpec((block_rows, LANES), lambda g, r: (r, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, group * nb, 4),
-                               lambda g, r: (g, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_groups, group * nb, 4),
-                                       jnp.float32),
-        interpret=interpret,
-    )(par, xs2d.astype(jnp.float32), ys2d.astype(jnp.float32),
-      vals2d.astype(jnp.float32), sid2d.astype(jnp.float32), valid2d)
-
-    return out.reshape(n_groups * group, nb, 4)[:n_seg]
 
 
 @functools.partial(jax.jit,
@@ -526,15 +304,15 @@ def segment_window_bin_select_multi_pallas(xs2d, ys2d, vals2d, sid2d,
                                            by,
                                            block_rows=DEFAULT_BLOCK_ROWS,
                                            seg_group=None,
-                                           interpret=True):
-    """Single-dispatch multi-window fused select: the
-    :func:`fused_table_multi_pallas` megakernel + the per-query-span
+                                           interpret=False):
+    """Single-dispatch multi-window fused select — the serving tick's
+    kernel: segment s masked AND binned by its OWN window (row s of
+    ``params``, ``ref.window_bin_thresholds``), plus the per-query-span
     :func:`segmented_suffix` epilogue in one jit. Returns
     ``(agg (S, bx·by, 4), suffix_w (S, bx·by))`` float32."""
-    agg = fused_table_multi_pallas(xs2d, ys2d, vals2d, sid2d, valid2d,
-                                   params, n_seg=n_seg, bx=bx, by=by,
-                                   block_rows=block_rows,
-                                   seg_group=seg_group,
-                                   interpret=interpret)
+    agg = grouped_agg_pallas(params, xs2d, ys2d, vals2d, sid2d, valid2d,
+                             n_seg=n_seg, window=True, gx=bx, gy=by,
+                             block_rows=block_rows, seg_group=seg_group,
+                             interpret=interpret)
     w = agg[:, :, 0] * (vmax_s - vmin_s)[:, None]
     return agg, segmented_suffix(w, qend)

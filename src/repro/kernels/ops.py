@@ -1,20 +1,28 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""Public jit'd wrappers around the data-plane kernel.
 
 Backends:
-- ``"pallas"``  — the TPU kernels; on this CPU container they execute via
-  ``interpret=True`` (the kernel body runs in Python) for correctness
-  validation. This is the deploy path on TPU (interpret=False).
-- ``"jnp"``     — the pure-jnp oracle from :mod:`repro.kernels.ref`,
-  jit-compiled by XLA:CPU. This is the fast path used by the benchmark
-  harness on this container so that measured query times reflect data
-  volume rather than interpret-mode Python overhead.
+- ``"pallas"`` — the one grouped Pallas kernel
+  (:mod:`repro.kernels.segment_agg`). Compiled for the chip on TPU;
+  elsewhere it runs in the Pallas interpreter (the kernel body evaluated
+  by XLA:CPU) for correctness validation. ``interpret=None`` resolves
+  from the platform (:func:`resolve_interpret`), so no caller gets the
+  interpreter on a TPU unless it asks for it.
+- ``"jnp"`` — the pure-jnp oracle from :mod:`repro.kernels.ref`,
+  jit-compiled by XLA.
+- ``"np"`` — the f64 NumPy host mirrors: the sequential references the
+  batched and device paths are tested against.
 
-``default_backend()`` picks "pallas" on TPU and "jnp" elsewhere; every op
-takes an explicit ``backend=`` override so tests can pin both and
-assert_allclose them against each other.
+``default_backend()`` ("pallas" on TPU, "jnp" elsewhere) is what an op
+uses when called without ``backend=``; :func:`index_backend` ("pallas"
+on TPU, "np" elsewhere) is what a ``TileIndex`` uses when its
+``IndexConfig.backend`` is unset. Every op takes an explicit
+``backend=`` override so tests can pin each backend and compare them.
 
 All ops accept flat 1-D object arrays and handle the (rows, 128) padding
-layout internally.
+layout internally. The Pallas paths take their per-segment scalars from
+host-derived tables (``ref.window_bin_thresholds``,
+``ref.split_thresholds``, ``ref.edge_thresholds``), so device cell
+assignment is bit-identical to the host mirrors' ownership rules.
 """
 from __future__ import annotations
 
@@ -26,28 +34,31 @@ import numpy as np
 
 from . import fused_select
 from . import ref
-from .window_agg import window_agg_pallas, LANES, DEFAULT_BLOCK_ROWS
-from .bin_agg import bin_agg_pallas
-from .segment_agg import (segment_window_agg_pallas, segment_bin_agg_pallas,
-                          segment_bin_agg_edges_pallas,
-                          segment_window_bin_agg_pallas,
-                          segment_window_agg_multi_pallas,
-                          segment_window_bin_agg_multi_pallas)
+from .segment_agg import LANES, DEFAULT_BLOCK_ROWS, grouped_agg_pallas
+
+
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 def default_backend() -> str:
-    """Device data plane: "pallas" on TPU, "jnp" elsewhere.
-
-    The *host control plane* (the index's per-tile bookkeeping, which runs
-    on CPU with data-dependent segment lengths) uses the "np" backend to
-    avoid per-shape XLA recompiles; it is semantically identical and is
-    validated against both device backends in tests/test_kernels.py.
-    """
-    return "pallas" if jax.default_backend() == "tpu" else "jnp"
+    """Device data plane of a bare op call: "pallas" on TPU, "jnp"
+    elsewhere."""
+    return "pallas" if on_tpu() else "jnp"
 
 
-def host_backend() -> str:
-    return "np"
+def index_backend() -> str:
+    """Data plane of a ``TileIndex`` whose config leaves ``backend``
+    unset: the compiled Pallas kernel on TPU, the f64 "np" mirror
+    elsewhere (on a CPU host the mirror avoids per-shape XLA recompiles
+    of data-dependent segment lengths, and it is the bit-for-bit
+    sequential reference)."""
+    return "pallas" if on_tpu() else "np"
+
+
+def resolve_interpret(interpret=None) -> bool:
+    """``None`` → compiled on TPU, interpreted elsewhere."""
+    return (not on_tpu()) if interpret is None else bool(interpret)
 
 
 def _window_agg_np(xs, ys, vals, window, n):
@@ -121,24 +132,75 @@ def pack2d(*arrays, n=None, block_rows=DEFAULT_BLOCK_ROWS):
     return (*outs, valid)
 
 
-@functools.partial(jax.jit, static_argnames=("backend", "interpret", "full"))
-def _window_agg_flat(xs, ys, vals, window, n, backend, interpret,
-                     full=False):
-    if backend == "jnp":
-        # full=True: the caller passed n=None (whole array live) — skip
-        # the validity stream, the sweeps are bandwidth-bound
-        valid = None if full else jnp.arange(xs.shape[0]) < n
-        return ref.window_agg_ref(xs, ys, vals, window, valid)
-    xs2, ys2, vs2, valid2 = pack2d(xs, ys, vals, n=xs.shape[0])
-    # mask padding AND the tail beyond n
+def _packed(xs, ys, vals, sids, n):
+    """(traced) pack2d planes with the tail beyond ``n`` masked off."""
+    planes = (xs, ys, vals) if sids is None else (xs, ys, vals, sids)
+    *p2, valid2 = pack2d(*planes, n=xs.shape[0])
     valid2 = valid2 * (jnp.arange(valid2.size).reshape(valid2.shape) <
                        n).astype(jnp.int8)
-    return window_agg_pallas(xs2, ys2, vs2, valid2, window,
-                             interpret=interpret)
+    return p2[0], p2[1], p2[2], (None if sids is None else p2[3]), valid2
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("n_seg", "window", "gx", "gy",
+                                    "interpret", "seg_group"))
+def _pallas_flat(xs, ys, vals, sids, params, n, n_seg, window, gx, gy,
+                 interpret, seg_group=None):
+    xs2, ys2, vs2, sid2, valid2 = _packed(xs, ys, vals, sids, n)
+    return grouped_agg_pallas(params, xs2, ys2, vs2, sid2, valid2,
+                              n_seg=n_seg, window=window, gx=gx, gy=gy,
+                              seg_group=seg_group, interpret=interpret)
+
+
+def _seg_bucket(n_seg: int, *tables):
+    """Round a packed call's segment count up to a power of two (one
+    compiled kernel per bucket, not per count) and zero-pad its
+    per-segment tables to it. Padded segments hold no objects, so
+    their rows come back empty and are sliced off."""
+    pad = 1 << (n_seg - 1).bit_length()
+    out = []
+    for t in tables:
+        t = np.asarray(t)
+        out.append(np.concatenate(
+            [t, np.zeros((pad - n_seg,) + t.shape[1:], t.dtype)]))
+    return pad, out
+
+
+def _pallas(xs, ys, vals, sids, params, n, *, n_seg, window, gx=1, gy=1,
+            interpret=None, seg_group=None):
+    """Host entry of the grouped kernel: ``params`` is the per-segment
+    scalar table (``(n_seg, ncol)`` f32)."""
+    params = np.asarray(params, np.float32).reshape(n_seg, -1)
+    pad, (params,) = _seg_bucket(n_seg, params)
+    return _pallas_flat(xs, ys, vals, sids, params, int(n), pad, window,
+                        gx, gy, resolve_interpret(interpret),
+                        seg_group)[:n_seg]
+
+
+def _segment_stream(xs, ys, vals, boundaries):
+    """Bucket-padded flat arrays + per-object segment ids of a
+    concatenated segment stream: ``(xs, ys, vals, sids, n, n_seg)``."""
+    n_seg = len(boundaries) - 1
+    n = int(boundaries[-1])
+    sids = np.repeat(np.arange(n_seg), np.diff(boundaries))
+    xs, ys, vals, sids = _bucket_pad(xs, ys, vals, sids, n=n)
+    return xs, ys, vals, sids, n, n_seg
+
+
+def _window_row(window):
+    return np.asarray(window, np.float32).reshape(1, 4)
+
+
+@functools.partial(jax.jit, static_argnames=("full",))
+def _window_agg_jnp(xs, ys, vals, window, n, full=False):
+    # full=True: the caller passed n=None (whole array live) — skip the
+    # validity stream, the sweeps are bandwidth-bound
+    valid = None if full else jnp.arange(xs.shape[0]) < n
+    return ref.window_agg_ref(xs, ys, vals, window, valid)
 
 
 def window_agg(xs, ys, vals, window, *, n=None, backend=None,
-               interpret=True):
+               interpret=None):
     """(count, sum, min, max) of ``vals`` for objects in the closed window.
 
     ``n``: logical length (entries past n are ignored) — lets callers pass
@@ -150,28 +212,23 @@ def window_agg(xs, ys, vals, window, *, n=None, backend=None,
         return _window_agg_np(xs, ys, vals, window, n)
     full = n is None
     xs, ys, vals = _dev(xs), _dev(ys), _dev(vals)
-    window = np.asarray(window, np.float32)
     n = xs.shape[0] if n is None else n
-    return _window_agg_flat(xs, ys, vals, window, int(n),
-                            backend, interpret, full=full)
-
-
-@functools.partial(jax.jit, static_argnames=("gx", "gy", "backend",
-                                             "interpret", "full"))
-def _bin_agg_flat(xs, ys, vals, bbox, n, gx, gy, backend, interpret,
-                  full=False):
     if backend == "jnp":
-        valid = None if full else jnp.arange(xs.shape[0]) < n
-        return ref.bin_agg_ref(xs, ys, vals, bbox, (gx, gy), valid)
-    xs2, ys2, vs2, valid2 = pack2d(xs, ys, vals, n=xs.shape[0])
-    valid2 = valid2 * (jnp.arange(valid2.size).reshape(valid2.shape) <
-                       n).astype(jnp.int8)
-    return bin_agg_pallas(xs2, ys2, vs2, valid2, bbox, gx=gx, gy=gy,
-                          interpret=interpret)
+        return _window_agg_jnp(xs, ys, vals,
+                               np.asarray(window, np.float32), int(n),
+                               full=full)
+    return _pallas(xs, ys, vals, None, _window_row(window), n, n_seg=1,
+                   window=True, interpret=interpret).reshape(4)
+
+
+@functools.partial(jax.jit, static_argnames=("gx", "gy", "full"))
+def _bin_agg_jnp(xs, ys, vals, bbox, n, gx, gy, full=False):
+    valid = None if full else jnp.arange(xs.shape[0]) < n
+    return ref.bin_agg_ref(xs, ys, vals, bbox, (gx, gy), valid)
 
 
 def bin_agg(xs, ys, vals, bbox, *, gx, gy, n=None, backend=None,
-            interpret=True):
+            interpret=None):
     """Per-cell (count, sum, min, max) over a gx×gy split of bbox."""
     backend = backend or default_backend()
     if backend == "np":
@@ -179,10 +236,13 @@ def bin_agg(xs, ys, vals, bbox, *, gx, gy, n=None, backend=None,
         return _bin_agg_np(xs, ys, vals, bbox, gx, gy, n)
     full = n is None
     xs, ys, vals = _dev(xs), _dev(ys), _dev(vals)
-    bbox = np.asarray(bbox, np.float32)
     n = xs.shape[0] if n is None else n
-    return _bin_agg_flat(xs, ys, vals, bbox, int(n),
-                         gx, gy, backend, interpret, full=full)
+    if backend == "jnp":
+        return _bin_agg_jnp(xs, ys, vals, np.asarray(bbox, np.float32),
+                            int(n), gx, gy, full=full)
+    params = ref.split_thresholds(np.asarray(bbox)[None], gx, gy)
+    return _pallas(xs, ys, vals, None, params, n, n_seg=1, window=False,
+                   gx=gx, gy=gy, interpret=interpret)[0]
 
 
 def _bucket_pad(*arrays, n):
@@ -196,22 +256,15 @@ def _bucket_pad(*arrays, n):
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("n_seg", "backend", "interpret"))
-def _segment_window_agg_flat(xs, ys, vals, sids, window, n, n_seg, backend,
-                             interpret):
-    if backend == "jnp":
-        valid = jnp.arange(xs.shape[0]) < n
-        return ref.segment_window_agg_ref(xs, ys, vals, sids, window, valid,
-                                          n_seg)
-    xs2, ys2, vs2, sid2, valid2 = pack2d(xs, ys, vals, sids, n=xs.shape[0])
-    valid2 = valid2 * (jnp.arange(valid2.size).reshape(valid2.shape) <
-                       n).astype(jnp.int8)
-    return segment_window_agg_pallas(xs2, ys2, vs2, sid2, valid2, window,
-                                     n_seg=n_seg, interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("n_seg",))
+def _segment_window_agg_jnp(xs, ys, vals, sids, window, n, n_seg):
+    valid = jnp.arange(xs.shape[0]) < n
+    return ref.segment_window_agg_ref(xs, ys, vals, sids, window, valid,
+                                      n_seg)
 
 
 def segment_window_agg(xs, ys, vals, boundaries, window, *, backend=None,
-                       interpret=True):
+                       interpret=None):
     """Per-segment (count, sum, min, max) inside the closed ``window``.
 
     The batched-adaptation primitive: ``xs/ys/vals`` are the CONCATENATED
@@ -228,71 +281,58 @@ def segment_window_agg(xs, ys, vals, boundaries, window, *, backend=None,
     boundaries = np.asarray(boundaries, np.int64)
     if backend == "np":
         return ref.segment_window_agg_np(xs, ys, vals, boundaries, window)
-    n_seg = len(boundaries) - 1
-    n = int(boundaries[-1])
-    sids = np.repeat(np.arange(n_seg), np.diff(boundaries))
-    xs, ys, vals, sids = _bucket_pad(xs, ys, vals, sids, n=n)
-    return _segment_window_agg_flat(
-        xs, ys, vals, sids, np.asarray(window, np.float32),
-        int(n), n_seg, backend, interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("n_seg", "gx", "gy", "backend",
-                                             "interpret"))
-def _segment_bin_agg_flat(xs, ys, vals, sids, bboxes, n, n_seg, gx, gy,
-                          backend, interpret):
+    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                   boundaries)
     if backend == "jnp":
-        valid = jnp.arange(xs.shape[0]) < n
-        return ref.segment_bin_agg_ref(xs, ys, vals, sids, bboxes, (gx, gy),
-                                       valid, n_seg)
-    xs2, ys2, vs2, sid2, valid2 = pack2d(xs, ys, vals, sids, n=xs.shape[0])
-    valid2 = valid2 * (jnp.arange(valid2.size).reshape(valid2.shape) <
-                       n).astype(jnp.int8)
-    return segment_bin_agg_pallas(xs2, ys2, vs2, sid2, valid2, bboxes,
-                                  n_seg=n_seg, gx=gx, gy=gy,
-                                  interpret=interpret)
+        return _segment_window_agg_jnp(
+            xs, ys, vals, sids, np.asarray(window, np.float32), n, n_seg)
+    params = np.broadcast_to(_window_row(window), (n_seg, 4))
+    return _pallas(xs, ys, vals, sids, params, n, n_seg=n_seg,
+                   window=True, interpret=interpret)[:, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("n_seg", "gx", "gy"))
+def _segment_bin_agg_jnp(xs, ys, vals, sids, bboxes, n, n_seg, gx, gy):
+    valid = jnp.arange(xs.shape[0]) < n
+    return ref.segment_bin_agg_ref(xs, ys, vals, sids, bboxes, (gx, gy),
+                                   valid, n_seg)
 
 
 def segment_bin_agg(xs, ys, vals, boundaries, bboxes, *, gx, gy,
-                    backend=None, interpret=True):
+                    backend=None, interpret=None):
     """Per-segment, per-cell (count, sum, min, max): one packed call that
     splits every segment s of the concatenated stream by its own
     ``bboxes[s]`` into ``gx × gy`` cells — the multi-tile-split metadata
     kernel. Returns ``(S, gx*gy, 4)``; cell id = cy*gx + cx. Backend
-    semantics as in :func:`segment_window_agg`.
+    semantics as in :func:`segment_window_agg`; "pallas" assigns cells
+    by the f64 host rule (``ref.split_thresholds``).
     """
     backend = backend or default_backend()
     boundaries = np.asarray(boundaries, np.int64)
     if backend == "np":
         return ref.segment_bin_agg_np(xs, ys, vals, boundaries, bboxes,
                                       gx, gy)
-    n_seg = len(boundaries) - 1
-    n = int(boundaries[-1])
-    sids = np.repeat(np.arange(n_seg), np.diff(boundaries))
-    xs, ys, vals, sids = _bucket_pad(xs, ys, vals, sids, n=n)
-    return _segment_bin_agg_flat(
-        xs, ys, vals, sids, np.asarray(bboxes, np.float32),
-        int(n), n_seg, gx, gy, backend, interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("n_seg", "gx", "gy", "backend",
-                                             "interpret"))
-def _segment_bin_agg_edges_flat(xs, ys, vals, sids, x_edges, y_edges, n,
-                                n_seg, gx, gy, backend, interpret):
+    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                   boundaries)
     if backend == "jnp":
-        valid = jnp.arange(xs.shape[0]) < n
-        return ref.segment_bin_agg_edges_ref(xs, ys, vals, sids, x_edges,
-                                             y_edges, valid, n_seg)
-    xs2, ys2, vs2, sid2, valid2 = pack2d(xs, ys, vals, sids, n=xs.shape[0])
-    valid2 = valid2 * (jnp.arange(valid2.size).reshape(valid2.shape) <
-                       n).astype(jnp.int8)
-    return segment_bin_agg_edges_pallas(xs2, ys2, vs2, sid2, valid2,
-                                        x_edges, y_edges, n_seg=n_seg,
-                                        gx=gx, gy=gy, interpret=interpret)
+        return _segment_bin_agg_jnp(
+            xs, ys, vals, sids, np.asarray(bboxes, np.float32), n, n_seg,
+            gx, gy)
+    return _pallas(xs, ys, vals, sids, ref.split_thresholds(bboxes, gx, gy),
+                   n, n_seg=n_seg, window=False, gx=gx, gy=gy,
+                   interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("n_seg",))
+def _segment_bin_agg_edges_jnp(xs, ys, vals, sids, x_edges, y_edges, n,
+                               n_seg):
+    valid = jnp.arange(xs.shape[0]) < n
+    return ref.segment_bin_agg_edges_ref(xs, ys, vals, sids, x_edges,
+                                         y_edges, valid, n_seg)
 
 
 def segment_bin_agg_edges(xs, ys, vals, boundaries, x_edges, y_edges, *,
-                          backend=None, interpret=True):
+                          backend=None, interpret=None):
     """Per-segment, per-cell (count, sum, min, max) under per-segment
     SPLIT EDGES: one packed call that cuts every segment s of the
     concatenated stream along its own ``x_edges[s]`` (gx+1,) /
@@ -301,7 +341,8 @@ def segment_bin_agg_edges(xs, ys, vals, boundaries, x_edges, y_edges, *,
     gx×gy subdivision). Returns ``(S, gx*gy, 4)``; cell id = cy*gx + cx.
     Backend semantics as in :func:`segment_window_agg` ("np" ⇒ float64
     host mirror whose cell assignment matches
-    ``geometry.edge_cell_ids`` bit-for-bit).
+    ``geometry.edge_cell_ids`` bit-for-bit; "pallas" compares against
+    the edges rounded up to float32, the same assignment).
     """
     backend = backend or default_backend()
     boundaries = np.asarray(boundaries, np.int64)
@@ -310,36 +351,29 @@ def segment_bin_agg_edges(xs, ys, vals, boundaries, x_edges, y_edges, *,
     if backend == "np":
         return ref.segment_bin_agg_edges_np(xs, ys, vals, boundaries,
                                             x_edges, y_edges)
-    n_seg = len(boundaries) - 1
     gx = x_edges.shape[1] - 1
     gy = y_edges.shape[1] - 1
-    n = int(boundaries[-1])
-    sids = np.repeat(np.arange(n_seg), np.diff(boundaries))
-    xs, ys, vals, sids = _bucket_pad(xs, ys, vals, sids, n=n)
-    return _segment_bin_agg_edges_flat(
-        xs, ys, vals, sids, np.asarray(x_edges, np.float32),
-        np.asarray(y_edges, np.float32), int(n),
-        n_seg, gx, gy, backend, interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("n_seg", "bx", "by", "backend",
-                                             "interpret"))
-def _segment_window_bin_agg_flat(xs, ys, vals, sids, window, n, n_seg, bx,
-                                 by, backend, interpret):
+    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                   boundaries)
     if backend == "jnp":
-        valid = jnp.arange(xs.shape[0]) < n
-        return ref.segment_window_bin_agg_ref(xs, ys, vals, sids, window,
-                                              (bx, by), valid, n_seg)
-    xs2, ys2, vs2, sid2, valid2 = pack2d(xs, ys, vals, sids, n=xs.shape[0])
-    valid2 = valid2 * (jnp.arange(valid2.size).reshape(valid2.shape) <
-                       n).astype(jnp.int8)
-    return segment_window_bin_agg_pallas(xs2, ys2, vs2, sid2, valid2, window,
-                                         n_seg=n_seg, bx=bx, by=by,
-                                         interpret=interpret)
+        return _segment_bin_agg_edges_jnp(
+            xs, ys, vals, sids, np.asarray(x_edges, np.float32),
+            np.asarray(y_edges, np.float32), n, n_seg)
+    return _pallas(xs, ys, vals, sids,
+                   ref.edge_thresholds(x_edges, y_edges), n, n_seg=n_seg,
+                   window=False, gx=gx, gy=gy, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("n_seg", "bx", "by"))
+def _segment_window_bin_agg_jnp(xs, ys, vals, sids, window, n, n_seg, bx,
+                                by):
+    valid = jnp.arange(xs.shape[0]) < n
+    return ref.segment_window_bin_agg_ref(xs, ys, vals, sids, window,
+                                          (bx, by), valid, n_seg)
 
 
 def segment_window_bin_agg(xs, ys, vals, boundaries, window, *, bx, by,
-                           backend=None, interpret=True):
+                           backend=None, interpret=None):
     """Per-segment, per-window-bin (count, sum, min, max) — the heatmap
     primitive: one packed call bins every segment of the concatenated
     stream by the SAME ``bx × by`` grid over the (finite, closed) query
@@ -353,37 +387,42 @@ def segment_window_bin_agg(xs, ys, vals, boundaries, window, *, bx, by,
     if backend == "np":
         return ref.segment_window_bin_agg_np(xs, ys, vals, boundaries,
                                              window, bx, by)
-    n_seg = len(boundaries) - 1
-    n = int(boundaries[-1])
-    sids = np.repeat(np.arange(n_seg), np.diff(boundaries))
-    xs, ys, vals, sids = _bucket_pad(xs, ys, vals, sids, n=n)
-    return _segment_window_bin_agg_flat(
-        xs, ys, vals, sids, np.asarray(window, np.float32),
-        int(n), n_seg, bx, by, backend, interpret)
+    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                   boundaries)
+    if backend == "jnp":
+        return _segment_window_bin_agg_jnp(
+            xs, ys, vals, sids, np.asarray(window, np.float32), n, n_seg,
+            bx, by)
+    row = ref.window_bin_thresholds(np.asarray(window)[None], bx, by)
+    return _pallas(xs, ys, vals, sids,
+                   np.broadcast_to(row, (n_seg, row.shape[1])), n,
+                   n_seg=n_seg, window=True, gx=bx, gy=by,
+                   interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("n_seg", "bx", "by"))
+def _segment_window_bin_select_jnp(xs, ys, vals, sids, window, vmin_s,
+                                   vmax_s, n, n_seg, bx, by):
+    valid = jnp.arange(xs.shape[0]) < n
+    return fused_select.segment_window_bin_select_ref(
+        xs, ys, vals, sids, window, (bx, by), valid, n_seg, vmin_s, vmax_s)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_seg", "bx", "by", "backend",
-                                    "interpret", "seg_group"))
-def _segment_window_bin_select_flat(xs, ys, vals, sids, window, vmin_s,
-                                    vmax_s, n, n_seg, bx, by, backend,
-                                    interpret, seg_group=None):
-    if backend == "jnp":
-        valid = jnp.arange(xs.shape[0]) < n
-        return fused_select.segment_window_bin_select_ref(
-            xs, ys, vals, sids, window, (bx, by), valid, n_seg,
-            vmin_s, vmax_s)
-    xs2, ys2, vs2, sid2, valid2 = pack2d(xs, ys, vals, sids, n=xs.shape[0])
-    valid2 = valid2 * (jnp.arange(valid2.size).reshape(valid2.shape) <
-                       n).astype(jnp.int8)
+                   static_argnames=("n_seg", "bx", "by", "interpret",
+                                    "seg_group"))
+def _segment_window_bin_select_pallas(xs, ys, vals, sids, params, vmin_s,
+                                      vmax_s, n, n_seg, bx, by, interpret,
+                                      seg_group=None):
+    xs2, ys2, vs2, sid2, valid2 = _packed(xs, ys, vals, sids, n)
     return fused_select.segment_window_bin_select_pallas(
-        xs2, ys2, vs2, sid2, valid2, window, vmin_s, vmax_s, n_seg=n_seg,
+        xs2, ys2, vs2, sid2, valid2, params, vmin_s, vmax_s, n_seg=n_seg,
         bx=bx, by=by, seg_group=seg_group, interpret=interpret)
 
 
 def segment_window_bin_select(xs, ys, vals, boundaries, window, vmin_s,
                               vmax_s, *, bx, by, backend=None,
-                              interpret=True, seg_group=None):
+                              interpret=None, seg_group=None):
     """Fused heatmap-selection primitive: per-segment per-window-bin
     ``(count, sum, min, max)`` PLUS the selection-ready suffix widths, in
     one pass.
@@ -406,33 +445,32 @@ def segment_window_bin_select(xs, ys, vals, boundaries, window, vmin_s,
     if backend == "np":
         return fused_select.segment_window_bin_select_np(
             xs, ys, vals, boundaries, window, bx, by, vmin_s, vmax_s)
-    n_seg = len(boundaries) - 1
-    n = int(boundaries[-1])
-    sids = np.repeat(np.arange(n_seg), np.diff(boundaries))
-    xs, ys, vals, sids = _bucket_pad(xs, ys, vals, sids, n=n)
-    return _segment_window_bin_select_flat(
-        xs, ys, vals, sids, np.asarray(window, np.float32),
-        np.asarray(vmin_s, np.float32), np.asarray(vmax_s, np.float32),
-        int(n), n_seg, bx, by, backend, interpret, seg_group)
-
-
-@functools.partial(jax.jit, static_argnames=("n_seg", "backend", "interpret"))
-def _segment_window_agg_multi_flat(xs, ys, vals, sids, windows, n, n_seg,
-                                   backend, interpret):
+    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                   boundaries)
+    vmin_s = np.asarray(vmin_s, np.float32)
+    vmax_s = np.asarray(vmax_s, np.float32)
     if backend == "jnp":
-        valid = jnp.arange(xs.shape[0]) < n
-        return ref.segment_window_agg_multi_ref(xs, ys, vals, sids, windows,
-                                                valid, n_seg)
-    xs2, ys2, vs2, sid2, valid2 = pack2d(xs, ys, vals, sids, n=xs.shape[0])
-    valid2 = valid2 * (jnp.arange(valid2.size).reshape(valid2.shape) <
-                       n).astype(jnp.int8)
-    return segment_window_agg_multi_pallas(xs2, ys2, vs2, sid2, valid2,
-                                           windows, n_seg=n_seg,
-                                           interpret=interpret)
+        return _segment_window_bin_select_jnp(
+            xs, ys, vals, sids, np.asarray(window, np.float32), vmin_s,
+            vmax_s, n, n_seg, bx, by)
+    row = ref.window_bin_thresholds(np.asarray(window)[None], bx, by)
+    pad, (params, vmin_s, vmax_s) = _seg_bucket(
+        n_seg, np.broadcast_to(row, (n_seg, row.shape[1])), vmin_s, vmax_s)
+    agg, suffix_w = _segment_window_bin_select_pallas(
+        xs, ys, vals, sids, params, vmin_s, vmax_s, n, pad, bx, by,
+        resolve_interpret(interpret), seg_group)
+    return agg[:n_seg], suffix_w[:n_seg + 1]
+
+
+@functools.partial(jax.jit, static_argnames=("n_seg",))
+def _segment_window_agg_multi_jnp(xs, ys, vals, sids, windows, n, n_seg):
+    valid = jnp.arange(xs.shape[0]) < n
+    return ref.segment_window_agg_multi_ref(xs, ys, vals, sids, windows,
+                                            valid, n_seg)
 
 
 def segment_window_agg_multi(xs, ys, vals, boundaries, windows, *,
-                             backend=None, interpret=True):
+                             backend=None, interpret=None):
     """Per-segment (count, sum, min, max) where segment s is filtered by
     its OWN closed ``windows[s]`` — the multi-query serving primitive:
     the concatenated (query, tile) streams of one serving tick answer N
@@ -446,34 +484,27 @@ def segment_window_agg_multi(xs, ys, vals, boundaries, windows, *,
     if backend == "np":
         return ref.segment_window_agg_multi_np(xs, ys, vals, boundaries,
                                                windows)
-    n_seg = len(boundaries) - 1
-    n = int(boundaries[-1])
-    sids = np.repeat(np.arange(n_seg), np.diff(boundaries))
-    xs, ys, vals, sids = _bucket_pad(xs, ys, vals, sids, n=n)
-    return _segment_window_agg_multi_flat(
-        xs, ys, vals, sids, np.asarray(windows, np.float32),
-        int(n), n_seg, backend, interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("n_seg", "bx", "by", "backend",
-                                             "interpret"))
-def _segment_window_bin_agg_multi_flat(xs, ys, vals, sids, windows, n, n_seg,
-                                       bx, by, backend, interpret):
+    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                   boundaries)
+    windows = np.asarray(windows, np.float32).reshape(n_seg, 4)
     if backend == "jnp":
-        valid = jnp.arange(xs.shape[0]) < n
-        return ref.segment_window_bin_agg_multi_ref(xs, ys, vals, sids,
-                                                    windows, (bx, by), valid,
-                                                    n_seg)
-    xs2, ys2, vs2, sid2, valid2 = pack2d(xs, ys, vals, sids, n=xs.shape[0])
-    valid2 = valid2 * (jnp.arange(valid2.size).reshape(valid2.shape) <
-                       n).astype(jnp.int8)
-    return segment_window_bin_agg_multi_pallas(xs2, ys2, vs2, sid2, valid2,
-                                               windows, n_seg=n_seg, bx=bx,
-                                               by=by, interpret=interpret)
+        return _segment_window_agg_multi_jnp(xs, ys, vals, sids, windows,
+                                             n, n_seg)
+    return _pallas(xs, ys, vals, sids, windows, n, n_seg=n_seg,
+                   window=True, interpret=interpret)[:, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("n_seg", "bx", "by"))
+def _segment_window_bin_agg_multi_jnp(xs, ys, vals, sids, windows, n,
+                                      n_seg, bx, by):
+    valid = jnp.arange(xs.shape[0]) < n
+    return ref.segment_window_bin_agg_multi_ref(xs, ys, vals, sids,
+                                                windows, (bx, by), valid,
+                                                n_seg)
 
 
 def segment_window_bin_agg_multi(xs, ys, vals, boundaries, windows, *, bx,
-                                 by, backend=None, interpret=True):
+                                 by, backend=None, interpret=None):
     """Per-segment, per-bin (count, sum, min, max) where segment s is
     binned by the ``bx × by`` grid of its OWN window ``windows[s]`` — the
     multi-query heatmap serving primitive. All queries in the packed tick
@@ -486,30 +517,36 @@ def segment_window_bin_agg_multi(xs, ys, vals, boundaries, windows, *, bx,
     if backend == "np":
         return ref.segment_window_bin_agg_multi_np(xs, ys, vals, boundaries,
                                                    windows, bx, by)
-    n_seg = len(boundaries) - 1
-    n = int(boundaries[-1])
-    sids = np.repeat(np.arange(n_seg), np.diff(boundaries))
-    xs, ys, vals, sids = _bucket_pad(xs, ys, vals, sids, n=n)
-    return _segment_window_bin_agg_multi_flat(
-        xs, ys, vals, sids, np.asarray(windows, np.float32),
-        int(n), n_seg, bx, by, backend, interpret)
+    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                   boundaries)
+    if backend == "jnp":
+        return _segment_window_bin_agg_multi_jnp(
+            xs, ys, vals, sids, np.asarray(windows, np.float32), n, n_seg,
+            bx, by)
+    return _pallas(xs, ys, vals, sids,
+                   ref.window_bin_thresholds(windows, bx, by), n,
+                   n_seg=n_seg, window=True, gx=bx, gy=by,
+                   interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("n_seg", "bx", "by"))
+def _segment_window_bin_select_multi_jnp(xs, ys, vals, sids, params,
+                                         vmin_s, vmax_s, qend, n, n_seg,
+                                         bx, by):
+    valid = jnp.arange(xs.shape[0]) < n
+    return fused_select.segment_window_bin_select_multi_ref(
+        xs, ys, vals, sids, params, (bx, by), valid, n_seg, vmin_s, vmax_s,
+        qend)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_seg", "bx", "by", "backend",
-                                    "interpret", "seg_group"))
-def _segment_window_bin_select_multi_flat(xs, ys, vals, sids, params,
-                                          vmin_s, vmax_s, qend, n, n_seg,
-                                          bx, by, backend, interpret,
-                                          seg_group=None):
-    if backend == "jnp":
-        valid = jnp.arange(xs.shape[0]) < n
-        return fused_select.segment_window_bin_select_multi_ref(
-            xs, ys, vals, sids, params, (bx, by), valid, n_seg,
-            vmin_s, vmax_s, qend)
-    xs2, ys2, vs2, sid2, valid2 = pack2d(xs, ys, vals, sids, n=xs.shape[0])
-    valid2 = valid2 * (jnp.arange(valid2.size).reshape(valid2.shape) <
-                       n).astype(jnp.int8)
+                   static_argnames=("n_seg", "bx", "by", "interpret",
+                                    "seg_group"))
+def _segment_window_bin_select_multi_pallas(xs, ys, vals, sids, params,
+                                            vmin_s, vmax_s, qend, n,
+                                            n_seg, bx, by, interpret,
+                                            seg_group=None):
+    xs2, ys2, vs2, sid2, valid2 = _packed(xs, ys, vals, sids, n)
     return fused_select.segment_window_bin_select_multi_pallas(
         xs2, ys2, vs2, sid2, valid2, params, vmin_s, vmax_s, qend,
         n_seg=n_seg, bx=bx, by=by, seg_group=seg_group,
@@ -518,7 +555,7 @@ def _segment_window_bin_select_multi_flat(xs, ys, vals, sids, params,
 
 def segment_window_bin_select_multi(xs, ys, vals, boundaries, windows,
                                     vmin_s, vmax_s, qbounds=None, *, bx,
-                                    by, backend=None, interpret=True,
+                                    by, backend=None, interpret=None,
                                     seg_group=None):
     """Multi-window fused heatmap-selection primitive: per-segment
     OWN-window per-bin ``(count, sum, min, max)`` PLUS per-query-span
@@ -538,11 +575,11 @@ def segment_window_bin_select_multi(xs, ys, vals, boundaries, windows,
     :func:`segment_window_agg_multi`: "np" is the f64 host mirror whose
     ``agg`` is bit-for-bit ``segment_window_bin_agg_multi
     (backend="np")`` and whose span rows match the single-window
-    ``segment_window_bin_select(backend="np")``; device backends bin
-    via the precomputed axis-index contract params
-    (``ref.window_bin_params``) so counts/extrema stay bit-identical to
-    the host rule (f32 sums/suffixes allclose). ``seg_group`` forces
-    the megakernel's segments-per-program group.
+    ``segment_window_bin_select(backend="np")``; the device backends
+    bin by the host contract (``ref.window_bin_params`` for "jnp",
+    ``ref.window_bin_thresholds`` for "pallas") so counts/extrema stay
+    bit-identical to the host rule (f32 sums/suffixes allclose).
+    ``seg_group`` forces the megakernel's segments-per-program group.
     """
     backend = backend or default_backend()
     boundaries = np.asarray(boundaries, np.int64)
@@ -551,17 +588,24 @@ def segment_window_bin_select_multi(xs, ys, vals, boundaries, windows,
             xs, ys, vals, boundaries, windows, bx, by, vmin_s, vmax_s,
             qbounds)
     n_seg = len(boundaries) - 1
-    n = int(boundaries[-1])
     qb = (np.array([0, n_seg], np.int64) if qbounds is None
           else np.asarray(qbounds, np.int64))
     qend = np.repeat(qb[1:], np.diff(qb)).astype(np.int32)
-    params = ref.window_bin_params(windows, bx, by)
-    sids = np.repeat(np.arange(n_seg), np.diff(boundaries))
-    xs, ys, vals, sids = _bucket_pad(xs, ys, vals, sids, n=n)
-    return _segment_window_bin_select_multi_flat(
-        xs, ys, vals, sids, params,
-        np.asarray(vmin_s, np.float32), np.asarray(vmax_s, np.float32),
-        qend, int(n), n_seg, bx, by, backend, interpret, seg_group)
+    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                   boundaries)
+    vmin_s = np.asarray(vmin_s, np.float32)
+    vmax_s = np.asarray(vmax_s, np.float32)
+    if backend == "jnp":
+        return _segment_window_bin_select_multi_jnp(
+            xs, ys, vals, sids, ref.window_bin_params(windows, bx, by),
+            vmin_s, vmax_s, qend, n, n_seg, bx, by)
+    pad, (params, vmin_s, vmax_s) = _seg_bucket(
+        n_seg, ref.window_bin_thresholds(windows, bx, by), vmin_s, vmax_s)
+    qend = np.concatenate([qend, np.full(pad - n_seg, pad, np.int32)])
+    agg, suffix_w = _segment_window_bin_select_multi_pallas(
+        xs, ys, vals, sids, params, vmin_s, vmax_s, qend, n, pad, bx, by,
+        resolve_interpret(interpret), seg_group)
+    return agg[:n_seg], suffix_w[:n_seg]
 
 
 def window_count(xs, ys, window, *, n=None, backend=None):
@@ -582,4 +626,5 @@ __all__ = ["window_agg", "bin_agg", "segment_window_agg", "segment_bin_agg",
            "segment_window_bin_select",
            "segment_window_agg_multi", "segment_window_bin_agg_multi",
            "segment_window_bin_select_multi",
-           "window_count", "window_mask_np", "pack2d", "default_backend"]
+           "window_count", "window_mask_np", "pack2d", "default_backend",
+           "index_backend", "resolve_interpret"]

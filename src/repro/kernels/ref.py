@@ -555,6 +555,92 @@ def window_bin_params(windows, bx, by):
     return out
 
 
+def _f32_key(f):
+    """float32 → int64 key, monotone in the float order (±0 → 0)."""
+    i = np.asarray(f, np.float32).view(np.int32).astype(np.int64)
+    return np.where(i < 0, -(i & 0x7FFFFFFF), i)
+
+
+def _f32_from_key(k):
+    k = np.asarray(k, np.int64)
+    bits = np.where(k < 0, (-k) | 0x80000000, k).astype(np.uint32)
+    return bits.view(np.float32)
+
+
+def f32_thresholds(pred, shape):
+    """Smallest float32 ``t`` (per element of ``shape``) with
+    ``pred(t)`` true, for a predicate monotone in float32 ``x`` —
+    bisection over the ordered float32 bit patterns from -inf to +inf
+    (33 vectorized evaluations, exact whatever the arithmetic inside
+    ``pred``)."""
+    lo = np.full(shape, _f32_key(np.float32(-np.inf)) - 1, np.int64)
+    hi = np.full(shape, _f32_key(np.float32(np.inf)), np.int64)
+    while (hi - lo > 1).any():
+        mid = (lo + hi) // 2
+        p = np.asarray(pred(_f32_from_key(mid)), bool)
+        hi = np.where(p, mid, hi)
+        lo = np.where(p, lo, mid)
+    return _f32_from_key(hi)
+
+
+def _axis_thresholds(x0, cw, g, dtype):
+    """Interior cell thresholds ``(S, g−1)`` of the rule
+    ``clip(floor((x − x0) / cw), 0, g−1)`` evaluated in ``dtype``: cell
+    ``cx = Σ_j 1[x ≥ t_j]`` for every float32 ``x``."""
+    j = np.arange(1, g, dtype=np.float64)[None, :]
+    x0 = np.asarray(x0, dtype)[:, None]
+    cw = np.asarray(cw, dtype)[:, None]
+    shape = (x0.shape[0], g - 1)
+
+    def pred(t):
+        with np.errstate(invalid="ignore", over="ignore"):
+            return np.floor((t.astype(dtype) - x0) / cw) >= j
+    return f32_thresholds(pred, shape)
+
+
+def window_bin_thresholds(windows, bx, by):
+    """THE device binning contract for heatmap windows: float32
+    ``(S, 4 + (bx−1) + (by−1))`` rows ``(x0, y0, x1, y1, tx…, ty…)``.
+
+    The window corners are the float32 values the host mask compares
+    against; ``tx_j``/``ty_j`` are the smallest float32 coordinates that
+    :func:`window_bin_ids_np` puts in bin column / row ≥ j (its f32
+    ``floor((x − x0) / cw)`` with ``cw`` derived in f64 first). A
+    kernel that bins by ``Σ_j 1[x ≥ t_j]`` needs no division, so its
+    bin ids are BIT-IDENTICAL to the host rule on any hardware — a
+    TPU's vector divide is not correctly rounded, an f32 compare is."""
+    windows = np.asarray(windows, np.float64).reshape(-1, 4)
+    cw = np.maximum((windows[:, 2] - windows[:, 0]) / bx, 1e-30)
+    ch = np.maximum((windows[:, 3] - windows[:, 1]) / by, 1e-30)
+    return np.concatenate([
+        windows.astype(np.float32),
+        _axis_thresholds(windows[:, 0], cw, bx, np.float32),
+        _axis_thresholds(windows[:, 1], ch, by, np.float32)], axis=1)
+
+
+def split_thresholds(bboxes, gx, gy):
+    """Device form of the even-split ownership rule of
+    :func:`segment_bin_agg_np` (f64 ``floor((x − b0) / cw)`` per
+    bbox): float32 ``(S, (gx−1) + (gy−1))`` rows ``(tx…, ty…)``."""
+    bboxes = np.asarray(bboxes, np.float64).reshape(-1, 4)
+    cw = np.maximum((bboxes[:, 2] - bboxes[:, 0]) / gx, 1e-30)
+    ch = np.maximum((bboxes[:, 3] - bboxes[:, 1]) / gy, 1e-30)
+    return np.concatenate([
+        _axis_thresholds(bboxes[:, 0], cw, gx, np.float64),
+        _axis_thresholds(bboxes[:, 1], ch, gy, np.float64)], axis=1)
+
+
+def edge_thresholds(x_edges, y_edges):
+    """Device form of :func:`edge_cell_ids_np`: the interior split
+    edges rounded UP to float32 (``x ≥ e`` in f64 ⇔ ``x ≥ ceil32(e)``
+    for float32 ``x``). Returns ``(S, (gx−1) + (gy−1))`` float32."""
+    inner = np.concatenate([np.asarray(x_edges, np.float64)[:, 1:-1],
+                            np.asarray(y_edges, np.float64)[:, 1:-1]],
+                           axis=1)
+    return f32_thresholds(lambda t: t.astype(np.float64) >= inner,
+                          inner.shape)
+
+
 def segment_window_bin_agg_np(xs, ys, vals, boundaries, window, bx, by):
     """Per-contiguous-segment, per-window-bin aggregates (f64 ``(S,K,4)``).
 

@@ -1,48 +1,35 @@
-"""Pallas TPU kernels: packed multi-segment aggregation (batched adaptation).
+"""Pallas TPU kernel: packed multi-segment aggregation — the one kernel.
 
-The batched refinement pipeline gathers the object segments of the top-k
-pending tiles of a refinement round into ONE concatenated stream and needs,
-in a single kernel invocation,
+Every device data-plane op of :mod:`repro.kernels.ops` reduces
+``(count, sum, min, max)`` of the aggregate attribute per (segment,
+cell) over ONE concatenated object stream:
 
-- per-segment ``(count, sum, min, max)`` of the aggregate attribute for the
-  objects inside the query window (``segment_window_agg_pallas``) — the
-  exact in-window contribution of every tile in the batch; and
-- per-segment, per-cell aggregates over each tile's own ``gx × gy`` split
-  (``segment_bin_agg_pallas``) — the child metadata of every tile split in
-  the batch; or, when splits are bin-aligned, over each tile's own
-  explicit split-edge arrays (``segment_bin_agg_edges_pallas`` — cell ids
-  are a static unroll of ``Σ_i 1[x ≥ edge_i]`` compares instead of the
-  uniform floor-divide, so split lines can snap to a heatmap grid); and
-- per-segment, per-cell aggregates over ONE shared ``bx × by`` grid laid
-  over the query window, in-window objects only
-  (``segment_window_bin_agg_pallas``) — every tile's exact per-bin heatmap
-  contribution for a refinement round. All four output channels are
-  consumed: count/sum drive the sum/mean heatmap fold, and the per-cell
-  min/max channels are the *grouped extrema* state behind the min/max
-  heatmap aggregates (single-host fold; ``core.distributed`` mirrors the
-  same state in-SPMD with a per-(tile, bin) scatter merged by
-  pmin/pmax).
+- window ops (``segment_window_agg[_multi]``, ``window_agg``): one cell
+  per segment, objects inside the segment's closed window — the exact
+  in-window contribution of every tile of a refinement round;
+- heatmap ops (``segment_window_bin_agg[_multi]`` and the fused
+  selects of :mod:`repro.kernels.fused_select`): the ``bx × by`` grid
+  laid over the segment's window, in-window objects only;
+- split ops (``segment_bin_agg[_edges]``, ``bin_agg``): the segment's
+  own ``gx × gy`` child cells — the metadata of every tile split in a
+  round.
 
-All reuse the ``pack2d`` block layout of :mod:`repro.kernels.window_agg`
-(flat object arrays padded to ``(rows, 128)`` f32 planes + validity plane)
-and add one more plane: the *segment id* of each object (f32; ids are
-small integers, exactly representable). Segments are contiguous in the
-stream, so on TPU this is still one fully sequential HBM→VMEM stream; the
-per-segment masks are VREG compares against the resident sid plane, i.e.
-batching k tiles multiplies arithmetic intensity by k with no extra bytes
-moved — the same trick :mod:`repro.kernels.bin_agg` plays with cells.
+They differ only in the per-segment row of scalars the kernel reads
+from SMEM: ``(x0, y0, x1, y1)`` when a window masks the segment, then
+``gx−1`` x-thresholds and ``gy−1`` y-thresholds. Cell ids are
+``Σ_j 1[x ≥ tx_j]`` (and likewise in y): compares only, no division,
+so they are bit-identical to the f64 host mirrors' ownership rules on
+any hardware (the thresholds come from ``ref.window_bin_thresholds``,
+``ref.split_thresholds`` and ``ref.edge_thresholds``).
 
-Grid: a real 2-D ``(cell_groups, row_blocks)`` launch planned by
-:mod:`repro.kernels.gridplan`. The outer axis walks groups of segments
-whose ``group · k`` unroll fits the per-program budget; the minor axis
-streams row blocks with the group's ``(1, group·k, 4)`` output block
-mapped to the SAME location every step — ``@pl.when(r == 0)`` init +
-read-modify-write accumulation keeps it VMEM-resident, so there is no
-``(grid, S·K, 4)`` partial slab and no host-side reduction (the 1-D-grid
-ancestors of these kernels paid both). Per-segment parameter arrays
-(windows / bboxes / edges) are padded to ``group · n_groups`` rows and
-block-sliced per group; padded segments match no object's sid and are
-sliced off the result.
+Layout: the ``pack2d`` planes of :mod:`repro.kernels.ops` — flat object
+arrays padded to ``(rows, 128)`` f32 planes plus an int8 validity
+plane, and one more f32 plane holding each object's *segment id*
+(small integers, exactly representable). Segments are contiguous in
+the stream, so on TPU this is one fully sequential HBM→VMEM stream;
+the per-segment masks are VREG compares against the resident sid
+plane. The grid, the planner and the lane-dense accumulator are in
+:mod:`repro.kernels.gridplan`.
 """
 from __future__ import annotations
 
@@ -52,485 +39,106 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .gridplan import (LANES, DEFAULT_BLOCK_ROWS, MAX_UNROLL,  # noqa: F401
-                       plan_cell_groups)
+from .gridplan import (  # noqa: F401 — LANES/MAX_GROUP_CELLS re-exported
+    LANES, DEFAULT_BLOCK_ROWS, MAX_GROUP_CELLS, acc_cell, acc_finalize,
+    acc_init, acc_out, pad_flat, plan_cell_groups, plane_specs, smem_spec)
 
 MAX_SEGMENTS = 64
 
-def _acc_init(out_ref):
-    """Write the (count, sum, min, max) neutral element to the whole
-    resident output block — run under ``@pl.when(r == 0)``. Channel-wise
-    scalar broadcasts: a stacked ``[0, 0, +inf, -inf]`` constant would be
-    a captured array, which pallas kernels reject."""
-    shp = out_ref.shape[:-1]
-    out_ref[:, :, 0] = jnp.zeros(shp, jnp.float32)
-    out_ref[:, :, 1] = jnp.zeros(shp, jnp.float32)
-    out_ref[:, :, 2] = jnp.full(shp, jnp.inf, jnp.float32)
-    out_ref[:, :, 3] = jnp.full(shp, -jnp.inf, jnp.float32)
 
+def _make_kernel(group: int, ncol: int, window: bool, gx: int, gy: int,
+                 with_sid: bool):
+    k = gx * gy
 
-def _acc_cell(out_ref, i: int, m, vs):
-    """Read-modify-write one (segment, cell) row of the resident block
-    with the masked reductions of the current row block."""
-    out_ref[0, i, 0] = out_ref[0, i, 0] + jnp.sum(m.astype(jnp.float32))
-    out_ref[0, i, 1] = out_ref[0, i, 1] + jnp.sum(jnp.where(m, vs, 0.0))
-    out_ref[0, i, 2] = jnp.minimum(out_ref[0, i, 2],
-                                   jnp.min(jnp.where(m, vs, jnp.inf)))
-    out_ref[0, i, 3] = jnp.maximum(out_ref[0, i, 3],
-                                   jnp.max(jnp.where(m, vs, -jnp.inf)))
-
-
-def _pad_rows(a, n_pad: int):
-    """Zero-pad a per-segment parameter array to ``n_pad`` rows (padded
-    segments are never matched by any object's sid)."""
-    n = a.shape[0]
-    if n == n_pad:
-        return a
-    pad = jnp.zeros((n_pad - n,) + a.shape[1:], a.dtype)
-    return jnp.concatenate([a, pad])
-
-
-def _plane_specs(block_rows: int, n: int = 5):
-    """BlockSpecs of the streamed object planes (x, y, v, sid, valid):
-    row-block r of the minor axis, re-streamed for every group g."""
-    return [pl.BlockSpec((block_rows, LANES), lambda g, r: (r, 0))
-            for _ in range(n)]
-
-
-def _make_segment_window_agg_kernel(group: int):
-    def kernel(win_ref, x_ref, y_ref, v_ref, sid_ref, valid_ref, out_ref):
-        g = pl.program_id(0)
+    def kernel(par_ref, *refs):
+        if with_sid:
+            x_ref, y_ref, v_ref, sid_ref, valid_ref, out_ref = refs
+        else:
+            x_ref, y_ref, v_ref, valid_ref, out_ref = refs
+        g = pl.program_id(0)    # cell group (outer)
 
         @pl.when(pl.program_id(1) == 0)
         def _init():
-            _acc_init(out_ref)
+            acc_init(out_ref)
 
-        x0 = win_ref[0, 0]
-        y0 = win_ref[0, 1]
-        x1 = win_ref[0, 2]
-        y1 = win_ref[0, 3]
-        xs = x_ref[...]
-        ys = y_ref[...]
-        vs = v_ref[...]
-        sid = sid_ref[...]
-        valid = valid_ref[...] != 0
-        inw = (xs >= x0) & (xs <= x1) & (ys >= y0) & (ys <= y1) & valid
-        for t in range(group):  # static unroll: per-segment masked reductions
-            s_glob = (g * group + t).astype(jnp.float32)
-            m = inw & (sid == s_glob)
-            _acc_cell(out_ref, t, m, vs)
+        def segment(t, carry):
+            s = g * group + t
+            base = s * ncol
+            xs = x_ref[...]
+            ys = y_ref[...]
+            vs = v_ref[...]
+            m = valid_ref[...] != 0
+            if with_sid:
+                m = m & (sid_ref[...] == s.astype(jnp.float32))
+            col = 0
+            if window:
+                m = (m & (xs >= par_ref[base]) & (ys >= par_ref[base + 1])
+                     & (xs <= par_ref[base + 2]) & (ys <= par_ref[base + 3]))
+                col = 4
+            if k == 1:
+                acc_cell(out_ref, t, m, vs)
+                return carry
+            cx = jnp.zeros(xs.shape, jnp.int32)
+            for j in range(gx - 1):
+                cx = cx + (xs >= par_ref[base + col + j]).astype(jnp.int32)
+            cy = jnp.zeros(ys.shape, jnp.int32)
+            for j in range(gy - 1):
+                cy = cy + (ys >= par_ref[base + col + gx - 1 + j]
+                           ).astype(jnp.int32)
+            cid = cy * gx + cx
+
+            def cell(c, carry2):
+                acc_cell(out_ref, t * k + c, m & (cid == c), vs)
+                return carry2
+            jax.lax.fori_loop(0, k, cell, 0)
+            return carry
+
+        jax.lax.fori_loop(0, group, segment, 0)
     return kernel
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_seg", "block_rows", "seg_group",
-                                    "interpret"))
-def segment_window_agg_pallas(xs2d, ys2d, vals2d, sid2d, valid2d, window,
-                              *, n_seg, block_rows=DEFAULT_BLOCK_ROWS,
-                              seg_group=None, interpret=True):
-    """Per-segment window aggregation over 2-D laid-out object arrays.
+                   static_argnames=("n_seg", "window", "gx", "gy",
+                                    "block_rows", "seg_group", "interpret"))
+def grouped_agg_pallas(params, xs2d, ys2d, vals2d, sid2d, valid2d, *,
+                       n_seg, window, gx=1, gy=1,
+                       block_rows=DEFAULT_BLOCK_ROWS, seg_group=None,
+                       interpret=False):
+    """Per-(segment, cell) ``(count, sum, min, max)`` in one kernel.
 
     Args:
-      xs2d/ys2d/vals2d/sid2d: float32 ``(R, 128)`` planes (R a multiple of
-        block_rows); sid2d holds each object's segment id in [0, n_seg).
+      params: f32 ``(n_seg, ncol)`` per-segment scalar rows: the window
+        ``(x0, y0, x1, y1)`` when ``window``, then ``gx−1`` x- and
+        ``gy−1`` y-thresholds (see the module docstring).
+      xs2d/ys2d/vals2d/sid2d: f32 ``(R, 128)`` planes (R a multiple of
+        ``block_rows``); ``sid2d`` may be None for a single segment.
       valid2d: int8/bool ``(R, 128)``.
-      window: float32 ``(4,)`` closed rectangle (±inf edges allowed — an
-        all-covering window yields full-segment aggregates).
-      seg_group: force the segments-per-program group size (tests use it
-        to exercise the multi-group outer axis at small shapes).
+      seg_group: force the segments-per-program group size (tests use
+        it to exercise the multi-group outer axis at small shapes).
     Returns:
-      float32 ``(n_seg, 4)`` = per-segment (count, sum, min, max);
-      empty selection ⇒ (0, 0, +inf, -inf).
+      f32 ``(n_seg, gx·gy, 4)``; cell id = cy·gx + cx; empty cell ⇒
+      ``(0, 0, +inf, −inf)``.
     """
-    assert n_seg <= MAX_SEGMENTS, n_seg
-    rows = xs2d.shape[0]
-    assert rows % block_rows == 0, (rows, block_rows)
-    group, n_groups, n_pad = plan_cell_groups(n_seg, 1,
-                                              block_rows=block_rows,
-                                              group=seg_group)
-    win2d = window.reshape(1, 4).astype(jnp.float32)
-    valid2d = valid2d.astype(jnp.int8)
-
-    out = pl.pallas_call(
-        _make_segment_window_agg_kernel(group),
-        grid=(n_groups, rows // block_rows),
-        in_specs=[pl.BlockSpec((1, 4), lambda g, r: (0, 0))]  # window
-        + _plane_specs(block_rows),
-        out_specs=pl.BlockSpec((1, group, 4), lambda g, r: (g, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_groups, group, 4), jnp.float32),
-        interpret=interpret,
-    )(win2d, xs2d.astype(jnp.float32), ys2d.astype(jnp.float32),
-      vals2d.astype(jnp.float32), sid2d.astype(jnp.float32), valid2d)
-
-    return out.reshape(n_pad, 4)[:n_seg]
-
-
-def _make_segment_window_agg_multi_kernel(group: int):
-    def kernel(win_ref, x_ref, y_ref, v_ref, sid_ref, valid_ref, out_ref):
-        g = pl.program_id(0)
-
-        @pl.when(pl.program_id(1) == 0)
-        def _init():
-            _acc_init(out_ref)
-
-        xs = x_ref[...]
-        ys = y_ref[...]
-        vs = v_ref[...]
-        sid = sid_ref[...]
-        valid = valid_ref[...] != 0
-        for t in range(group):  # static unroll: segment t has its OWN
-            # window (the multi-query serving pass) — per-segment VREG
-            # compares against the resident planes, no extra bytes moved
-            x0 = win_ref[t, 0]
-            y0 = win_ref[t, 1]
-            x1 = win_ref[t, 2]
-            y1 = win_ref[t, 3]
-            s_glob = (g * group + t).astype(jnp.float32)
-            m = ((xs >= x0) & (xs <= x1) & (ys >= y0) & (ys <= y1)
-                 & valid & (sid == s_glob))
-            _acc_cell(out_ref, t, m, vs)
-    return kernel
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_seg", "block_rows", "seg_group",
-                                    "interpret"))
-def segment_window_agg_multi_pallas(xs2d, ys2d, vals2d, sid2d, valid2d,
-                                    windows, *, n_seg,
-                                    block_rows=DEFAULT_BLOCK_ROWS,
-                                    seg_group=None, interpret=True):
-    """Per-segment window aggregation with PER-SEGMENT windows.
-
-    The multi-session serving primitive: one packed pass over the union
-    stream of a scheduler tick, where segment s is one (query, tile)
-    stream selected against that query's own viewport ``windows[s]``
-    (float32 ``(n_seg, 4)``, ±inf edges allowed). Other args mirror
-    :func:`segment_window_agg_pallas`. Returns float32 ``(n_seg, 4)``.
-    """
-    assert n_seg <= MAX_SEGMENTS, n_seg
-    rows = xs2d.shape[0]
-    assert rows % block_rows == 0, (rows, block_rows)
-    group, n_groups, n_pad = plan_cell_groups(n_seg, 1,
-                                              block_rows=block_rows,
-                                              group=seg_group)
-    win2d = _pad_rows(windows.reshape(n_seg, 4).astype(jnp.float32), n_pad)
-    valid2d = valid2d.astype(jnp.int8)
-
-    out = pl.pallas_call(
-        _make_segment_window_agg_multi_kernel(group),
-        grid=(n_groups, rows // block_rows),
-        in_specs=[pl.BlockSpec((group, 4), lambda g, r: (g, 0))]  # windows
-        + _plane_specs(block_rows),
-        out_specs=pl.BlockSpec((1, group, 4), lambda g, r: (g, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_groups, group, 4), jnp.float32),
-        interpret=interpret,
-    )(win2d, xs2d.astype(jnp.float32), ys2d.astype(jnp.float32),
-      vals2d.astype(jnp.float32), sid2d.astype(jnp.float32), valid2d)
-
-    return out.reshape(n_pad, 4)[:n_seg]
-
-
-def _make_segment_window_bin_agg_kernel(group: int, bx: int, by: int):
-    k = bx * by
-
-    def kernel(win_ref, x_ref, y_ref, v_ref, sid_ref, valid_ref, out_ref):
-        g = pl.program_id(0)
-
-        @pl.when(pl.program_id(1) == 0)
-        def _init():
-            _acc_init(out_ref)
-
-        x0 = win_ref[0, 0]
-        y0 = win_ref[0, 1]
-        x1 = win_ref[0, 2]
-        y1 = win_ref[0, 3]
-        xs = x_ref[...]
-        ys = y_ref[...]
-        vs = v_ref[...]
-        sid = sid_ref[...]
-        valid = valid_ref[...] != 0
-        inw = (xs >= x0) & (xs <= x1) & (ys >= y0) & (ys <= y1) & valid
-        # ONE shared bin grid over the window (unlike segment_bin_agg's
-        # per-segment bboxes): bin ids are computed once, outside the
-        # segment unroll
-        cw = jnp.maximum((x1 - x0) / bx, 1e-30)
-        ch = jnp.maximum((y1 - y0) / by, 1e-30)
-        cx = jnp.clip(jnp.floor((xs - x0) / cw).astype(jnp.int32), 0, bx - 1)
-        cy = jnp.clip(jnp.floor((ys - y0) / ch).astype(jnp.int32), 0, by - 1)
-        cid = cy * bx + cx
-        for t in range(group):  # static unroll over the group's segments…
-            s_glob = (g * group + t).astype(jnp.float32)
-            ms = inw & (sid == s_glob)
-            for c in range(k):  # …and window bins: group·K masked reductions
-                _acc_cell(out_ref, t * k + c, ms & (cid == c), vs)
-    return kernel
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_seg", "bx", "by", "block_rows",
-                                    "seg_group", "interpret"))
-def segment_window_bin_agg_pallas(xs2d, ys2d, vals2d, sid2d, valid2d,
-                                  window, *, n_seg, bx, by,
-                                  block_rows=DEFAULT_BLOCK_ROWS,
-                                  seg_group=None, interpret=True):
-    """Per-segment, per-window-bin aggregation — the heatmap primitive.
-
-    One invocation gives, for every segment (= tile) of a refinement
-    batch, the ``(count, sum, min, max)`` of its in-window objects in
-    every cell of the ``bx × by`` grid laid over the (finite, closed)
-    query window. Args mirror :func:`segment_window_agg_pallas`.
-    Returns float32 ``(n_seg, bx*by, 4)``; bin id = by_row*bx + bx_col;
-    empty selection ⇒ (0, 0, +inf, -inf).
-    """
-    k = bx * by
-    assert n_seg <= MAX_SEGMENTS, n_seg
+    k = gx * gy
+    assert n_seg <= MAX_SEGMENTS or sid2d is None, n_seg
+    assert sid2d is not None or n_seg == 1, n_seg
     rows = xs2d.shape[0]
     assert rows % block_rows == 0, (rows, block_rows)
     group, n_groups, n_pad = plan_cell_groups(n_seg, k,
                                               block_rows=block_rows,
                                               group=seg_group)
-    win2d = window.reshape(1, 4).astype(jnp.float32)
-    valid2d = valid2d.astype(jnp.int8)
-
+    ncol = params.reshape(n_seg, -1).shape[1]
+    # padded segments are never matched by any object's sid
+    par = pad_flat(params, n_seg, n_pad)
+    planes = [xs2d, ys2d, vals2d] + ([] if sid2d is None else [sid2d])
+    planes = [p.astype(jnp.float32) for p in planes]
+    out_spec, out_shape = acc_out(n_groups, group * k)
     out = pl.pallas_call(
-        _make_segment_window_bin_agg_kernel(group, bx, by),
+        _make_kernel(group, ncol, window, gx, gy, sid2d is not None),
         grid=(n_groups, rows // block_rows),
-        in_specs=[pl.BlockSpec((1, 4), lambda g, r: (0, 0))]  # window
-        + _plane_specs(block_rows),
-        out_specs=pl.BlockSpec((1, group * k, 4), lambda g, r: (g, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_groups, group * k, 4),
-                                       jnp.float32),
+        in_specs=[smem_spec()] + plane_specs(block_rows, len(planes) + 1),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(win2d, xs2d.astype(jnp.float32), ys2d.astype(jnp.float32),
-      vals2d.astype(jnp.float32), sid2d.astype(jnp.float32), valid2d)
-
-    return out.reshape(n_pad, k, 4)[:n_seg]
-
-
-def _make_segment_window_bin_agg_multi_kernel(group: int, bx: int, by: int):
-    k = bx * by
-
-    def kernel(win_ref, x_ref, y_ref, v_ref, sid_ref, valid_ref, out_ref):
-        g = pl.program_id(0)
-
-        @pl.when(pl.program_id(1) == 0)
-        def _init():
-            _acc_init(out_ref)
-
-        xs = x_ref[...]
-        ys = y_ref[...]
-        vs = v_ref[...]
-        sid = sid_ref[...]
-        valid = valid_ref[...] != 0
-        for t in range(group):  # static unroll over segments: each has
-            # its OWN window AND the bx×by grid laid over it
-            x0 = win_ref[t, 0]
-            y0 = win_ref[t, 1]
-            x1 = win_ref[t, 2]
-            y1 = win_ref[t, 3]
-            inw = (xs >= x0) & (xs <= x1) & (ys >= y0) & (ys <= y1) & valid
-            cw = jnp.maximum((x1 - x0) / bx, 1e-30)
-            ch = jnp.maximum((y1 - y0) / by, 1e-30)
-            cx = jnp.clip(jnp.floor((xs - x0) / cw).astype(jnp.int32),
-                          0, bx - 1)
-            cy = jnp.clip(jnp.floor((ys - y0) / ch).astype(jnp.int32),
-                          0, by - 1)
-            cid = cy * bx + cx
-            s_glob = (g * group + t).astype(jnp.float32)
-            ms = inw & (sid == s_glob)
-            for c in range(k):  # …and window bins: group·K masked reductions
-                _acc_cell(out_ref, t * k + c, ms & (cid == c), vs)
-    return kernel
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_seg", "bx", "by", "block_rows",
-                                    "seg_group", "interpret"))
-def segment_window_bin_agg_multi_pallas(xs2d, ys2d, vals2d, sid2d, valid2d,
-                                        windows, *, n_seg, bx, by,
-                                        block_rows=DEFAULT_BLOCK_ROWS,
-                                        seg_group=None, interpret=True):
-    """Per-segment, per-bin aggregation with PER-SEGMENT windows.
-
-    The multi-session heatmap serving primitive: segment s is binned by
-    the ``bx × by`` grid of its own window ``windows[s]`` (one shared
-    bin shape per call — the scheduler groups same-shape heatmap
-    queries into a pass). Args mirror
-    :func:`segment_window_bin_agg_pallas` with ``windows`` float32
-    ``(n_seg, 4)``. Returns float32 ``(n_seg, bx*by, 4)``.
-    """
-    k = bx * by
-    assert n_seg <= MAX_SEGMENTS, n_seg
-    rows = xs2d.shape[0]
-    assert rows % block_rows == 0, (rows, block_rows)
-    group, n_groups, n_pad = plan_cell_groups(n_seg, k,
-                                              block_rows=block_rows,
-                                              group=seg_group)
-    win2d = _pad_rows(windows.reshape(n_seg, 4).astype(jnp.float32), n_pad)
-    valid2d = valid2d.astype(jnp.int8)
-
-    out = pl.pallas_call(
-        _make_segment_window_bin_agg_multi_kernel(group, bx, by),
-        grid=(n_groups, rows // block_rows),
-        in_specs=[pl.BlockSpec((group, 4), lambda g, r: (g, 0))]  # windows
-        + _plane_specs(block_rows),
-        out_specs=pl.BlockSpec((1, group * k, 4), lambda g, r: (g, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_groups, group * k, 4),
-                                       jnp.float32),
-        interpret=interpret,
-    )(win2d, xs2d.astype(jnp.float32), ys2d.astype(jnp.float32),
-      vals2d.astype(jnp.float32), sid2d.astype(jnp.float32), valid2d)
-
-    return out.reshape(n_pad, k, 4)[:n_seg]
-
-
-def _make_segment_bin_agg_edges_kernel(group: int, gx: int, gy: int):
-    k = gx * gy
-
-    def kernel(xe_ref, ye_ref, x_ref, y_ref, v_ref, sid_ref, valid_ref,
-               out_ref):
-        g = pl.program_id(0)
-
-        @pl.when(pl.program_id(1) == 0)
-        def _init():
-            _acc_init(out_ref)
-
-        xs = x_ref[...]
-        ys = y_ref[...]
-        vs = v_ref[...]
-        sid = sid_ref[...]
-        valid = valid_ref[...] != 0
-        for t in range(group):  # static unroll over segments…
-            # ownership under explicit edges: child i owns
-            # [edge_i, edge_{i+1}); outer overflow clamps into the
-            # boundary cells — same rule as geometry.edge_cell_ids
-            cx = jnp.zeros_like(xs, jnp.int32)
-            for i in range(1, gx):
-                cx = cx + (xs >= xe_ref[t, i]).astype(jnp.int32)
-            cy = jnp.zeros_like(ys, jnp.int32)
-            for i in range(1, gy):
-                cy = cy + (ys >= ye_ref[t, i]).astype(jnp.int32)
-            cid = cy * gx + cx
-            s_glob = (g * group + t).astype(jnp.float32)
-            ms = valid & (sid == s_glob)
-            for c in range(k):  # …and cells: group·K masked reductions
-                _acc_cell(out_ref, t * k + c, ms & (cid == c), vs)
-    return kernel
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_seg", "gx", "gy", "block_rows",
-                                    "seg_group", "interpret"))
-def segment_bin_agg_edges_pallas(xs2d, ys2d, vals2d, sid2d, valid2d,
-                                 x_edges, y_edges, *, n_seg, gx, gy,
-                                 block_rows=DEFAULT_BLOCK_ROWS,
-                                 seg_group=None, interpret=True):
-    """Per-segment, per-cell aggregation along explicit split edges.
-
-    Like :func:`segment_bin_agg_pallas`, but segment s is cut along its
-    own ``x_edges[s]`` (gx+1,) / ``y_edges[s]`` (gy+1,) instead of the
-    even grid of a bbox — the bin-aligned-split metadata kernel. Returns
-    float32 ``(n_seg, gx*gy, 4)``; cell id = cy*gx + cx.
-    """
-    k = gx * gy
-    assert n_seg <= MAX_SEGMENTS, n_seg
-    rows = xs2d.shape[0]
-    assert rows % block_rows == 0, (rows, block_rows)
-    group, n_groups, n_pad = plan_cell_groups(n_seg, k,
-                                              block_rows=block_rows,
-                                              group=seg_group)
-    xe2d = _pad_rows(x_edges.reshape(n_seg, gx + 1).astype(jnp.float32),
-                     n_pad)
-    ye2d = _pad_rows(y_edges.reshape(n_seg, gy + 1).astype(jnp.float32),
-                     n_pad)
-    valid2d = valid2d.astype(jnp.int8)
-
-    out = pl.pallas_call(
-        _make_segment_bin_agg_edges_kernel(group, gx, gy),
-        grid=(n_groups, rows // block_rows),
-        in_specs=[
-            pl.BlockSpec((group, gx + 1), lambda g, r: (g, 0)),  # x edges
-            pl.BlockSpec((group, gy + 1), lambda g, r: (g, 0)),  # y edges
-        ] + _plane_specs(block_rows),
-        out_specs=pl.BlockSpec((1, group * k, 4), lambda g, r: (g, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_groups, group * k, 4),
-                                       jnp.float32),
-        interpret=interpret,
-    )(xe2d, ye2d, xs2d.astype(jnp.float32), ys2d.astype(jnp.float32),
-      vals2d.astype(jnp.float32), sid2d.astype(jnp.float32), valid2d)
-
-    return out.reshape(n_pad, k, 4)[:n_seg]
-
-
-def _make_segment_bin_agg_kernel(group: int, gx: int, gy: int):
-    k = gx * gy
-
-    def kernel(bbox_ref, x_ref, y_ref, v_ref, sid_ref, valid_ref, out_ref):
-        g = pl.program_id(0)
-
-        @pl.when(pl.program_id(1) == 0)
-        def _init():
-            _acc_init(out_ref)
-
-        xs = x_ref[...]
-        ys = y_ref[...]
-        vs = v_ref[...]
-        sid = sid_ref[...]
-        valid = valid_ref[...] != 0
-        for t in range(group):  # static unroll over segments…
-            x0 = bbox_ref[t, 0]
-            y0 = bbox_ref[t, 1]
-            x1 = bbox_ref[t, 2]
-            y1 = bbox_ref[t, 3]
-            # pure clip-binning against segment s's own bbox (ownership
-            # rule — see kernels/bin_agg.py)
-            cw = jnp.maximum((x1 - x0) / gx, 1e-30)
-            ch = jnp.maximum((y1 - y0) / gy, 1e-30)
-            cx = jnp.clip(jnp.floor((xs - x0) / cw).astype(jnp.int32),
-                          0, gx - 1)
-            cy = jnp.clip(jnp.floor((ys - y0) / ch).astype(jnp.int32),
-                          0, gy - 1)
-            cid = cy * gx + cx
-            s_glob = (g * group + t).astype(jnp.float32)
-            ms = valid & (sid == s_glob)
-            for c in range(k):  # …and cells: group·K masked reductions
-                _acc_cell(out_ref, t * k + c, ms & (cid == c), vs)
-    return kernel
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_seg", "gx", "gy", "block_rows",
-                                    "seg_group", "interpret"))
-def segment_bin_agg_pallas(xs2d, ys2d, vals2d, sid2d, valid2d, bboxes, *,
-                           n_seg, gx, gy, block_rows=DEFAULT_BLOCK_ROWS,
-                           seg_group=None, interpret=True):
-    """Per-segment, per-cell aggregation: segment s split by its bboxes[s].
-
-    Args mirror :func:`segment_window_agg_pallas`; ``bboxes`` is float32
-    ``(n_seg, 4)``. Returns float32 ``(n_seg, gx*gy, 4)``;
-    cell id = cy*gx + cx.
-    """
-    k = gx * gy
-    assert n_seg <= MAX_SEGMENTS, n_seg
-    rows = xs2d.shape[0]
-    assert rows % block_rows == 0, (rows, block_rows)
-    group, n_groups, n_pad = plan_cell_groups(n_seg, k,
-                                              block_rows=block_rows,
-                                              group=seg_group)
-    bboxes2d = _pad_rows(bboxes.reshape(n_seg, 4).astype(jnp.float32),
-                         n_pad)
-    valid2d = valid2d.astype(jnp.int8)
-
-    out = pl.pallas_call(
-        _make_segment_bin_agg_kernel(group, gx, gy),
-        grid=(n_groups, rows // block_rows),
-        in_specs=[pl.BlockSpec((group, 4), lambda g, r: (g, 0))]  # bboxes
-        + _plane_specs(block_rows),
-        out_specs=pl.BlockSpec((1, group * k, 4), lambda g, r: (g, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_groups, group * k, 4),
-                                       jnp.float32),
-        interpret=interpret,
-    )(bboxes2d, xs2d.astype(jnp.float32), ys2d.astype(jnp.float32),
-      vals2d.astype(jnp.float32), sid2d.astype(jnp.float32), valid2d)
-
-    return out.reshape(n_pad, k, 4)[:n_seg]
+    )(par, *planes, valid2d.astype(jnp.int8))
+    return acc_finalize(out).reshape(n_pad, k, 4)[:n_seg]

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from . import layers as L
 from .layers import dense_init
@@ -247,12 +246,12 @@ def moe_ffn_sharded(params, x, dims: MoEDims, mesh) \
 
     wi_spec = P("model", "data" if fsdp else None, None)
     wo_spec = P("model", None, "data" if fsdp else None)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None), wi_spec,
                   wi_spec, wo_spec),
         out_specs=(P(bspec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], wi, wg, wo)
     if dims.n_shared:
         xt = x.reshape(b * s, d)

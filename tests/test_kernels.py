@@ -699,3 +699,85 @@ def test_window_bin_params_binning_contract():
     cid_g = geometry.bin_cell_ids(xs.astype(np.float64),
                                   ys.astype(np.float64), win, bx, by)
     np.testing.assert_array_equal(cid_h[m_h], cid_g[m_h])
+
+
+def _near(t):
+    """Each threshold and its two float32 neighbours."""
+    t = np.asarray(t, np.float32).ravel()
+    up = np.float32(np.inf)
+    return np.concatenate([t, np.nextafter(t, up), np.nextafter(t, -up)])
+
+
+@pytest.mark.parametrize("bins", [(1, 1), (5, 3), (8, 8)])
+def test_window_bin_thresholds_match_host_rule(bins):
+    """The Pallas binning contract: ``Σ_j 1[x ≥ t_j]`` over the
+    ``ref.window_bin_thresholds`` row is bit-identical to
+    ``ref.window_bin_ids_np`` (mask and bin ids), including points on
+    and one ulp either side of every threshold and window edge, and a
+    degenerate zero-area window."""
+    bx, by = bins
+    rng = np.random.default_rng(5)
+    windows = [(2.0, 2.0, 2.0, 2.0)]
+    for _ in range(8):
+        x0, y0 = rng.uniform(-50, 500, 2)
+        windows.append((float(x0), float(y0),
+                        float(x0 + rng.uniform(1e-3, 400)),
+                        float(y0 + rng.uniform(1e-3, 400))))
+    for win in windows:
+        p = ref.window_bin_thresholds(np.array([win]), bx, by)[0]
+        tx, ty = p[4:4 + bx - 1], p[4 + bx - 1:]
+        xs = np.concatenate([rng.uniform(win[0] - 5, win[2] + 5, 3000),
+                             _near(np.concatenate([tx, p[[0, 2]]]))])
+        ys = np.concatenate([rng.uniform(win[1] - 5, win[3] + 5, 3000),
+                             _near(np.concatenate([ty, p[[1, 3]]]))])
+        n = min(len(xs), len(ys))
+        xs = np.concatenate([xs[:n], rng.permutation(xs[:n])])
+        ys = np.concatenate([rng.permutation(ys[:n]), ys[:n]])
+        xs, ys = xs.astype(np.float32), ys.astype(np.float32)
+        with np.errstate(invalid="ignore"):
+            m_h, cid_h = ref.window_bin_ids_np(xs, ys, win, bx, by)
+        m_d = (xs >= p[0]) & (xs <= p[2]) & (ys >= p[1]) & (ys <= p[3])
+        cid_d = ((ys[:, None] >= ty[None]).sum(1) * bx
+                 + (xs[:, None] >= tx[None]).sum(1))
+        np.testing.assert_array_equal(m_h, m_d)
+        np.testing.assert_array_equal(cid_h[m_h], cid_d[m_d])
+
+
+def test_split_and_edge_thresholds_match_host_rules():
+    """``ref.split_thresholds`` reproduces the f64 even-split rule of
+    ``segment_bin_agg_np`` and ``ref.edge_thresholds`` the f64 edge rule
+    of ``edge_cell_ids_np`` on every float32 coordinate, boundary ulps
+    included — the pallas split kernels' cells equal the host's."""
+    rng = np.random.default_rng(6)
+    gx, gy = 3, 4
+    lo = rng.uniform(0, 100, (6, 2))
+    bboxes = np.concatenate([lo, lo + rng.uniform(1e-2, 80, (6, 2))], 1)
+    t = ref.split_thresholds(bboxes, gx, gy)
+    for s in range(len(bboxes)):
+        tx, ty = t[s, :gx - 1], t[s, gx - 1:]
+        xs = np.concatenate([rng.uniform(lo[s, 0] - 1, lo[s, 0] + 90,
+                                         2000), _near(tx)]).astype(
+            np.float32)
+        cw = max((bboxes[s, 2] - bboxes[s, 0]) / gx, 1e-30)
+        want = np.clip(np.floor((xs - bboxes[s, 0]) / cw).astype(np.int64),
+                       0, gx - 1)
+        np.testing.assert_array_equal(want, (xs[:, None] >= tx).sum(1))
+        ys = np.concatenate([rng.uniform(lo[s, 1] - 1, lo[s, 1] + 90,
+                                         2000), _near(ty)]).astype(
+            np.float32)
+        ch = max((bboxes[s, 3] - bboxes[s, 1]) / gy, 1e-30)
+        want = np.clip(np.floor((ys - bboxes[s, 1]) / ch).astype(np.int64),
+                       0, gy - 1)
+        np.testing.assert_array_equal(want, (ys[:, None] >= ty).sum(1))
+    # explicit f64 edges, most not representable in float32
+    xe = np.sort(rng.uniform(0, 10, (5, gx + 1)), axis=1)
+    ye = np.sort(rng.uniform(0, 10, (5, gy + 1)), axis=1)
+    t = ref.edge_thresholds(xe, ye)
+    for s in range(5):
+        pts = np.concatenate([rng.uniform(-1, 11, 2000),
+                              _near(t[s])]).astype(np.float32)
+        sid = np.full(len(pts), s)
+        cid = ref.edge_cell_ids_np(pts, pts, xe, ye, sid)
+        cx = (pts[:, None] >= t[s, :gx - 1]).sum(1)
+        cy = (pts[:, None] >= t[s, gx - 1:]).sum(1)
+        np.testing.assert_array_equal(cid, cy * gx + cx)
