@@ -244,6 +244,8 @@ class QueryResult:
     read_calls: int = 0        # raw-file read invocations (gathered = 1/round)
     batch_rounds: int = 0      # batched refinement rounds (0 ⇒ sequential)
     speculative_rows: int = 0  # rows read past the stopping point
+    kernel_calls: int = 0      # data-plane op calls (AdaptStats delta)
+    h2d_bytes: int = 0         # bytes those calls shipped to the device
     pruned_chunks: int = 0     # chunks skipped on their bbox (chunked ds)
     retired_during_query: bool = False  # a chunk retired mid-query; its
     #                            tiles were dropped from the answer set
@@ -443,6 +445,8 @@ class HeatmapResult:
     read_calls: int = 0        # raw-file read invocations (gathered = 1/round)
     batch_rounds: int = 0      # batched refinement rounds (0 ⇒ sequential)
     speculative_rows: int = 0  # rows read past the stopping point
+    kernel_calls: int = 0      # data-plane op calls (AdaptStats delta)
+    h2d_bytes: int = 0         # bytes those calls shipped to the device
     pruned_chunks: int = 0     # chunks skipped on their bbox (chunked ds)
     retired_during_query: bool = False  # a chunk retired mid-query; its
     #                            tiles were dropped from the answer set

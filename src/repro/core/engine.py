@@ -68,6 +68,7 @@ from .bounds import AccuracyPolicy, HeatmapResult, QueryResult
 from .index import ChunkIndexSet, IndexConfig, TileIndex
 from .predict import (TrajectoryStep, ViewportPredictor, prefetch_crack,
                       resolve_learned_salience)
+from ..spans import PREDICT, QUERY, span
 
 
 @dataclasses.dataclass
@@ -96,6 +97,8 @@ class EngineTrace:
                                       for r in self.results),
             "total_speculative_rows": sum(r.speculative_rows
                                           for r in self.results),
+            "total_kernel_calls": sum(r.kernel_calls for r in self.results),
+            "total_h2d_bytes": sum(r.h2d_bytes for r in self.results),
             "total_pruned_chunks": sum(r.pruned_chunks
                                        for r in self.results),
         }
@@ -147,7 +150,8 @@ class AQPEngine:
             tuple(float(v) for v in window),
             None if bins is None else (int(bins[0]), int(bins[1])),
             float(dwell_s)))
-        self.predictor.observe(window, bins=bins, dwell_s=dwell_s)
+        with span(PREDICT):
+            self.predictor.observe(window, bins=bins, dwell_s=dwell_s)
         self._last_attr = attr
         if bins is not None:
             self._last_bins = (int(bins[0]), int(bins[1]))
@@ -169,11 +173,13 @@ class AQPEngine:
         dwell_s: how long the user dwelled on this viewport — weights the
           learned-salience histogram (default 1 ⇒ uniform dwell).
         """
-        r = query_mod.evaluate(self.index, window, agg, attr, phi=phi,
-                               alpha=self.alpha if alpha is None else alpha,
-                               batch_k=batch_k, sequential=sequential)
-        self.trace.results.append(r)
-        self._observe(window, None, attr, dwell_s)
+        with span(QUERY):
+            r = query_mod.evaluate(
+                self.index, window, agg, attr, phi=phi,
+                alpha=self.alpha if alpha is None else alpha,
+                batch_k=batch_k, sequential=sequential)
+            self.trace.results.append(r)
+            self._observe(window, None, attr, dwell_s)
         return r
 
     def heatmap(self, window: Tuple[float, float, float, float], agg: str,
@@ -200,14 +206,15 @@ class AQPEngine:
         batch_k / sequential: as in :meth:`query`.
         dwell_s: as in :meth:`query`.
         """
-        policy = resolve_learned_salience(policy, self.predictor, window,
-                                          bins)
-        r = query_mod.evaluate_heatmap(
-            self.index, window, agg, attr, bins=bins, phi=phi,
-            alpha=self.alpha if alpha is None else alpha, policy=policy,
-            batch_k=batch_k, sequential=sequential)
-        self.trace.results.append(r)
-        self._observe(window, bins, attr, dwell_s)
+        with span(QUERY):
+            policy = resolve_learned_salience(policy, self.predictor,
+                                              window, bins)
+            r = query_mod.evaluate_heatmap(
+                self.index, window, agg, attr, bins=bins, phi=phi,
+                alpha=self.alpha if alpha is None else alpha, policy=policy,
+                batch_k=batch_k, sequential=sequential)
+            self.trace.results.append(r)
+            self._observe(window, bins, attr, dwell_s)
         return r
 
     def prefetch(self, budget_rows: int, attr: Optional[str] = None,

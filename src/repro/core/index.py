@@ -43,6 +43,8 @@ from ..data.rawfile import RawDataset
 from ..kernels import fused_select as fused_mod
 from ..kernels import ops
 from ..kernels import ref as ref_mod
+from .. import spans
+from ..spans import span
 from . import geometry
 from .geometry import DISJOINT, PARTIAL, FULL
 
@@ -120,6 +122,7 @@ class AdaptStats:
     tiles_enriched: int = 0
     objects_reorganized: int = 0
     kernel_calls: int = 0      # device/mirror kernel invocations (ops.*)
+    h2d_bytes: int = 0         # host arrays handed to device kernels
     batch_rounds: int = 0      # gathered-read refinement rounds
     speculative_rows: int = 0  # rows read in a round but never folded
 
@@ -179,16 +182,20 @@ class TileIndex:
 
         # --- initialization pass (the "crude" index) ---
         gx, gy = config.grid0
-        domain = dataset.domain()
-        # widen max edge epsilon so ownership clamping matches extents
-        self.domain = domain
-        cell_ids = geometry.bin_cell_ids(dataset.x, dataset.y, domain, gx, gy)
-        perm = np.argsort(cell_ids, kind="stable")
-        self.perm = perm.astype(np.int64)          # file row id per slot
-        self.x_s = dataset.x[perm]                 # axis values, perm order
-        self.y_s = dataset.y[perm]
-        counts = np.bincount(cell_ids, minlength=gx * gy)
-        offsets = np.concatenate([[0], np.cumsum(counts)[:-1]])
+        with span(spans.INIT_BIN):
+            domain = dataset.domain()
+            # widen max edge epsilon so ownership clamping matches extents
+            self.domain = domain
+            cell_ids = geometry.bin_cell_ids(dataset.x, dataset.y, domain,
+                                             gx, gy)
+        with span(spans.INIT_SORT):
+            perm = np.argsort(cell_ids, kind="stable")
+        with span(spans.INIT_PERMUTE):
+            self.perm = perm.astype(np.int64)      # file row id per slot
+            self.x_s = dataset.x[perm]             # axis values, perm order
+            self.y_s = dataset.y[perm]
+            counts = np.bincount(cell_ids, minlength=gx * gy)
+            offsets = np.concatenate([[0], np.cumsum(counts)[:-1]])
         boxes = geometry.subtile_bboxes(domain, gx, gy)
         t = gx * gy
         self.bbox[:t] = boxes
@@ -199,11 +206,12 @@ class TileIndex:
         self.n_tiles = t
         dataset.account_init_pass()
 
-        for attr in config.init_metadata_attrs:
-            self.ensure_attr(attr)
-            # init-pass metadata: one sequential file scan (accounted)
-            vals = dataset.read_values(attr, self.perm)
-            self._fill_meta_from_segments(attr, np.arange(t), vals)
+        with span(spans.INIT_META):
+            for attr in config.init_metadata_attrs:
+                self.ensure_attr(attr)
+                # init-pass metadata: one sequential file scan (accounted)
+                vals = dataset.read_values(attr, self.perm)
+                self._fill_meta_from_segments(attr, np.arange(t), vals)
 
     # ------------------------------------------------------------------ #
     # attribute registration
@@ -505,14 +513,17 @@ class TileIndex:
 
         # child metadata for the processed attribute: one binned pass
         # (data plane — Pallas bin_agg kernel on TPU)
+        cols = ops.kernel_cols(False, gx, gy)
         if edges is None:
-            agg = np.asarray(ops.bin_agg(xs, ys, vals, bbox, gx=gx, gy=gy,
-                                         backend=self._backend))
+            agg = self.run_op(ops.bin_agg, xs, ys, vals, bbox, gx=gx, gy=gy,
+                              h2d=ops.h2d_bytes(self._backend, c, 1, cols,
+                                                stream=False))
         else:
-            agg = np.asarray(ops.segment_bin_agg_edges(
-                xs, ys, vals, np.array([0, c], np.int64),
-                edges[0][None], edges[1][None], backend=self._backend))[0]
-        self.adapt_stats.kernel_calls += 1
+            agg = self.run_op(ops.segment_bin_agg_edges, xs, ys, vals,
+                              np.array([0, c], np.int64), edges[0][None],
+                              edges[1][None],
+                              h2d=ops.h2d_bytes(self._backend, c, 1,
+                                                cols))[0]
 
         order = np.argsort(cell, kind="stable")
         # local reorganization of the parent's segment
@@ -564,16 +575,30 @@ class TileIndex:
     # ------------------------------------------------------------------ #
     # batched processing (the amortized, crack-in-batch path)
     # ------------------------------------------------------------------ #
+    def run_op(self, op, *args, h2d: int = 0, **kwargs):
+        """One data-plane op call on this index's backend, its result in
+        host memory: counted in ``kernel_calls`` and, with the bytes of
+        host arrays it ships (``ops.h2d_bytes``), in ``h2d_bytes``."""
+        with span(spans.KERNEL):
+            out = op(*args, backend=self._backend, **kwargs)
+            with span(spans.KERNEL_WAIT):
+                out = (tuple(np.asarray(o) for o in out)
+                       if isinstance(out, tuple) else np.asarray(out))
+        self.adapt_stats.kernel_calls += 1
+        self.adapt_stats.h2d_bytes += h2d
+        return out
+
     def _read_batch_gather(self, tile_ids, attr: str):
         """Shared phase-1 plumbing of a batched refinement round: ONE
         gathered ``read_values`` over the tiles' concatenated segments,
         plus the :meth:`apply_batch` payload describing them."""
-        self.ensure_attr(attr)
-        tile_ids = np.asarray(tile_ids, np.int64)
-        idx, bounds = self._gather_segments(tile_ids)
-        rows = self.perm[idx]
-        vals = self.ds.read_values(attr, rows)     # ← ONE accounted read
-        xs, ys = self.x_s[idx], self.y_s[idx]
+        with span(spans.GATHER):
+            self.ensure_attr(attr)
+            tile_ids = np.asarray(tile_ids, np.int64)
+            idx, bounds = self._gather_segments(tile_ids)
+            rows = self.perm[idx]
+            vals = self.ds.read_values(attr, rows)     # ← ONE accounted read
+            xs, ys = self.x_s[idx], self.y_s[idx]
         self.adapt_stats.batch_rounds += 1
         payload = {"tile_ids": tile_ids, "idx": idx, "bounds": bounds,
                    "xs": xs, "ys": ys, "vals": vals, "attr": attr}
@@ -619,9 +644,10 @@ class TileIndex:
         tile_ids, idx, bounds, xs, ys, vals, payload = \
             self._read_batch_gather(tile_ids, attr)
         # exact in-window contributions: one packed kernel over the batch
-        contrib = np.asarray(ops.segment_window_agg(
-            xs, ys, vals, bounds, window, backend=self._backend))
-        self.adapt_stats.kernel_calls += 1
+        contrib = self.run_op(
+            ops.segment_window_agg, xs, ys, vals, bounds, window,
+            h2d=ops.h2d_bytes(self._backend, bounds[-1], len(tile_ids),
+                              ops.kernel_cols(True)))
         contribs = [
             (int(contrib[s, 0]), float(contrib[s, 1]),
              float(contrib[s, 2]), float(contrib[s, 3]))
@@ -665,9 +691,10 @@ class TileIndex:
         bx, by = bins
         tile_ids, idx, bounds, xs, ys, vals, payload = \
             self._read_batch_gather(tile_ids, attr)
-        agg, suffix_w = fused_mod.segment_window_bin_select_np(
-            xs, ys, vals, bounds, window, bx, by,
-            self.meta_min[attr][tile_ids], self.meta_max[attr][tile_ids])
+        with span(spans.KERNEL):
+            agg, suffix_w = fused_mod.segment_window_bin_select_np(
+                xs, ys, vals, bounds, window, bx, by,
+                self.meta_min[attr][tile_ids], self.meta_max[attr][tile_ids])
         payload["suffix_w"] = suffix_w
         self.adapt_stats.kernel_calls += 1
         # bin-aligned split lines for every tile of the round (the same
@@ -692,6 +719,11 @@ class TileIndex:
         return contribs, payload
 
     def apply_batch(self, payload, n_used: int, split_flags):
+        """Phase 2 of a batched refinement round (see :meth:`_apply`)."""
+        with span(spans.APPLY):
+            self._apply(payload, n_used, split_flags)
+
+    def _apply(self, payload, n_used: int, split_flags):
         """Phase 2: enrich + split the round's first ``n_used`` tiles.
 
         Tiles past ``n_used`` (read speculatively but never folded by the
@@ -836,15 +868,14 @@ class TileIndex:
              np.cumsum(counts_sk, axis=1)[:, :-1]], axis=1)
 
         # child metadata for the processed attribute: one packed kernel
+        h2d = ops.h2d_bytes(self._backend, bounds[-1], s_n,
+                            ops.kernel_cols(False, gx, gy))
         if edges is None:
-            agg = np.asarray(ops.segment_bin_agg(
-                xs, ys, vals, bounds, bboxes, gx=gx, gy=gy,
-                backend=self._backend))
+            agg = self.run_op(ops.segment_bin_agg, xs, ys, vals, bounds,
+                              bboxes, gx=gx, gy=gy, h2d=h2d)
         else:
-            agg = np.asarray(ops.segment_bin_agg_edges(
-                xs, ys, vals, bounds, edges[0], edges[1],
-                backend=self._backend))
-        self.adapt_stats.kernel_calls += 1
+            agg = self.run_op(ops.segment_bin_agg_edges, xs, ys, vals,
+                              bounds, edges[0], edges[1], h2d=h2d)
 
         # one global stable argsort reorganizes every parent's segment
         # (keys are segment-major, so the permutation never crosses

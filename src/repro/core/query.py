@@ -56,6 +56,7 @@ from .bounds import (AccuracyPolicy, GroupedAccumulator, GroupedPendingTile,
 from .refine import HeatmapQueryAdapter, RefinementDriver, ScalarQueryAdapter
 from ..kernels.ops import window_mask_np
 from ..kernels.ref import window_bin_ids_np
+from ..spans import CLASSIFY, span
 
 
 def _build_accumulator(index, window, agg: str, attr: str):
@@ -124,10 +125,10 @@ def evaluate(index, window, agg: str, attr: str,
     t_start = time.perf_counter()
     io_before = index.ds.stats.snapshot()
     adapt_before = index.adapt_stats.snapshot()
-    index.ensure_attr(attr)
-
-    acc, full_set, n_full, n_partial = _build_accumulator(
-        index, window, agg, attr)
+    with span(CLASSIFY):
+        index.ensure_attr(attr)
+        acc, full_set, n_full, n_partial = _build_accumulator(
+            index, window, agg, attr)
 
     driver = RefinementDriver(
         acc, ScalarQueryAdapter(index, window, attr, full_set), phi, alpha,
@@ -145,6 +146,8 @@ def evaluate(index, window, agg: str, attr: str,
         read_calls=io_delta.read_calls,
         batch_rounds=adapt_delta.batch_rounds,
         speculative_rows=adapt_delta.speculative_rows,
+        kernel_calls=adapt_delta.kernel_calls,
+        h2d_bytes=adapt_delta.h2d_bytes,
         pruned_chunks=io_delta.pruned_calls,
         retired_during_query=driver.dropped > 0,
         eval_time_s=time.perf_counter() - t_start)
@@ -259,12 +262,12 @@ def evaluate_heatmap(index, window, agg: str, attr: str,
     assert bx > 0 and by > 0
     assert np.isfinite(np.asarray(window, np.float64)).all(), \
         "heatmap windows must be finite rectangles"
-    index.ensure_attr(attr)
-
     # (no full-tile set here: heatmap refinement splits every processed
     # tile — see HeatmapQueryAdapter)
-    acc, n_full, n_partial = _build_grouped_accumulator(
-        index, window, agg, attr, (bx, by))
+    with span(CLASSIFY):
+        index.ensure_attr(attr)
+        acc, n_full, n_partial = _build_grouped_accumulator(
+            index, window, agg, attr, (bx, by))
     if policy is not None and phi > 0.0:
         acc.set_policy(policy, phi, (bx, by))
 
@@ -287,6 +290,8 @@ def evaluate_heatmap(index, window, agg: str, attr: str,
         read_calls=io_delta.read_calls,
         batch_rounds=adapt_delta.batch_rounds,
         speculative_rows=adapt_delta.speculative_rows,
+        kernel_calls=adapt_delta.kernel_calls,
+        h2d_bytes=adapt_delta.h2d_bytes,
         pruned_chunks=io_delta.pruned_calls,
         retired_during_query=driver.dropped > 0,
         eval_time_s=time.perf_counter() - t_start,

@@ -54,6 +54,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 from . import adapt
 from ..kernels.segment_agg import MAX_SEGMENTS, MAX_GROUP_CELLS
+from ..spans import REFINE, span
 
 
 def met(phi: float, bound: float) -> bool:
@@ -207,7 +208,11 @@ class RefinementDriver:
         accumulator and — through ``process_one`` / ``apply_batch`` —
         the index.
         """
-        acc, phi = self.acc, self.phi
+        with span(REFINE):
+            return self._run(batch_k, sequential)
+
+    def _run(self, batch_k: Optional[int], sequential: bool) -> int:
+        acc = self.acc
         bound = acc.query_bound()
         if not acc.pending or self._met(bound):
             return 0

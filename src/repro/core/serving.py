@@ -749,12 +749,13 @@ class ServingEngine:
         a span must never straddle a chunk; every span is ≤ batch_k ≤
         MAX_SEGMENTS segments, so span-aligned packing always fits)."""
         n_seg = len(bounds) - 1
+        # the grouped kernel's table, then vmin, vmax and query end
+        cols = ops.kernel_cols(True, bx, by) + 3
         if ti._backend == "np" or n_seg <= MAX_SEGMENTS:
-            ti.adapt_stats.kernel_calls += 1
-            agg, suffix_w = ops.segment_window_bin_select_multi(
-                xs, ys, vals, bounds, windows, vmin_s, vmax_s, qbounds,
-                bx=bx, by=by, backend=ti._backend)
-            return np.asarray(agg), np.asarray(suffix_w)
+            return ti.run_op(
+                ops.segment_window_bin_select_multi, xs, ys, vals, bounds,
+                windows, vmin_s, vmax_s, qbounds, bx=bx, by=by,
+                h2d=ops.h2d_bytes(ti._backend, bounds[-1], n_seg, cols))
         qb = np.asarray(qbounds, np.int64)
         aggs, sufs = [], []
         s = 0
@@ -764,14 +765,15 @@ class ServingEngine:
                 e += 1
             a, b = int(qb[s]), int(qb[e])
             o0, o1 = int(bounds[a]), int(bounds[b])
-            ti.adapt_stats.kernel_calls += 1
-            agg, suf = ops.segment_window_bin_select_multi(
+            agg, suf = ti.run_op(
+                ops.segment_window_bin_select_multi,
                 xs[o0:o1], ys[o0:o1], vals[o0:o1],
                 bounds[a:b + 1] - bounds[a], windows[a:b],
                 vmin_s[a:b], vmax_s[a:b], qb[s:e + 1] - qb[s],
-                bx=bx, by=by, backend=ti._backend)
-            aggs.append(np.asarray(agg))
-            sufs.append(np.asarray(suf))
+                bx=bx, by=by,
+                h2d=ops.h2d_bytes(ti._backend, o1 - o0, b - a, cols))
+            aggs.append(agg)
+            sufs.append(suf)
             s = e
         return np.concatenate(aggs), np.concatenate(sufs)
 
@@ -780,18 +782,19 @@ class ServingEngine:
         chunked to the packed kernels' static segment limit (the host
         "np" mirror — the default control plane — has none)."""
         n_seg = len(bounds) - 1
+        cols = ops.kernel_cols(True)
         if ti._backend == "np" or n_seg <= MAX_SEGMENTS:
-            ti.adapt_stats.kernel_calls += 1
-            return np.asarray(ops.segment_window_agg_multi(
-                xs, ys, vals, bounds, windows, backend=ti._backend))
+            return ti.run_op(
+                ops.segment_window_agg_multi, xs, ys, vals, bounds, windows,
+                h2d=ops.h2d_bytes(ti._backend, bounds[-1], n_seg, cols))
         outs = []
         for s in range(0, n_seg, MAX_SEGMENTS):
             e = min(s + MAX_SEGMENTS, n_seg)
             a, b = int(bounds[s]), int(bounds[e])
-            ti.adapt_stats.kernel_calls += 1
-            outs.append(np.asarray(ops.segment_window_agg_multi(
-                xs[a:b], ys[a:b], vals[a:b], bounds[s:e + 1] - bounds[s],
-                windows[s:e], backend=ti._backend)))
+            outs.append(ti.run_op(
+                ops.segment_window_agg_multi, xs[a:b], ys[a:b], vals[a:b],
+                bounds[s:e + 1] - bounds[s], windows[s:e],
+                h2d=ops.h2d_bytes(ti._backend, b - a, e - s, cols)))
         return np.concatenate(outs)
 
     def _canonicalize_hm(self, tickets) -> None:

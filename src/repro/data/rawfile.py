@@ -29,6 +29,8 @@ from typing import Dict, Optional, Sequence
 
 import numpy as np
 
+from ..spans import READ, span
+
 
 @dataclasses.dataclass
 class IOStats:
@@ -151,11 +153,12 @@ class RawDataset:
             raise RuntimeError("read_values on a retired chunk")
         self.stats.rows_read += int(len(rows))
         self.stats.read_calls += 1
-        if self.storage == "csv":
-            self.stats.bytes_read += int(len(rows)) * self.CSV_WIDTH
-            return self._text[attr][rows].astype(np.float32)
-        self.stats.bytes_read += int(len(rows)) * self.ITEM_BYTES
-        return np.asarray(self._cols[attr][rows], np.float32)
+        with span(READ):
+            if self.storage == "csv":
+                self.stats.bytes_read += int(len(rows)) * self.CSV_WIDTH
+                return self._text[attr][rows].astype(np.float32)
+            self.stats.bytes_read += int(len(rows)) * self.ITEM_BYTES
+            return np.asarray(self._cols[attr][rows], np.float32)
 
     def read_all_unaccounted(self, attr: str) -> np.ndarray:
         """Test/oracle access — bypasses accounting (ground truth only)."""

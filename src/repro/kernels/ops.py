@@ -35,6 +35,10 @@ import numpy as np
 from . import fused_select
 from . import ref
 from .segment_agg import LANES, DEFAULT_BLOCK_ROWS, grouped_agg_pallas
+from ..spans import KERNEL_DISPATCH, KERNEL_PACK, span
+
+PLANES = 4          # object planes of a packed call: x, y, value, segment id
+F32 = 4
 
 
 def on_tpu() -> bool:
@@ -152,12 +156,48 @@ def _pallas_flat(xs, ys, vals, sids, params, n, n_seg, window, gx, gy,
                               seg_group=seg_group, interpret=interpret)
 
 
+def object_bucket(n: int) -> int:
+    """Objects of a packed call, padded: the next power of two, at least
+    1024 (one compiled kernel per bucket, not per length)."""
+    return max(1024, 1 << (max(int(n), 1) - 1).bit_length())
+
+
+def segment_bucket(n_seg: int) -> int:
+    """Segments of a packed call on the grouped kernel, padded: the next
+    power of two."""
+    return 1 << (int(n_seg) - 1).bit_length()
+
+
+def kernel_cols(window: bool, gx: int = 1, gy: int = 1) -> int:
+    """Float32 scalars per segment of the grouped kernel: the window's
+    corners when a window masks the segment, then ``gx - 1`` x- and
+    ``gy - 1`` y-thresholds."""
+    return (4 if window else 0) + (gx - 1) + (gy - 1)
+
+
+def h2d_bytes(backend: str, n: int, n_seg: int = 1, cols: int = 4, *,
+              stream: bool = True) -> int:
+    """Bytes of host arrays one op call hands a device kernel.
+
+    A packed segment call (``stream``) ships ``PLANES`` float32 object
+    planes padded to :func:`object_bucket` and a float32 table of
+    ``cols`` scalars per segment, padded to :func:`segment_bucket`: the
+    grouped kernel's layout (``kernel_cols``), which the "jnp" backend is
+    counted by too. ``window_agg``/``bin_agg`` (``stream=False``) ship
+    x, y and value unpadded and one table row. The "np" mirror ships
+    nothing."""
+    if backend == "np":
+        return 0
+    if not stream:
+        return F32 * (3 * int(n) + cols)
+    return F32 * (PLANES * object_bucket(n) + segment_bucket(n_seg) * cols)
+
+
 def _seg_bucket(n_seg: int, *tables):
-    """Round a packed call's segment count up to a power of two (one
-    compiled kernel per bucket, not per count) and zero-pad its
-    per-segment tables to it. Padded segments hold no objects, so
-    their rows come back empty and are sliced off."""
-    pad = 1 << (n_seg - 1).bit_length()
+    """Zero-pad a packed call's per-segment tables to its
+    :func:`segment_bucket`. Padded segments hold no objects, so their
+    rows come back empty and are sliced off."""
+    pad = segment_bucket(n_seg)
     out = []
     for t in tables:
         t = np.asarray(t)
@@ -166,14 +206,19 @@ def _seg_bucket(n_seg: int, *tables):
     return pad, out
 
 
-def _pallas(xs, ys, vals, sids, params, n, *, n_seg, window, gx=1, gy=1,
-            interpret=None, seg_group=None):
-    """Host entry of the grouped kernel: ``params`` is the per-segment
-    scalar table (``(n_seg, ncol)`` f32)."""
+def _pallas_table(params, n_seg: int):
+    """The grouped kernel's per-segment scalar table (``(n_seg, ncol)``
+    f32), padded to the segment bucket."""
     params = np.asarray(params, np.float32).reshape(n_seg, -1)
-    pad, (params,) = _seg_bucket(n_seg, params)
-    return _pallas_flat(xs, ys, vals, sids, params, int(n), pad, window,
-                        gx, gy, resolve_interpret(interpret),
+    return _seg_bucket(n_seg, params)[1][0]
+
+
+def _pallas(xs, ys, vals, sids, table, n, *, n_seg, window, gx=1, gy=1,
+            interpret=None, seg_group=None):
+    """Dispatch of the grouped kernel on a :func:`_pallas_table`; the
+    first ``n_seg`` rows of its output."""
+    return _pallas_flat(xs, ys, vals, sids, table, int(n), table.shape[0],
+                        window, gx, gy, resolve_interpret(interpret),
                         seg_group)[:n_seg]
 
 
@@ -211,14 +256,16 @@ def window_agg(xs, ys, vals, window, *, n=None, backend=None,
         n = len(xs) if n is None else int(n)
         return _window_agg_np(xs, ys, vals, window, n)
     full = n is None
-    xs, ys, vals = _dev(xs), _dev(ys), _dev(vals)
-    n = xs.shape[0] if n is None else n
-    if backend == "jnp":
-        return _window_agg_jnp(xs, ys, vals,
-                               np.asarray(window, np.float32), int(n),
-                               full=full)
-    return _pallas(xs, ys, vals, None, _window_row(window), n, n_seg=1,
-                   window=True, interpret=interpret).reshape(4)
+    with span(KERNEL_PACK):
+        xs, ys, vals = _dev(xs), _dev(ys), _dev(vals)
+        n = xs.shape[0] if n is None else n
+        table = (np.asarray(window, np.float32) if backend == "jnp"
+                 else _pallas_table(_window_row(window), 1))
+    with span(KERNEL_DISPATCH):
+        if backend == "jnp":
+            return _window_agg_jnp(xs, ys, vals, table, int(n), full=full)
+        return _pallas(xs, ys, vals, None, table, n, n_seg=1, window=True,
+                       interpret=interpret).reshape(4)
 
 
 @functools.partial(jax.jit, static_argnames=("gx", "gy", "full"))
@@ -235,19 +282,23 @@ def bin_agg(xs, ys, vals, bbox, *, gx, gy, n=None, backend=None,
         n = len(xs) if n is None else int(n)
         return _bin_agg_np(xs, ys, vals, bbox, gx, gy, n)
     full = n is None
-    xs, ys, vals = _dev(xs), _dev(ys), _dev(vals)
-    n = xs.shape[0] if n is None else n
-    if backend == "jnp":
-        return _bin_agg_jnp(xs, ys, vals, np.asarray(bbox, np.float32),
-                            int(n), gx, gy, full=full)
-    params = ref.split_thresholds(np.asarray(bbox)[None], gx, gy)
-    return _pallas(xs, ys, vals, None, params, n, n_seg=1, window=False,
-                   gx=gx, gy=gy, interpret=interpret)[0]
+    with span(KERNEL_PACK):
+        xs, ys, vals = _dev(xs), _dev(ys), _dev(vals)
+        n = xs.shape[0] if n is None else n
+        table = (np.asarray(bbox, np.float32) if backend == "jnp" else
+                 _pallas_table(ref.split_thresholds(
+                     np.asarray(bbox)[None], gx, gy), 1))
+    with span(KERNEL_DISPATCH):
+        if backend == "jnp":
+            return _bin_agg_jnp(xs, ys, vals, table, int(n), gx, gy,
+                                full=full)
+        return _pallas(xs, ys, vals, None, table, n, n_seg=1,
+                       window=False, gx=gx, gy=gy, interpret=interpret)[0]
 
 
 def _bucket_pad(*arrays, n):
-    """Pad flat host arrays to a power-of-two bucket to bound recompiles."""
-    cap = max(1024, 1 << (max(int(n), 1) - 1).bit_length())
+    """Pad flat host arrays to :func:`object_bucket` to bound recompiles."""
+    cap = object_bucket(n)
     out = []
     for a in arrays:
         buf = np.zeros(cap, np.float32)
@@ -281,14 +332,18 @@ def segment_window_agg(xs, ys, vals, boundaries, window, *, backend=None,
     boundaries = np.asarray(boundaries, np.int64)
     if backend == "np":
         return ref.segment_window_agg_np(xs, ys, vals, boundaries, window)
-    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
-                                                   boundaries)
-    if backend == "jnp":
-        return _segment_window_agg_jnp(
-            xs, ys, vals, sids, np.asarray(window, np.float32), n, n_seg)
-    params = np.broadcast_to(_window_row(window), (n_seg, 4))
-    return _pallas(xs, ys, vals, sids, params, n, n_seg=n_seg,
-                   window=True, interpret=interpret)[:, 0]
+    with span(KERNEL_PACK):
+        xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                       boundaries)
+        table = (np.asarray(window, np.float32) if backend == "jnp" else
+                 _pallas_table(np.broadcast_to(_window_row(window),
+                                               (n_seg, 4)), n_seg))
+    with span(KERNEL_DISPATCH):
+        if backend == "jnp":
+            return _segment_window_agg_jnp(xs, ys, vals, sids, table, n,
+                                           n_seg)
+        return _pallas(xs, ys, vals, sids, table, n, n_seg=n_seg,
+                       window=True, interpret=interpret)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("n_seg", "gx", "gy"))
@@ -312,15 +367,17 @@ def segment_bin_agg(xs, ys, vals, boundaries, bboxes, *, gx, gy,
     if backend == "np":
         return ref.segment_bin_agg_np(xs, ys, vals, boundaries, bboxes,
                                       gx, gy)
-    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
-                                                   boundaries)
-    if backend == "jnp":
-        return _segment_bin_agg_jnp(
-            xs, ys, vals, sids, np.asarray(bboxes, np.float32), n, n_seg,
-            gx, gy)
-    return _pallas(xs, ys, vals, sids, ref.split_thresholds(bboxes, gx, gy),
-                   n, n_seg=n_seg, window=False, gx=gx, gy=gy,
-                   interpret=interpret)
+    with span(KERNEL_PACK):
+        xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                       boundaries)
+        table = (np.asarray(bboxes, np.float32) if backend == "jnp" else
+                 _pallas_table(ref.split_thresholds(bboxes, gx, gy), n_seg))
+    with span(KERNEL_DISPATCH):
+        if backend == "jnp":
+            return _segment_bin_agg_jnp(xs, ys, vals, sids, table, n,
+                                        n_seg, gx, gy)
+        return _pallas(xs, ys, vals, sids, table, n, n_seg=n_seg,
+                       window=False, gx=gx, gy=gy, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("n_seg",))
@@ -353,15 +410,19 @@ def segment_bin_agg_edges(xs, ys, vals, boundaries, x_edges, y_edges, *,
                                             x_edges, y_edges)
     gx = x_edges.shape[1] - 1
     gy = y_edges.shape[1] - 1
-    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
-                                                   boundaries)
-    if backend == "jnp":
-        return _segment_bin_agg_edges_jnp(
-            xs, ys, vals, sids, np.asarray(x_edges, np.float32),
-            np.asarray(y_edges, np.float32), n, n_seg)
-    return _pallas(xs, ys, vals, sids,
-                   ref.edge_thresholds(x_edges, y_edges), n, n_seg=n_seg,
-                   window=False, gx=gx, gy=gy, interpret=interpret)
+    with span(KERNEL_PACK):
+        xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                       boundaries)
+        tables = ((np.asarray(x_edges, np.float32),
+                   np.asarray(y_edges, np.float32)) if backend == "jnp" else
+                  (_pallas_table(ref.edge_thresholds(x_edges, y_edges),
+                                 n_seg),))
+    with span(KERNEL_DISPATCH):
+        if backend == "jnp":
+            return _segment_bin_agg_edges_jnp(xs, ys, vals, sids, *tables,
+                                              n, n_seg)
+        return _pallas(xs, ys, vals, sids, tables[0], n, n_seg=n_seg,
+                       window=False, gx=gx, gy=gy, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("n_seg", "bx", "by"))
@@ -387,17 +448,21 @@ def segment_window_bin_agg(xs, ys, vals, boundaries, window, *, bx, by,
     if backend == "np":
         return ref.segment_window_bin_agg_np(xs, ys, vals, boundaries,
                                              window, bx, by)
-    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
-                                                   boundaries)
-    if backend == "jnp":
-        return _segment_window_bin_agg_jnp(
-            xs, ys, vals, sids, np.asarray(window, np.float32), n, n_seg,
-            bx, by)
-    row = ref.window_bin_thresholds(np.asarray(window)[None], bx, by)
-    return _pallas(xs, ys, vals, sids,
-                   np.broadcast_to(row, (n_seg, row.shape[1])), n,
-                   n_seg=n_seg, window=True, gx=bx, gy=by,
-                   interpret=interpret)
+    with span(KERNEL_PACK):
+        xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                       boundaries)
+        if backend == "jnp":
+            table = np.asarray(window, np.float32)
+        else:
+            row = ref.window_bin_thresholds(np.asarray(window)[None], bx, by)
+            table = _pallas_table(
+                np.broadcast_to(row, (n_seg, row.shape[1])), n_seg)
+    with span(KERNEL_DISPATCH):
+        if backend == "jnp":
+            return _segment_window_bin_agg_jnp(xs, ys, vals, sids, table, n,
+                                               n_seg, bx, by)
+        return _pallas(xs, ys, vals, sids, table, n, n_seg=n_seg,
+                       window=True, gx=bx, gy=by, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("n_seg", "bx", "by"))
@@ -445,21 +510,25 @@ def segment_window_bin_select(xs, ys, vals, boundaries, window, vmin_s,
     if backend == "np":
         return fused_select.segment_window_bin_select_np(
             xs, ys, vals, boundaries, window, bx, by, vmin_s, vmax_s)
-    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
-                                                   boundaries)
-    vmin_s = np.asarray(vmin_s, np.float32)
-    vmax_s = np.asarray(vmax_s, np.float32)
-    if backend == "jnp":
-        return _segment_window_bin_select_jnp(
-            xs, ys, vals, sids, np.asarray(window, np.float32), vmin_s,
-            vmax_s, n, n_seg, bx, by)
-    row = ref.window_bin_thresholds(np.asarray(window)[None], bx, by)
-    pad, (params, vmin_s, vmax_s) = _seg_bucket(
-        n_seg, np.broadcast_to(row, (n_seg, row.shape[1])), vmin_s, vmax_s)
-    agg, suffix_w = _segment_window_bin_select_pallas(
-        xs, ys, vals, sids, params, vmin_s, vmax_s, n, pad, bx, by,
-        resolve_interpret(interpret), seg_group)
-    return agg[:n_seg], suffix_w[:n_seg + 1]
+    with span(KERNEL_PACK):
+        xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                       boundaries)
+        vmin_s = np.asarray(vmin_s, np.float32)
+        vmax_s = np.asarray(vmax_s, np.float32)
+        if backend == "pallas":
+            row = ref.window_bin_thresholds(np.asarray(window)[None], bx, by)
+            pad, (params, vmin_s, vmax_s) = _seg_bucket(
+                n_seg, np.broadcast_to(row, (n_seg, row.shape[1])), vmin_s,
+                vmax_s)
+    with span(KERNEL_DISPATCH):
+        if backend == "jnp":
+            return _segment_window_bin_select_jnp(
+                xs, ys, vals, sids, np.asarray(window, np.float32), vmin_s,
+                vmax_s, n, n_seg, bx, by)
+        agg, suffix_w = _segment_window_bin_select_pallas(
+            xs, ys, vals, sids, params, vmin_s, vmax_s, n, pad, bx, by,
+            resolve_interpret(interpret), seg_group)
+        return agg[:n_seg], suffix_w[:n_seg + 1]
 
 
 @functools.partial(jax.jit, static_argnames=("n_seg",))
@@ -484,14 +553,18 @@ def segment_window_agg_multi(xs, ys, vals, boundaries, windows, *,
     if backend == "np":
         return ref.segment_window_agg_multi_np(xs, ys, vals, boundaries,
                                                windows)
-    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
-                                                   boundaries)
-    windows = np.asarray(windows, np.float32).reshape(n_seg, 4)
-    if backend == "jnp":
-        return _segment_window_agg_multi_jnp(xs, ys, vals, sids, windows,
-                                             n, n_seg)
-    return _pallas(xs, ys, vals, sids, windows, n, n_seg=n_seg,
-                   window=True, interpret=interpret)[:, 0]
+    with span(KERNEL_PACK):
+        xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                       boundaries)
+        windows = np.asarray(windows, np.float32).reshape(n_seg, 4)
+        if backend == "pallas":
+            windows = _pallas_table(windows, n_seg)
+    with span(KERNEL_DISPATCH):
+        if backend == "jnp":
+            return _segment_window_agg_multi_jnp(xs, ys, vals, sids,
+                                                 windows, n, n_seg)
+        return _pallas(xs, ys, vals, sids, windows, n, n_seg=n_seg,
+                       window=True, interpret=interpret)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("n_seg", "bx", "by"))
@@ -517,16 +590,18 @@ def segment_window_bin_agg_multi(xs, ys, vals, boundaries, windows, *, bx,
     if backend == "np":
         return ref.segment_window_bin_agg_multi_np(xs, ys, vals, boundaries,
                                                    windows, bx, by)
-    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
-                                                   boundaries)
-    if backend == "jnp":
-        return _segment_window_bin_agg_multi_jnp(
-            xs, ys, vals, sids, np.asarray(windows, np.float32), n, n_seg,
-            bx, by)
-    return _pallas(xs, ys, vals, sids,
-                   ref.window_bin_thresholds(windows, bx, by), n,
-                   n_seg=n_seg, window=True, gx=bx, gy=by,
-                   interpret=interpret)
+    with span(KERNEL_PACK):
+        xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                       boundaries)
+        table = (np.asarray(windows, np.float32) if backend == "jnp" else
+                 _pallas_table(ref.window_bin_thresholds(windows, bx, by),
+                               n_seg))
+    with span(KERNEL_DISPATCH):
+        if backend == "jnp":
+            return _segment_window_bin_agg_multi_jnp(
+                xs, ys, vals, sids, table, n, n_seg, bx, by)
+        return _pallas(xs, ys, vals, sids, table, n, n_seg=n_seg,
+                       window=True, gx=bx, gy=by, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("n_seg", "bx", "by"))
@@ -587,25 +662,32 @@ def segment_window_bin_select_multi(xs, ys, vals, boundaries, windows,
         return fused_select.segment_window_bin_select_multi_np(
             xs, ys, vals, boundaries, windows, bx, by, vmin_s, vmax_s,
             qbounds)
-    n_seg = len(boundaries) - 1
-    qb = (np.array([0, n_seg], np.int64) if qbounds is None
-          else np.asarray(qbounds, np.int64))
-    qend = np.repeat(qb[1:], np.diff(qb)).astype(np.int32)
-    xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
-                                                   boundaries)
-    vmin_s = np.asarray(vmin_s, np.float32)
-    vmax_s = np.asarray(vmax_s, np.float32)
-    if backend == "jnp":
-        return _segment_window_bin_select_multi_jnp(
-            xs, ys, vals, sids, ref.window_bin_params(windows, bx, by),
-            vmin_s, vmax_s, qend, n, n_seg, bx, by)
-    pad, (params, vmin_s, vmax_s) = _seg_bucket(
-        n_seg, ref.window_bin_thresholds(windows, bx, by), vmin_s, vmax_s)
-    qend = np.concatenate([qend, np.full(pad - n_seg, pad, np.int32)])
-    agg, suffix_w = _segment_window_bin_select_multi_pallas(
-        xs, ys, vals, sids, params, vmin_s, vmax_s, qend, n, pad, bx, by,
-        resolve_interpret(interpret), seg_group)
-    return agg[:n_seg], suffix_w[:n_seg]
+    with span(KERNEL_PACK):
+        n_seg = len(boundaries) - 1
+        qb = (np.array([0, n_seg], np.int64) if qbounds is None
+              else np.asarray(qbounds, np.int64))
+        qend = np.repeat(qb[1:], np.diff(qb)).astype(np.int32)
+        xs, ys, vals, sids, n, n_seg = _segment_stream(xs, ys, vals,
+                                                       boundaries)
+        vmin_s = np.asarray(vmin_s, np.float32)
+        vmax_s = np.asarray(vmax_s, np.float32)
+        if backend == "jnp":
+            params = ref.window_bin_params(windows, bx, by)
+        else:
+            pad, (params, vmin_s, vmax_s) = _seg_bucket(
+                n_seg, ref.window_bin_thresholds(windows, bx, by), vmin_s,
+                vmax_s)
+            qend = np.concatenate([qend, np.full(pad - n_seg, pad,
+                                                 np.int32)])
+    with span(KERNEL_DISPATCH):
+        if backend == "jnp":
+            return _segment_window_bin_select_multi_jnp(
+                xs, ys, vals, sids, params, vmin_s, vmax_s, qend, n, n_seg,
+                bx, by)
+        agg, suffix_w = _segment_window_bin_select_multi_pallas(
+            xs, ys, vals, sids, params, vmin_s, vmax_s, qend, n, pad, bx,
+            by, resolve_interpret(interpret), seg_group)
+        return agg[:n_seg], suffix_w[:n_seg]
 
 
 def window_count(xs, ys, window, *, n=None, backend=None):
@@ -627,4 +709,5 @@ __all__ = ["window_agg", "bin_agg", "segment_window_agg", "segment_bin_agg",
            "segment_window_agg_multi", "segment_window_bin_agg_multi",
            "segment_window_bin_select_multi",
            "window_count", "window_mask_np", "pack2d", "default_backend",
-           "index_backend", "resolve_interpret"]
+           "index_backend", "resolve_interpret", "object_bucket",
+           "segment_bucket", "kernel_cols", "h2d_bytes"]

@@ -1,0 +1,31 @@
+"""The program's host spans: one phase of the query path each.
+
+``span`` is ``jax.profiler.TraceAnnotation``: with no trace running it
+costs about a microsecond; under ``jax.profiler.trace`` it is recorded
+on the same clock as the device's operations. Spans open once per phase
+of a query or round, never per tile.
+"""
+import jax
+
+span = jax.profiler.TraceAnnotation
+
+PREFIX = "aqp."
+QUERY = PREFIX + "query"                      # AQPEngine.query / .heatmap
+CLASSIFY = PREFIX + "classify"                # pending-set construction
+REFINE = PREFIX + "refine"                    # RefinementDriver.run
+GATHER = PREFIX + "gather"                    # a round's gathered segments
+READ = PREFIX + "read"                        # RawDataset.read_values
+KERNEL = PREFIX + "kernel"                    # one data-plane op call
+KERNEL_PACK = KERNEL + ".pack"                # host padding and tables
+KERNEL_DISPATCH = KERNEL + ".dispatch"        # jit dispatch, H2D copies
+KERNEL_WAIT = KERNEL + ".wait"                # device wait and D2H copy
+APPLY = PREFIX + "apply"                      # TileIndex.apply_batch
+PREDICT = PREFIX + "predict"                  # ViewportPredictor.observe
+INIT_BIN = PREFIX + "init.bin"                # TileIndex init pass phases
+INIT_SORT = PREFIX + "init.sort"
+INIT_PERMUTE = PREFIX + "init.permute"
+INIT_META = PREFIX + "init.meta"
+
+NAMES = (QUERY, CLASSIFY, REFINE, GATHER, READ, KERNEL, KERNEL_PACK,
+         KERNEL_DISPATCH, KERNEL_WAIT, APPLY, PREDICT, INIT_BIN, INIT_SORT,
+         INIT_PERMUTE, INIT_META)
