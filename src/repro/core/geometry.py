@@ -47,6 +47,32 @@ def bin_cell_ids(xs: np.ndarray, ys: np.ndarray, bbox, gx: int,
     return cy * gx + cx
 
 
+def bin_thresholds(bbox, gx: int, gy: int):
+    """Float32 thresholds ``(tx, ty)`` of :func:`bin_cell_ids` over
+    float32 points: ``cx = Σ_j 1[x ≥ tx_j]``, ``cy = Σ_j 1[y ≥ ty_j]``.
+
+    Each threshold is found by bisection on :func:`bin_cell_ids` itself,
+    in whatever precision it evaluates, so a device that bins by
+    comparison (no division, which a TPU does not round correctly)
+    assigns every float32 point the host's cell."""
+    x0, y0 = bbox[0], bbox[1]
+
+    def axis(g, cell_of):
+        j = np.arange(1, g)
+
+        def pred(t):
+            # far outside the domain the rule overflows; no point is there
+            with np.errstate(invalid="ignore", over="ignore"):
+                return cell_of(t) >= j
+        return ref_mod.f32_thresholds(pred, (g - 1,))
+
+    tx = axis(gx, lambda t: bin_cell_ids(t, np.full_like(t, y0), bbox,
+                                         gx, gy))
+    ty = axis(gy, lambda t: bin_cell_ids(np.full_like(t, x0), t, bbox,
+                                         gx, gy) // gx)
+    return tx, ty
+
+
 def subtile_bboxes(bbox, gx: int, gy: int) -> np.ndarray:
     """(gx*gy, 4) extents of the even gx×gy split of bbox (row-major y)."""
     x0, y0, x1, y1 = bbox

@@ -125,6 +125,8 @@ class AdaptStats:
     h2d_bytes: int = 0         # host arrays handed to device kernels
     batch_rounds: int = 0      # gathered-read refinement rounds
     speculative_rows: int = 0  # rows read in a round but never folded
+    init_device_rows: int = 0  # rows the init pass binned and ordered on
+                               # the device
 
     def snapshot(self):
         return dataclasses.replace(self)
@@ -181,21 +183,30 @@ class TileIndex:
             OrderedDict()
 
         # --- initialization pass (the "crude" index) ---
+        # file row id per slot (perm) and the axis values in perm order:
+        # binned, stably ordered and permuted on the device when the data
+        # plane is one, by the host rule on the "np" mirror (the
+        # bit-for-bit reference the device route is tested against)
         gx, gy = config.grid0
-        with span(spans.INIT_BIN):
-            domain = dataset.domain()
-            # widen max edge epsilon so ownership clamping matches extents
-            self.domain = domain
-            cell_ids = geometry.bin_cell_ids(dataset.x, dataset.y, domain,
-                                             gx, gy)
-        with span(spans.INIT_SORT):
-            perm = np.argsort(cell_ids, kind="stable")
-        with span(spans.INIT_PERMUTE):
-            self.perm = perm.astype(np.int64)      # file row id per slot
-            self.x_s = dataset.x[perm]             # axis values, perm order
-            self.y_s = dataset.y[perm]
-            counts = np.bincount(cell_ids, minlength=gx * gy)
-            offsets = np.concatenate([[0], np.cumsum(counts)[:-1]])
+        domain = dataset.domain()
+        self.domain = domain
+        if self._backend == "np":
+            with span(spans.INIT_BIN):
+                cell_ids = geometry.bin_cell_ids(dataset.x, dataset.y,
+                                                 domain, gx, gy)
+            with span(spans.INIT_SORT):
+                perm = np.argsort(cell_ids, kind="stable")
+            with span(spans.INIT_PERMUTE):
+                self.perm = perm.astype(np.int64)
+                self.x_s = dataset.x[perm]
+                self.y_s = dataset.y[perm]
+                counts = np.bincount(cell_ids, minlength=gx * gy)
+        else:
+            self.perm, self.x_s, self.y_s, counts = ops.init_order(
+                dataset.x, dataset.y,
+                *geometry.bin_thresholds(domain, gx, gy))
+            self.adapt_stats.init_device_rows += n
+        offsets = np.concatenate([[0], np.cumsum(counts)[:-1]])
         boxes = geometry.subtile_bboxes(domain, gx, gy)
         t = gx * gy
         self.bbox[:t] = boxes
@@ -1112,6 +1123,8 @@ class ChunkIndexSet:
         if ti is None:
             ti = TileIndex(chunk.data, self.cfg)
             # one shared adaptation ledger across the forest
+            self.adapt_stats.init_device_rows += \
+                ti.adapt_stats.init_device_rows
             ti.adapt_stats = self.adapt_stats
             self._indexes[chunk.chunk_id] = ti
         return ti

@@ -21,10 +21,15 @@ KERNEL_DISPATCH = KERNEL + ".dispatch"        # jit dispatch, H2D copies
 KERNEL_WAIT = KERNEL + ".wait"                # device wait and D2H copy
 APPLY = PREFIX + "apply"                      # TileIndex.apply_batch
 PREDICT = PREFIX + "predict"                  # ViewportPredictor.observe
-INIT_BIN = PREFIX + "init.bin"                # TileIndex init pass phases
+# TileIndex init pass phases. On the host ("np") route: cell ids, their
+# stable argsort, the permuted axes. On the device route
+# (ops.init_order): shipping the axes and dispatching the binning, then
+# dispatching the sorts, then waiting for the sorted rows, copying them
+# into the permutation and gathering the axes by them.
+INIT_BIN = PREFIX + "init.bin"
 INIT_SORT = PREFIX + "init.sort"
 INIT_PERMUTE = PREFIX + "init.permute"
-INIT_META = PREFIX + "init.meta"
+INIT_META = PREFIX + "init.meta"              # the init metadata's read
 
 NAMES = (QUERY, CLASSIFY, REFINE, GATHER, READ, KERNEL, KERNEL_PACK,
          KERNEL_DISPATCH, KERNEL_WAIT, APPLY, PREDICT, INIT_BIN, INIT_SORT,

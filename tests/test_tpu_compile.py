@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import IndexConfig
 from repro.kernels import ops
 
 N = 16384 * 128     # objects per packed call: 16 K rows of 128 lanes
@@ -111,3 +112,24 @@ def test_compile_shapes_cover_the_planner():
     assert plan_cell_groups(64, 64)[1] > 1
     assert np.all([plan_cell_groups(n, k)[0] * k <= 512
                    for n in (1, 8, 64) for k in (1, 4, 16, 64)])
+
+
+def test_init_order_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The init pass's device route at its full chunk on the default
+    16×16 grid: binning by threshold and the one-key sort fit one
+    chip."""
+    gx, gy = IndexConfig().grid0
+    g = gx * gy
+    shift = 32 - g.bit_length()
+    c = min(ops.INIT_CHUNK_ROWS, 1 << shift)
+    plane = _shape(one_chip, (c,))
+    keys = ops._init_keys.lower(
+        plane, plane, _shape(one_chip, (gx - 1,)),
+        _shape(one_chip, (gy - 1,)), _shape(one_chip, (), jnp.int32),
+        shift=shift).compile()
+    order = ops._init_order.lower(
+        _shape(one_chip, (c,), jnp.uint32), g=g, shift=shift).compile()
+    for compiled in (keys, order):
+        mem = compiled.memory_analysis()
+        if mem is not None:
+            assert mem.temp_size_in_bytes < 2 ** 30
