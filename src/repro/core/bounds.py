@@ -228,6 +228,17 @@ class PendingTile:
         return 0.5 * (self.vmin + self.vmax)
 
 
+@dataclasses.dataclass(frozen=True)
+class TickCounters:
+    """The serving tick that answered a query
+    (:meth:`~repro.core.serving.ServingEngine.tick`)."""
+    tick: int              # the epoch the tick read (0 for the first tick)
+    tick_tickets: int      # tickets the tick served
+    queue_s: float         # this ticket's wait, queued to the tick's start
+    publish_s: float       # the tick's EpochStage.publish
+    splits_masked: int     # split requests that publication masked
+
+
 @dataclasses.dataclass
 class QueryResult:
     agg: str
@@ -250,6 +261,7 @@ class QueryResult:
     retired_during_query: bool = False  # a chunk retired mid-query; its
     #                            tiles were dropped from the answer set
     eval_time_s: float = 0.0
+    serving: Optional[TickCounters] = None  # ServingEngine answers only
 
 
 class QueryAccumulator:
@@ -459,6 +471,7 @@ class HeatmapResult:
     phi_b: Optional[np.ndarray] = None
     eps_abs: float = 0.0
     bin_met: Optional[np.ndarray] = None
+    serving: Optional[TickCounters] = None  # ServingEngine answers only
 
     def grid(self, a: Optional[np.ndarray] = None) -> np.ndarray:
         """Reshape a per-bin vector (default: values) to (by, bx)."""

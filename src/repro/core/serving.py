@@ -73,19 +73,29 @@ reads are credited to every participant). The per-part session
 bin-grid registry is re-keyed canonically before publication (last
 overlapping heatmap ticket by arrival), so registry evolution matches
 the sequential reference too.
+
+Spans and counters: a tick opens ``aqp.serve.tick``; each lock-step
+round ``aqp.serve.round``, holding its gathers (``aqp.gather``), fused
+passes (``aqp.kernel``) and per-query folds (``aqp.serve.fold``); the
+publication ``aqp.serve.publish``. Every answer carries its tick's
+:class:`~repro.core.bounds.TickCounters` in ``result.serving``.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
+from fractions import Fraction
 from typing import Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
+from .. import spans
 from ..kernels import ops
 from ..kernels.segment_agg import MAX_SEGMENTS, MAX_GROUP_CELLS
+from ..spans import span
 from . import query as query_mod
-from .bounds import AccuracyPolicy, HeatmapResult, QueryResult
+from .bounds import AccuracyPolicy, HeatmapResult, QueryResult, TickCounters
 from .engine import AQPEngine, EngineTrace
 from .index import ChunkIndexSet, EpochStage, _chunk_overlaps
 from .predict import (TrajectoryStep, ViewportPredictor, prefetch_crack,
@@ -130,6 +140,7 @@ class Ticket:
     batch_k: Optional[int] = None
     dwell_s: float = 1.0
     result: Optional[Union[QueryResult, HeatmapResult]] = None
+    queued_s: float = 0.0        # perf_counter when it joined the queue
 
     @property
     def done(self) -> bool:
@@ -203,6 +214,10 @@ class _QueryRun:
         self.objects_read = 0
         self.read_calls = 0
         self.rounds = 0
+        # the op calls of the tick's fused passes this query's items
+        # rode in, and their H2D bytes, in equal shares per call
+        self.op_calls = Fraction(0)
+        self.op_bytes = Fraction(0)
         self.finish_time: Optional[float] = None
 
         # ---- phase 1: build (frozen-epoch classification) ----
@@ -307,7 +322,8 @@ class _QueryRun:
         self.stage.set_owner(self.i)
         self.stage.stage_apply(self.index, payload, n_used, flags)
 
-    def build_result(self, now: float, t0: float):
+    def build_result(self, now: float, t0: float, kernel_calls: int,
+                     h2d_bytes: int):
         tk = self.tk
         eval_s = (self.finish_time if self.finish_time is not None
                   else now) - t0
@@ -317,6 +333,7 @@ class _QueryRun:
             tiles_processed=self.processed,
             objects_read=self.objects_read, read_calls=self.read_calls,
             batch_rounds=self.rounds, speculative_rows=self.speculative,
+            kernel_calls=kernel_calls, h2d_bytes=h2d_bytes,
             pruned_chunks=self.pruned,
             retired_during_query=self.dropped > 0, eval_time_s=eval_s)
         if tk.kind == "query":
@@ -397,11 +414,13 @@ class ServingEngine:
                 ticket.policy, s.predictor, ticket.window, ticket.bins)
         s.trace.trajectory.append(TrajectoryStep(
             ticket.window, ticket.bins, ticket.dwell_s))
-        s.predictor.observe(ticket.window, bins=ticket.bins,
-                            dwell_s=ticket.dwell_s)
+        with span(spans.PREDICT):
+            s.predictor.observe(ticket.window, bins=ticket.bins,
+                                dwell_s=ticket.dwell_s)
         s._last_attr = ticket.attr
         if ticket.bins is not None:
             s._last_bins = ticket.bins
+        ticket.queued_s = time.perf_counter()
         self._queue.append(ticket)
         return ticket
 
@@ -451,27 +470,45 @@ class ServingEngine:
     def tick(self, *, mode: Optional[str] = None):
         """Serve every queued ticket as one epoch: all queries read the
         frozen pre-tick index, staged refinement publishes atomically
-        at the end. Returns the tickets' results in arrival order."""
+        at the end. Returns the tickets' results in arrival order, each
+        with the tick's :class:`~repro.core.bounds.TickCounters`.
+
+        A result's ``kernel_calls`` / ``h2d_bytes`` are its share of the
+        tick's op calls: each fused pass is credited in equal shares to
+        the queries whose items it packs, each published round's apply
+        to the query that staged it. Without prefetch they sum to the
+        tick's ``AdaptStats`` delta."""
         mode = mode or self.mode
         tickets, self._queue = self._queue, []
         if not tickets:
             return []
-        stage = EpochStage()
-        grants = self._crack_grants(tickets)
-        self.last_grants = grants
-        t0 = time.perf_counter()
-        if mode == "sequential":
-            self._tick_sequential(tickets, stage, grants)
-        elif mode == "batched":
-            self._tick_batched(tickets, stage, grants, t0)
-        else:
-            raise ValueError(f"unknown serving mode {mode!r}")
-        self.last_prefetch = self._prefetch_predicted(tickets, stage,
-                                                      grants)
-        self.last_publish = stage.publish()
-        self.epoch += 1
-        for tk in tickets:
-            tk.session.trace.results.append(tk.result)
+        with span(spans.SERVE_TICK):
+            t0 = time.perf_counter()
+            stage = EpochStage()
+            grants = self._crack_grants(tickets)
+            self.last_grants = grants
+            if mode == "sequential":
+                self._tick_sequential(tickets, stage, grants)
+            elif mode == "batched":
+                self._tick_batched(tickets, stage, grants, t0)
+            else:
+                raise ValueError(f"unknown serving mode {mode!r}")
+            self.last_prefetch = self._prefetch_predicted(tickets, stage,
+                                                          grants)
+            with span(spans.SERVE_PUBLISH):
+                t_pub = time.perf_counter()
+                self.last_publish = stage.publish()
+                publish_s = time.perf_counter() - t_pub
+            for i, tk in enumerate(tickets):
+                calls, h2d = stage.owner_ops.get(i, (0, 0))
+                tk.result.kernel_calls += calls
+                tk.result.h2d_bytes += h2d
+                tk.result.serving = TickCounters(
+                    tick=self.epoch, tick_tickets=len(tickets),
+                    queue_s=t0 - tk.queued_s, publish_s=publish_s,
+                    splits_masked=self.last_publish["splits_masked"])
+                tk.session.trace.results.append(tk.result)
+            self.epoch += 1
         return [tk.result for tk in tickets]
 
     def _prefetch_predicted(self, tickets, stage, grants) -> List[dict]:
@@ -551,7 +588,8 @@ class ServingEngine:
                 entries.append((qr, np.asarray(batch, np.int64)))
             if not entries:
                 break
-            self._execute_round(entries)
+            with span(spans.SERVE_ROUND):
+                self._execute_round(entries)
             now = time.perf_counter()
             for qr, _ in entries:
                 # stamp latency the moment the stopping rule fires
@@ -561,8 +599,10 @@ class ServingEngine:
                     qr.finish_time = now
         self._canonicalize_hm(tickets)
         now = time.perf_counter()
-        for qr in runs:
-            qr.tk.result = qr.build_result(now, t0)
+        calls = _apportion([qr.op_calls for qr in runs])
+        h2d = _apportion([qr.op_bytes for qr in runs])
+        for qr, c, b in zip(runs, calls, h2d):
+            qr.tk.result = qr.build_result(now, t0, c, b)
 
     # -- micro-round execution ---------------------------------------- #
     def _entry_runs(self, batch):
@@ -611,24 +651,25 @@ class ServingEngine:
 
         # per query: reassemble contribs + payload across its runs and
         # run the driver's fold/stage epilogue
-        for qr, batch, idxs in per_entry:
-            contribs = []
-            for j in idxs:
-                contribs.extend(items[j]["contribs"])
-            if not isinstance(self.index, ChunkIndexSet):
-                payload = items[idxs[0]]["payload"]
-            else:
-                runs, g_bounds, base = [], [np.zeros(1, np.int64)], 0
+        with span(spans.SERVE_FOLD):
+            for qr, batch, idxs in per_entry:
+                contribs = []
                 for j in idxs:
-                    it = items[j]
-                    runs.append((it["ti"], it["payload"], it["s"],
-                                 it["e"]))
-                    g_bounds.append(base + it["payload"]["bounds"][1:])
-                    base += int(it["payload"]["bounds"][-1])
-                payload = {"tile_ids": batch,
-                           "bounds": np.concatenate(g_bounds),
-                           "runs": runs, "attr": qr.tk.attr}
-            qr.fold(batch, contribs, payload)
+                    contribs.extend(items[j]["contribs"])
+                if not isinstance(self.index, ChunkIndexSet):
+                    payload = items[idxs[0]]["payload"]
+                else:
+                    runs, g_bounds, base = [], [np.zeros(1, np.int64)], 0
+                    for j in idxs:
+                        it = items[j]
+                        runs.append((it["ti"], it["payload"], it["s"],
+                                     it["e"]))
+                        g_bounds.append(base + it["payload"]["bounds"][1:])
+                        base += int(it["payload"]["bounds"][-1])
+                    payload = {"tile_ids": batch,
+                               "bounds": np.concatenate(g_bounds),
+                               "runs": runs, "attr": qr.tk.attr}
+                qr.fold(batch, contribs, payload)
 
     def _read_group(self, group_items) -> None:
         """One gathered read + packed kernel passes for every item of a
@@ -644,11 +685,12 @@ class ServingEngine:
                     it["local"], attr)
                 it["qr"].read_calls += 1
             return
-        all_local = np.concatenate([it["local"] for it in group_items])
-        idx, bounds = ti._gather_segments(all_local)
-        rows = ti.perm[idx]
-        vals = ti.ds.read_values(attr, rows)   # ← ONE accounted read
-        xs, ys = ti.x_s[idx], ti.y_s[idx]
+        with span(spans.GATHER):
+            all_local = np.concatenate([it["local"] for it in group_items])
+            idx, bounds = ti._gather_segments(all_local)
+            rows = ti.perm[idx]
+            vals = ti.ds.read_values(attr, rows)   # ← ONE accounted read
+            xs, ys = ti.x_s[idx], ti.y_s[idx]
         ti.adapt_stats.batch_rounds += 1
 
         # per-item segment spans within the group gather
@@ -671,9 +713,11 @@ class ServingEngine:
                     np.asarray(it["qr"].tk.window, np.float64),
                     (len(it["local"]), 4))
                 for it in its])
+            # the query of each segment, whom its pass is credited to
+            owners = [it["qr"] for it in its for _ in it["local"]]
             if fam[0] == 0:
-                agg = self._scalar_multi(ti, xs[a:b], ys[a:b], vals[a:b],
-                                         f_bounds, windows)
+                agg = self._scalar_multi(ti, owners, xs[a:b], ys[a:b],
+                                         vals[a:b], f_bounds, windows)
                 contribs = [
                     (int(agg[s, 0]), float(agg[s, 1]), float(agg[s, 2]),
                      float(agg[s, 3]))
@@ -704,8 +748,8 @@ class ServingEngine:
                 vmax_s = np.concatenate(
                     [ti.meta_max[attr][it["local"]] for it in its])
                 agg, suffix_w = self._heatmap_multi(
-                    ti, xs[a:b], ys[a:b], vals[a:b], f_bounds, windows,
-                    vmin_s, vmax_s, qbounds, bx, by)
+                    ti, owners, xs[a:b], ys[a:b], vals[a:b], f_bounds,
+                    windows, vmin_s, vmax_s, qbounds, bx, by)
                 contribs = [
                     (agg[s, :, 0].astype(np.int64), agg[s, :, 1].copy(),
                      agg[s, :, 2].copy(), agg[s, :, 3].copy())
@@ -741,8 +785,19 @@ class ServingEngine:
                 payload["hm_contribs"] = it["contribs"]
             it["payload"] = payload
 
-    def _heatmap_multi(self, ti, xs, ys, vals, bounds, windows, vmin_s,
-                       vmax_s, qbounds, bx, by):
+    @staticmethod
+    def _run_op(ti, owners, op, *args, h2d: int, **kwargs):
+        """``ti.run_op``; the call and its H2D bytes are credited in
+        equal shares to the distinct queries in ``owners``."""
+        out = ti.run_op(op, *args, h2d=h2d, **kwargs)
+        runs = list(dict.fromkeys(owners))
+        for qr in runs:
+            qr.op_calls += Fraction(1, len(runs))
+            qr.op_bytes += Fraction(h2d, len(runs))
+        return out
+
+    def _heatmap_multi(self, ti, owners, xs, ys, vals, bounds, windows,
+                       vmin_s, vmax_s, qbounds, bx, by):
         """One ``segment_window_bin_select_multi`` pass; device backends
         are chunked to the packed kernels' static segment limit at
         QUERY-SPAN boundaries (suffix widths are per-span quantities, so
@@ -752,10 +807,11 @@ class ServingEngine:
         # the grouped kernel's table, then vmin, vmax and query end
         cols = ops.kernel_cols(True, bx, by) + 3
         if ti._backend == "np" or n_seg <= MAX_SEGMENTS:
-            return ti.run_op(
-                ops.segment_window_bin_select_multi, xs, ys, vals, bounds,
-                windows, vmin_s, vmax_s, qbounds, bx=bx, by=by,
-                h2d=ops.h2d_bytes(ti._backend, bounds[-1], n_seg, cols))
+            return self._run_op(
+                ti, owners, ops.segment_window_bin_select_multi, xs, ys,
+                vals, bounds, windows, vmin_s, vmax_s, qbounds, bx=bx,
+                by=by, h2d=ops.h2d_bytes(ti._backend, bounds[-1], n_seg,
+                                         cols))
         qb = np.asarray(qbounds, np.int64)
         aggs, sufs = [], []
         s = 0
@@ -765,8 +821,8 @@ class ServingEngine:
                 e += 1
             a, b = int(qb[s]), int(qb[e])
             o0, o1 = int(bounds[a]), int(bounds[b])
-            agg, suf = ti.run_op(
-                ops.segment_window_bin_select_multi,
+            agg, suf = self._run_op(
+                ti, owners[a:b], ops.segment_window_bin_select_multi,
                 xs[o0:o1], ys[o0:o1], vals[o0:o1],
                 bounds[a:b + 1] - bounds[a], windows[a:b],
                 vmin_s[a:b], vmax_s[a:b], qb[s:e + 1] - qb[s],
@@ -777,23 +833,25 @@ class ServingEngine:
             s = e
         return np.concatenate(aggs), np.concatenate(sufs)
 
-    def _scalar_multi(self, ti, xs, ys, vals, bounds, windows):
+    def _scalar_multi(self, ti, owners, xs, ys, vals, bounds, windows):
         """One ``segment_window_agg_multi`` pass; device backends are
         chunked to the packed kernels' static segment limit (the host
         "np" mirror — the default control plane — has none)."""
         n_seg = len(bounds) - 1
         cols = ops.kernel_cols(True)
         if ti._backend == "np" or n_seg <= MAX_SEGMENTS:
-            return ti.run_op(
-                ops.segment_window_agg_multi, xs, ys, vals, bounds, windows,
+            return self._run_op(
+                ti, owners, ops.segment_window_agg_multi, xs, ys, vals,
+                bounds, windows,
                 h2d=ops.h2d_bytes(ti._backend, bounds[-1], n_seg, cols))
         outs = []
         for s in range(0, n_seg, MAX_SEGMENTS):
             e = min(s + MAX_SEGMENTS, n_seg)
             a, b = int(bounds[s]), int(bounds[e])
-            outs.append(ti.run_op(
-                ops.segment_window_agg_multi, xs[a:b], ys[a:b], vals[a:b],
-                bounds[s:e + 1] - bounds[s], windows[s:e],
+            outs.append(self._run_op(
+                ti, owners[s:e], ops.segment_window_agg_multi, xs[a:b],
+                ys[a:b], vals[a:b], bounds[s:e + 1] - bounds[s],
+                windows[s:e],
                 h2d=ops.h2d_bytes(ti._backend, b - a, e - s, cols)))
         return np.concatenate(outs)
 
@@ -820,6 +878,18 @@ class ServingEngine:
             if ti is not None and _chunk_overlaps(chunk.bbox, window):
                 out.append(ti)
         return out
+
+
+def _apportion(shares: List[Fraction]) -> List[int]:
+    """Whole numbers that sum to ``sum(shares)`` (itself whole): each
+    share's floor, the largest remainders (first in arrival on ties)
+    rounded up."""
+    out = [math.floor(x) for x in shares]
+    left = int(sum(shares)) - sum(out)
+    order = sorted(range(len(shares)), key=lambda i: out[i] - shares[i])
+    for i in order[:left]:
+        out[i] += 1
+    return out
 
 
 __all__ = ["ServingEngine", "Session", "Ticket", "NullStage"]

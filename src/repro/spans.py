@@ -30,7 +30,16 @@ INIT_BIN = PREFIX + "init.bin"
 INIT_SORT = PREFIX + "init.sort"
 INIT_PERMUTE = PREFIX + "init.permute"
 INIT_META = PREFIX + "init.meta"              # the init metadata's read
+# ServingEngine: a tick, each lock-step round of a batched tick, a
+# round's per-query fold and staging, and the tick's epoch publication.
+# A tick's gathers and its sessions' predictor updates open GATHER and
+# PREDICT.
+SERVE_TICK = PREFIX + "serve.tick"
+SERVE_ROUND = PREFIX + "serve.round"
+SERVE_FOLD = PREFIX + "serve.fold"
+SERVE_PUBLISH = PREFIX + "serve.publish"
 
 NAMES = (QUERY, CLASSIFY, REFINE, GATHER, READ, KERNEL, KERNEL_PACK,
          KERNEL_DISPATCH, KERNEL_WAIT, APPLY, PREDICT, INIT_BIN, INIT_SORT,
          INIT_PERMUTE, INIT_META)
+SERVE_NAMES = (SERVE_TICK, SERVE_ROUND, SERVE_FOLD, SERVE_PUBLISH)
