@@ -16,7 +16,7 @@ bounded index, and is exactly the layout the Pallas data plane wants
 
 Metadata soundness rule: ``min/max`` for a tile are ALWAYS present and
 always sound (children inherit the parent's bounds until refined, and
-split-child extremes from the float32 kernels are clamped into the
+a split child's extremes, taken from its own rows, are clamped into the
 parent's sound interval; the root fallback is the global attribute
 min/max from the init pass). ``sum`` is present only when marked valid
 (``meta_valid``); a fully-contained tile whose sum is not valid for the
@@ -24,18 +24,19 @@ queried attribute is handled as *pending enrichment* by the query layer —
 bounded, never wrong.
 
 Refinement runs in two flavors with identical semantics: the sequential
-reference path (:meth:`TileIndex.process` — one raw-file read + one
-kernel per tile) and the batched pipeline
+reference path (:meth:`TileIndex.process` — one raw-file read per
+tile, folded and split on the host) and the batched pipeline
 (:meth:`TileIndex.read_batch`/:meth:`TileIndex.apply_batch` — per round
 of ``IndexConfig.batch_k`` tiles, one gathered read, one packed
-``segment_window_agg``/``segment_bin_agg`` kernel, and one vectorized SoA
-append of all children).
+``segment_window_agg`` kernel, and one vectorized SoA append of all
+children, whose metadata the host takes from the rows it has just
+ordered by child).
 """
 from __future__ import annotations
 
 import dataclasses
 from collections import OrderedDict
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -140,6 +141,29 @@ class AdaptStats:
 # an all-covering closed window: segment aggregation over it yields the
 # full-segment (enrichment) statistics
 EVERYWHERE = (-np.inf, -np.inf, np.inf, np.inf)
+
+
+def _child_metadata(vals_sorted, starts, counts, pmn, pmx):
+    """Per-child ``(sum, min, max)`` of the processed attribute after a
+    split, from the split's rows ordered child by child: child j owns
+    ``vals_sorted[starts[j]:starts[j] + counts[j]]``, and the children's
+    rows fill ``vals_sorted`` back to back. Sums are float64 over each
+    child's rows in order. The extremes of float32 rows are exact; they
+    are clamped into the parent's sound interval ``[pmn[j], pmx[j]]``,
+    whose bounds an empty child inherits."""
+    sums = np.array([vals_sorted[s:s + c].sum(dtype=np.float64)
+                     for s, c in zip(starts, counts)], np.float64)
+    mn = np.array(pmn, np.float64)
+    mx = np.array(pmx, np.float64)
+    nz = counts > 0
+    if nz.any():
+        # reduceat over the non-empty children's starts only: at a
+        # repeated start it returns that one row, and a start equal to
+        # the length raises
+        st = starts[nz]
+        mn[nz] = np.maximum(np.minimum.reduceat(vals_sorted, st), pmn[nz])
+        mx[nz] = np.minimum(np.maximum.reduceat(vals_sorted, st), pmx[nz])
+    return sums, mn, mx
 
 
 class TileIndex:
@@ -490,13 +514,13 @@ class TileIndex:
 
     def _split(self, tile_id: int, vals: np.ndarray, attr: str,
                edges=None):
-        """Split + reorganize + per-child metadata (one bin_agg pass).
+        """Split + reorganize + per-child metadata (from the rows the
+        split orders by child, see :func:`_child_metadata`).
 
         ``edges=(x_edges, y_edges)`` cuts along explicit (bin-aligned)
         split lines instead of the even gx×gy subdivision; ownership is
-        then ``geometry.edge_cell_ids``'s rule, child metadata comes
-        from the edges variant of the packed split kernel, and the split
-        GRID is the edges' own (bin-count-matched grids vary per tile).
+        then ``geometry.edge_cell_ids``'s rule, and the split GRID is
+        the edges' own (bin-count-matched grids vary per tile).
         """
         if edges is None:
             gx, gy = self.cfg.split_grid
@@ -506,11 +530,7 @@ class TileIndex:
             self.adapt_stats.tiles_enriched += 1
             return
         o, c = int(self.offset[tile_id]), int(self.count[tile_id])
-        # NOTE: copies, not views — the segment reorganization below
-        # writes into self.x_s/y_s in place and bin_agg must see the
-        # pristine (coordinate, value)-aligned arrays
-        xs = self.x_s[o:o + c].copy()
-        ys = self.y_s[o:o + c].copy()
+        xs, ys = self.x_s[o:o + c], self.y_s[o:o + c]
         bbox = self.bbox[tile_id]
 
         if edges is None:
@@ -521,20 +541,6 @@ class TileIndex:
             boxes = geometry.bboxes_from_edges(edges[0], edges[1])
         counts = np.bincount(cell, minlength=gx * gy)
         child_off = o + np.concatenate([[0], np.cumsum(counts)[:-1]])
-
-        # child metadata for the processed attribute: one binned pass
-        # (data plane — Pallas bin_agg kernel on TPU)
-        cols = ops.kernel_cols(False, gx, gy)
-        if edges is None:
-            agg = self.run_op(ops.bin_agg, xs, ys, vals, bbox, gx=gx, gy=gy,
-                              h2d=ops.h2d_bytes(self._backend, c, 1, cols,
-                                                stream=False))
-        else:
-            agg = self.run_op(ops.segment_bin_agg_edges, xs, ys, vals,
-                              np.array([0, c], np.int64), edges[0][None],
-                              edges[1][None],
-                              h2d=ops.h2d_bytes(self._backend, c, 1,
-                                                cols))[0]
 
         order = np.argsort(cell, kind="stable")
         # local reorganization of the parent's segment
@@ -558,24 +564,12 @@ class TileIndex:
 
         for a in self.meta_sum:
             if a == attr:
-                nonzero = counts > 0
-                # the parent's bounds are exact (just enriched) and sound;
-                # the kernel's float32 child extremes may round past the
-                # true f64 extremes — clamp children into the parent's
-                # interval so metadata soundness holds exactly
-                pmn = self.meta_min[a][tile_id]
-                pmx = self.meta_max[a][tile_id]
-                self.meta_sum[a][sl] = agg[:, 1].astype(np.float64)
-                self.meta_min[a][sl] = np.where(
-                    nonzero, np.maximum(agg[:, 2], pmn), pmn)
-                self.meta_max[a][sl] = np.where(
-                    nonzero, np.minimum(agg[:, 3], pmx), pmx)
+                (self.meta_sum[a][sl], self.meta_min[a][sl],
+                 self.meta_max[a][sl]) = _child_metadata(
+                    vals_sorted, child_off - o, counts,
+                    np.full(k, self.meta_min[a][tile_id]),
+                    np.full(k, self.meta_max[a][tile_id]))
                 self.meta_valid[a][sl] = True
-                # float32 kernel sums → recompute exact f64 sums per child
-                for j in range(k):
-                    oj, cj = child_off[j], counts[j]
-                    self.meta_sum[a][t0 + j] = float(
-                        vals_sorted[oj - o:oj - o + cj].sum(dtype=np.float64))
             else:
                 # inherit sound min/max bounds; sum unknown for children
                 self.meta_min[a][sl] = self.meta_min[a][tile_id]
@@ -791,7 +785,7 @@ class TileIndex:
         # invariant AND children get the same ids as under sequential
         # processing (run grouping preserves the fold order), while
         # homogeneous rounds — the common case — still split in one
-        # packed kernel pass
+        # vectorized pass
         pos = np.flatnonzero(will_split)
         r = 0
         while r < len(pos):
@@ -878,16 +872,6 @@ class TileIndex:
             [np.zeros((s_n, 1), np.int64),
              np.cumsum(counts_sk, axis=1)[:, :-1]], axis=1)
 
-        # child metadata for the processed attribute: one packed kernel
-        h2d = ops.h2d_bytes(self._backend, bounds[-1], s_n,
-                            ops.kernel_cols(False, gx, gy))
-        if edges is None:
-            agg = self.run_op(ops.segment_bin_agg, xs, ys, vals, bounds,
-                              bboxes, gx=gx, gy=gy, h2d=h2d)
-        else:
-            agg = self.run_op(ops.segment_bin_agg_edges, xs, ys, vals,
-                              bounds, edges[0], edges[1], h2d=h2d)
-
         # one global stable argsort reorganizes every parent's segment
         # (keys are segment-major, so the permutation never crosses
         # segment boundaries — identical to the per-tile counting sort)
@@ -917,24 +901,12 @@ class TileIndex:
         rel_off = child_off - off[:, None] + bounds[:-1, None]
         for a in self.meta_sum:
             if a == attr:
-                nonzero = counts_sk > 0
-                pmn = self.meta_min[a][parents][:, None]
-                pmx = self.meta_max[a][parents][:, None]
-                # clamp float32 kernel extremes into the parents' sound
-                # intervals (same rule as the sequential _split)
-                self.meta_min[a][sl] = np.where(
-                    nonzero, np.maximum(agg[:, :, 2], pmn), pmn).reshape(-1)
-                self.meta_max[a][sl] = np.where(
-                    nonzero, np.minimum(agg[:, :, 3], pmx), pmx).reshape(-1)
+                (self.meta_sum[a][sl], self.meta_min[a][sl],
+                 self.meta_max[a][sl]) = _child_metadata(
+                    vals_sorted, rel_off.reshape(-1), counts_sk.reshape(-1),
+                    np.repeat(self.meta_min[a][parents], k),
+                    np.repeat(self.meta_max[a][parents], k))
                 self.meta_valid[a][sl] = True
-                # float32 kernel sums → exact f64 sums per child
-                flat_rel = rel_off.reshape(-1)
-                flat_cnt = counts_sk.reshape(-1)
-                sums = np.empty(s_n * k, np.float64)
-                for j in range(s_n * k):
-                    sums[j] = vals_sorted[flat_rel[j]:flat_rel[j] +
-                                          flat_cnt[j]].sum(dtype=np.float64)
-                self.meta_sum[a][sl] = sums
             else:
                 # inherit sound min/max bounds; sum unknown for children
                 self.meta_min[a][sl] = np.repeat(self.meta_min[a][parents], k)
@@ -1017,9 +989,6 @@ class EpochStage:
         #                           n_used, split_flags)
         self._seq = 0
         self._owner = 0
-        # owner -> [op calls, H2D bytes] its rounds' applies made in the
-        # last publish (the split-metadata kernel)
-        self.owner_ops: Dict[int, List[int]] = {}
 
     def set_owner(self, owner: int) -> None:
         """Tag subsequent staged rounds with the owning query's arrival
@@ -1052,15 +1021,13 @@ class EpochStage:
         """Apply every staged round atomically (no concurrent readers by
         construction — the tick has quiesced). Returns publication
         counters: rounds applied and split requests masked by the
-        first-claimant rule. The op calls each owner's applies make are
-        left in :attr:`owner_ops`."""
+        first-claimant rule."""
         entries = sorted(self._entries, key=lambda en: (en[0], en[1]))
         self._entries = []
-        self.owner_ops = {}
         claimed = set()
         masked = 0
         applied = 0
-        for owner, _, ti, payload, used, flags in entries:
+        for _, _, ti, payload, used, flags in entries:
             if used == 0 or payload.get("dead"):
                 continue
             eff = []
@@ -1073,12 +1040,7 @@ class EpochStage:
                 elif want:
                     claimed.add(key)
                 eff.append(want)
-            st = ti.adapt_stats
-            calls, h2d = st.kernel_calls, st.h2d_bytes
             ti.apply_batch(payload, used, eff)
-            credit = self.owner_ops.setdefault(owner, [0, 0])
-            credit[0] += st.kernel_calls - calls
-            credit[1] += st.h2d_bytes - h2d
             applied += 1
         return {"rounds_published": applied, "splits_masked": masked}
 

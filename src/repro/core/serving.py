@@ -475,9 +475,8 @@ class ServingEngine:
 
         A result's ``kernel_calls`` / ``h2d_bytes`` are its share of the
         tick's op calls: each fused pass is credited in equal shares to
-        the queries whose items it packs, each published round's apply
-        to the query that staged it. Without prefetch they sum to the
-        tick's ``AdaptStats`` delta."""
+        the queries whose items it packs (publication makes none).
+        Without prefetch they sum to the tick's ``AdaptStats`` delta."""
         mode = mode or self.mode
         tickets, self._queue = self._queue, []
         if not tickets:
@@ -499,10 +498,7 @@ class ServingEngine:
                 t_pub = time.perf_counter()
                 self.last_publish = stage.publish()
                 publish_s = time.perf_counter() - t_pub
-            for i, tk in enumerate(tickets):
-                calls, h2d = stage.owner_ops.get(i, (0, 0))
-                tk.result.kernel_calls += calls
-                tk.result.h2d_bytes += h2d
+            for tk in tickets:
                 tk.result.serving = TickCounters(
                     tick=self.epoch, tick_tickets=len(tickets),
                     queue_s=t0 - tk.queued_s, publish_s=publish_s,

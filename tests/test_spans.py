@@ -124,6 +124,15 @@ def test_spans_nest(traced, inner, outer):
     assert inner_iv and _inside(inner_iv, _intervals(host, outer))
 
 
+def test_apply_opens_no_kernel(traced):
+    """A round's apply takes split children's metadata from the rows it
+    orders on the host: no op call under ``aqp.apply``."""
+    host = traced[0]
+    applies = _intervals(host, spans.APPLY)
+    assert applies and not any(_inside([iv], applies)
+                               for iv in _intervals(host, spans.KERNEL))
+
+
 def test_wait_follows_dispatch(traced):
     host = traced[0]
     dispatch = _intervals(host, spans.KERNEL_DISPATCH)
