@@ -1,7 +1,7 @@
 """Benchmark harness — one entry per paper table/figure/claim.
 
-Prints ``name,us_per_call,derived`` CSV rows (B1–B6), then the roofline
-table (§Roofline) if dry-run artifacts exist under experiments/dryrun.
+Prints ``name,us_per_call,derived`` CSV rows (B1–B9) and writes them
+to ``experiments/BENCH_{smoke,full}.json``.
 
     PYTHONPATH=src python -m benchmarks.run            # full size
     PYTHONPATH=src python -m benchmarks.run --smoke    # tiny-n CI smoke
@@ -63,16 +63,6 @@ def main(smoke: bool = False) -> None:
     with open(path, "w") as f:
         json.dump(out, f, indent=1)
     print(f"# wrote {path} ({len(common.EMITTED)} rows)")
-
-    dd = "experiments/dryrun"
-    if os.path.isdir(dd) and any(f.endswith(".json")
-                                 for f in os.listdir(dd)):
-        print()
-        from repro.launch import roofline
-        roofline.print_table(dd)
-    else:
-        print("# roofline: no dry-run artifacts under experiments/dryrun "
-              "(run: PYTHONPATH=src python -m repro.launch.dryrun)")
 
 
 if __name__ == "__main__":

@@ -12,9 +12,10 @@ predictive/geometric round sizing, gathered reads, the per-item stopping
 rule, and prefix-exact side-effect application — lives in ONE place,
 :class:`repro.core.refine.RefinementDriver`, shared verbatim by scalar
 and heatmap queries (and mirrored in SPMD form by
-``core.distributed``). :func:`evaluate` and :func:`evaluate_heatmap`
-are thin wrappers: build the accumulator, hand it to the driver with the
-matching index adapter, read the final interval off the accumulator.
+``core.distributed``). :func:`classify` builds the accumulator and
+hands it to a driver with the matching index adapter; :func:`evaluate`
+and :func:`evaluate_heatmap` run that driver and read the final
+interval off the accumulator, and the serving tick steps it.
 
 Evaluation of a query (window Q, aggregate, attribute A, constraint φ):
 
@@ -45,6 +46,7 @@ round size.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import Optional, Tuple
 
@@ -111,14 +113,77 @@ def _build_accumulator(index, window, agg: str, attr: str):
     return acc, full_set, n_full, n_partial
 
 
-def evaluate(index, window, agg: str, attr: str,
-             phi: float = 0.0, alpha: float = 1.0, *,
-             batch_k: Optional[int] = None,
-             sequential: bool = False, stage=None) -> QueryResult:
-    # chunked forests materialize overlapped chunks' indexes BEFORE the
-    # per-query snapshot: lazy build cost is index-construction I/O
-    # (init_rows + init-metadata reads on the chunk's own stats), same
-    # accounting moment as legacy engine construction
+@dataclasses.dataclass
+class Classified:
+    """A query after classification: its driver, ready to refine, the
+    counts of tiles it overlaps, and the clock and I/O and adapt
+    counters read after ``prepare`` and before classification."""
+    driver: RefinementDriver
+    agg: str
+    attr: str
+    bins: Optional[Tuple[int, int]]      # None for a scalar query
+    n_full: int
+    n_partial: int
+    t_start: float
+    io_before: object
+    adapt_before: object
+
+    def run(self, sequential: bool = False):
+        """Refine with the driver's own reads and answer, the costs
+        read off the index's counters."""
+        self.driver.run(sequential=sequential)
+        index = self.driver.index
+        io_delta = index.ds.stats.delta(self.io_before)
+        adapt_delta = index.adapt_stats.delta(self.adapt_before)
+        return self.answer(
+            objects_read=io_delta.rows_read,
+            read_calls=io_delta.read_calls,
+            batch_rounds=adapt_delta.batch_rounds,
+            speculative_rows=adapt_delta.speculative_rows,
+            kernel_calls=adapt_delta.kernel_calls,
+            h2d_bytes=adapt_delta.h2d_bytes,
+            pruned_chunks=io_delta.pruned_calls,
+            eval_time_s=time.perf_counter() - self.t_start)
+
+    def answer(self, **costs):
+        """The result read off the accumulator, with the given cost
+        fields."""
+        driver = self.driver
+        acc = driver.acc
+        common = dict(
+            agg=self.agg, attr=self.attr, exact=not acc.pending,
+            tiles_full=self.n_full, tiles_partial=self.n_partial,
+            tiles_processed=driver.processed,
+            retired_during_query=driver.dropped > 0, **costs)
+        if self.bins is None:
+            value, lo, hi, bound = acc.interval()
+            return QueryResult(value=float(value), lo=float(lo),
+                               hi=float(hi), bound=float(bound), **common)
+        values, lo, hi, bin_bound, bound = acc.interval()
+        policy_active = acc.phi_b is not None
+        return HeatmapResult(
+            bins=self.bins, values=np.asarray(values, np.float64),
+            lo=np.asarray(lo, np.float64), hi=np.asarray(hi, np.float64),
+            bin_bound=np.asarray(bin_bound, np.float64),
+            bound=float(bound),
+            phi_b=acc.phi_b.copy() if policy_active else None,
+            eps_abs=acc.eps_abs,
+            bin_met=acc.bin_satisfied(driver.phi) if policy_active else None,
+            **common)
+
+
+def classify(index, window, agg: str, attr: str, phi: float,
+             alpha: float = 1.0, *, bins: Optional[Tuple[int, int]] = None,
+             policy: Optional[AccuracyPolicy] = None,
+             batch_k: Optional[int] = None, stage=None) -> Classified:
+    """Steps 1–3 of every query, scalar (``bins=None``) or heatmap: the
+    accumulator and its index adapter, under a driver ready to refine.
+
+    Chunked forests materialize overlapped chunks' indexes BEFORE the
+    per-query snapshot: lazy build cost is index-construction I/O
+    (init_rows + init-metadata reads on the chunk's own stats), same
+    accounting moment as legacy engine construction.
+    """
     prepare = getattr(index, "prepare", None)
     if prepare is not None:
         prepare(window, attr)
@@ -127,30 +192,31 @@ def evaluate(index, window, agg: str, attr: str,
     adapt_before = index.adapt_stats.snapshot()
     with span(CLASSIFY):
         index.ensure_attr(attr)
-        acc, full_set, n_full, n_partial = _build_accumulator(
-            index, window, agg, attr)
+        if bins is None:
+            acc, full_set, n_full, n_partial = _build_accumulator(
+                index, window, agg, attr)
+            adapter = ScalarQueryAdapter(index, window, attr, full_set)
+        else:
+            # (no full-tile set here: heatmap refinement splits every
+            # processed tile — see HeatmapQueryAdapter)
+            acc, n_full, n_partial = _build_grouped_accumulator(
+                index, window, agg, attr, bins)
+            adapter = HeatmapQueryAdapter(index, window, attr, bins)
+    if policy is not None and phi > 0.0:
+        acc.set_policy(policy, phi, bins)
+    driver = RefinementDriver(acc, adapter, phi, alpha, stage=stage,
+                              batch_k=batch_k)
+    return Classified(driver, agg, attr, bins, n_full, n_partial, t_start,
+                      io_before, adapt_before)
 
-    driver = RefinementDriver(
-        acc, ScalarQueryAdapter(index, window, attr, full_set), phi, alpha,
-        stage=stage)
-    processed = driver.run(batch_k=batch_k, sequential=sequential)
 
-    value, lo, hi, bound = acc.interval()
-    io_delta = index.ds.stats.delta(io_before)
-    adapt_delta = index.adapt_stats.delta(adapt_before)
-    return QueryResult(
-        agg=agg, attr=attr, value=float(value), lo=float(lo), hi=float(hi),
-        bound=float(bound), exact=not acc.pending,
-        tiles_full=n_full, tiles_partial=n_partial,
-        tiles_processed=processed, objects_read=io_delta.rows_read,
-        read_calls=io_delta.read_calls,
-        batch_rounds=adapt_delta.batch_rounds,
-        speculative_rows=adapt_delta.speculative_rows,
-        kernel_calls=adapt_delta.kernel_calls,
-        h2d_bytes=adapt_delta.h2d_bytes,
-        pruned_chunks=io_delta.pruned_calls,
-        retired_during_query=driver.dropped > 0,
-        eval_time_s=time.perf_counter() - t_start)
+def evaluate(index, window, agg: str, attr: str,
+             phi: float = 0.0, alpha: float = 1.0, *,
+             batch_k: Optional[int] = None,
+             sequential: bool = False, stage=None) -> QueryResult:
+    c = classify(index, window, agg, attr, phi, alpha, batch_k=batch_k,
+                 stage=stage)
+    return c.run(sequential)
 
 
 def _build_grouped_accumulator(index, window, agg: str,
@@ -252,52 +318,13 @@ def evaluate_heatmap(index, window, agg: str, attr: str,
     trivial policy (or φ = 0, the exact method) leaves behavior
     bit-for-bit unchanged.
     """
-    prepare = getattr(index, "prepare", None)
-    if prepare is not None:
-        prepare(window, attr)
-    t_start = time.perf_counter()
-    io_before = index.ds.stats.snapshot()
-    adapt_before = index.adapt_stats.snapshot()
     bx, by = int(bins[0]), int(bins[1])
     assert bx > 0 and by > 0
     assert np.isfinite(np.asarray(window, np.float64)).all(), \
         "heatmap windows must be finite rectangles"
-    # (no full-tile set here: heatmap refinement splits every processed
-    # tile — see HeatmapQueryAdapter)
-    with span(CLASSIFY):
-        index.ensure_attr(attr)
-        acc, n_full, n_partial = _build_grouped_accumulator(
-            index, window, agg, attr, (bx, by))
-    if policy is not None and phi > 0.0:
-        acc.set_policy(policy, phi, (bx, by))
-
-    driver = RefinementDriver(
-        acc, HeatmapQueryAdapter(index, window, attr, (bx, by)), phi, alpha,
-        stage=stage)
-    processed = driver.run(batch_k=batch_k, sequential=sequential)
-
-    values, lo, hi, bin_bound, bound = acc.interval()
-    io_delta = index.ds.stats.delta(io_before)
-    adapt_delta = index.adapt_stats.delta(adapt_before)
-    policy_active = acc.phi_b is not None
-    return HeatmapResult(
-        agg=agg, attr=attr, bins=(bx, by),
-        values=np.asarray(values, np.float64),
-        lo=np.asarray(lo, np.float64), hi=np.asarray(hi, np.float64),
-        bin_bound=np.asarray(bin_bound, np.float64), bound=float(bound),
-        exact=not acc.pending, tiles_full=n_full, tiles_partial=n_partial,
-        tiles_processed=processed, objects_read=io_delta.rows_read,
-        read_calls=io_delta.read_calls,
-        batch_rounds=adapt_delta.batch_rounds,
-        speculative_rows=adapt_delta.speculative_rows,
-        kernel_calls=adapt_delta.kernel_calls,
-        h2d_bytes=adapt_delta.h2d_bytes,
-        pruned_chunks=io_delta.pruned_calls,
-        retired_during_query=driver.dropped > 0,
-        eval_time_s=time.perf_counter() - t_start,
-        phi_b=acc.phi_b.copy() if policy_active else None,
-        eps_abs=acc.eps_abs,
-        bin_met=acc.bin_satisfied(phi) if policy_active else None)
+    c = classify(index, window, agg, attr, phi, alpha, bins=(bx, by),
+                 policy=policy, batch_k=batch_k, stage=stage)
+    return c.run(sequential)
 
 
 def evaluate_heatmap_oracle(index, window, agg: str, attr: str,

@@ -37,7 +37,9 @@ Refinement side effects apply to exactly the folded prefix of each round
 (``TileIndex.apply_batch``), so the stopping rule, decision sequence,
 f64 arithmetic, AND the index evolution are identical to the sequential
 per-tile reference path (``sequential=True``) — batching changes the
-cost model, not the semantics.
+cost model, not the semantics. The serving tick steps the same driver
+(``next_batch`` / ``fold``) and supplies each round's read from its
+fused multi-query pass, so a tick's queries refine by this loop too.
 
 ``core.distributed`` is the OTHER backend of the same skeleton: its
 jitted session steps run this loop with the fold unrolled into one
@@ -171,10 +173,17 @@ class HeatmapQueryAdapter:
 
 class RefinementDriver:
     """One score → round-size → read → fold → apply loop for every query
-    type; see the module docstring for the contract."""
+    type; see the module docstring for the contract.
+
+    :meth:`run` drives the loop itself. The serving tick, which fuses
+    the reads of many queries into one pass, steps it instead:
+    :meth:`next_batch` gives the next round's tile ids (``None`` once
+    the query is done), the caller reads them, and :meth:`fold` takes
+    the read. Both ways make the same decisions round for round.
+    """
 
     def __init__(self, acc, adapter, phi: float, alpha: float = 1.0,
-                 stage=None):
+                 stage=None, batch_k: Optional[int] = None):
         # the index is the adapter's: reads, splits, and accounting must
         # hit the same object, so the driver never takes a separate one.
         # It may be a TileIndex or a ChunkIndexSet — both present cfg,
@@ -193,66 +202,22 @@ class RefinementDriver:
         # a query's rounds touch disjoint tiles, so deferring applies
         # past its own reads never changes its fold decisions.
         self.stage = stage
-        # pending tiles dropped because their chunk retired mid-query
-        # (the answer then covers only the still-live data)
+        # tiles folded; pending tiles dropped because their chunk
+        # retired mid-query (the answer then covers only the still-live
+        # data); rounds folded; rows read past the stopping point
+        self.processed = 0
         self.dropped = 0
-
-    def _met(self, bound: float) -> bool:
-        return met(self.phi, bound)
-
-    def run(self, *, batch_k: Optional[int] = None,
-            sequential: bool = False) -> int:
-        """Refine until the bound meets φ (or pending is exhausted).
-
-        Returns the number of tiles processed (folded). Mutates the
-        accumulator and — through ``process_one`` / ``apply_batch`` —
-        the index.
-        """
-        with span(REFINE):
-            return self._run(batch_k, sequential)
-
-    def _run(self, batch_k: Optional[int], sequential: bool) -> int:
-        acc = self.acc
-        bound = acc.query_bound()
-        if not acc.pending or self._met(bound):
-            return 0
-        order = self.adapter.score_order(acc, self.alpha)
-        if sequential:
-            assert self.stage is None, \
-                "epoch staging requires the batched path"
-            return self._run_sequential(order, bound)
-        return self._run_batched(order, bound, batch_k)
-
-    def _run_sequential(self, order, bound) -> int:
-        """Per-tile reference path: one read + one kernel per tile. The
-        batched path must match it bit-for-bit on counts and index
-        evolution, to f64 tolerance on sums."""
-        acc = self.acc
-        processed = 0
-        for t in order:
-            if self._met(bound):
-                break
-            contrib = self.adapter.process_one(t)
-            if contrib is None:          # chunk retired mid-query
-                acc.drop_pending(t)
-                self.dropped += 1
-            else:
-                acc.fold_exact(t, *contrib)
-                processed += 1
-            bound = acc.query_bound()
-        return processed
-
-    def _run_batched(self, order, bound, batch_k: Optional[int]) -> int:
-        acc, phi, index = self.acc, self.phi, self.index
-        k = index.cfg.batch_k if batch_k is None else int(batch_k)
+        self.rounds = 0
+        self.speculative_rows = 0
+        k = self.index.cfg.batch_k if batch_k is None else int(batch_k)
         # packed kernels take at most MAX_SEGMENTS segments and plan
         # MAX_GROUP_CELLS (segment, cell) pairs per program — cap the
         # round size at their limits, sized
         # by the LARGEST split grid this adapter's rounds may carry
         # (heatmap: bin-count-matched grids up to max_split_span per
         # axis; scalar: the even split_grid)
-        k = max(1, min(k, MAX_SEGMENTS,
-                       MAX_GROUP_CELLS // self.adapter.max_split_cells()))
+        self._k = max(1, min(k, MAX_SEGMENTS,
+                             MAX_GROUP_CELLS // adapter.max_split_cells()))
         # Round sizing under φ>0: the stopping rule can fire mid-round
         # and rows read past it are speculative. For sum/mean the needed
         # fold count has a certain lower bound (min_folds_needed) —
@@ -260,77 +225,151 @@ class RefinementDriver:
         # min/max a geometric ramp (1, 2, 4, …, k) bounds the overshoot
         # by the last round. φ=0 processes every pending tile anyway →
         # full-size rounds, zero waste.
-        predictive = phi > 0.0 and acc.agg in ("sum", "mean")
-        size = 1 if phi > 0.0 else k
-        processed, pos, stop = 0, 0, False
-        while pos < len(order) and not stop and not self._met(bound):
-            if predictive:
-                size = acc.min_folds_needed(order[pos:], phi)
-            batch = order[pos:pos + min(size, k)]
-            pos += len(batch)
-            if not predictive:
-                size = min(size * 2, k)
-            contribs, payload = self.adapter.read_batch(batch)
-            n_used = 0
-            wholesale = all(c is not None for c in contribs)
-            if wholesale and not predictive and len(batch) > 1:
-                # the fused select pass's suffix widths extend the
-                # certainty fast path beyond predictive sizing: if the
-                # residual width entering the round's LAST fold already
-                # exceeds some bin's budget, no interim stopping check
-                # can pass (suffix rows are non-increasing) — covers
-                # φ=0 and full-size rounds the sizing argument doesn't.
-                # (Single-tile rounds have no interim check at all.)
-                row = round_residual(payload)
-                wholesale = row is not None and acc.round_certain(row, phi)
-            if wholesale:
-                # certainty fast path: the stopping rule provably cannot
-                # fire before the round's last fold (min_folds_needed is
-                # a CERTAIN lower bound; round_certain is its reverse) —
-                # every interim _met/query_bound of the loop below is a
-                # no-op. Fold the whole batch and re-derive the bound
-                # once. (Any dropped tile falls back to the per-fold
-                # loop: a drop removes width differently from a fold
-                # and the certainty arguments no longer cover it.)
-                for t, contrib in zip(batch, contribs):
-                    acc.fold_exact(t, *contrib)
-                n_used = len(batch)
-                processed += len(batch)
-                bound = acc.query_bound()
-                contribs = ()            # consumed
-            for t, contrib in zip(batch, contribs):
-                if self._met(bound):
-                    stop = True
-                    break
-                if contrib is None:      # chunk retired mid-query: drop
-                    # the tile from the answer set. It still counts into
-                    # the applied prefix — its (dead) payload applies as
-                    # a no-op, keeping the prefix aligned for live runs
-                    acc.drop_pending(t)
-                    self.dropped += 1
-                    n_used += 1
-                    bound = acc.query_bound()
-                    continue
-                acc.fold_exact(t, *contrib)
-                n_used += 1
-                processed += 1
-                bound = acc.query_bound()
-            # rows of tiles read this round but never folded were
-            # speculative — account them so predictive sizing's zero-
-            # overshoot guarantee is observable per query
-            bounds_ = payload["bounds"]
-            index.adapt_stats.speculative_rows += int(
-                bounds_[len(batch)] - bounds_[n_used])
-            # refinement applies to exactly the folded prefix, so the
-            # index evolves bit-for-bit as under sequential processing —
-            # either in place, or staged for epoch publication when the
-            # serving layer holds the index frozen for concurrent readers
-            flags = self.adapter.split_flags(batch[:n_used])
-            if self.stage is not None:
-                self.stage.stage_apply(index, payload, n_used, flags)
+        self._predictive = self.phi > 0.0 and acc.agg in ("sum", "mean")
+        self._size = 1 if self.phi > 0.0 else self._k
+        self._order: Optional[List[int]] = None
+        self._bound = 0.0
+        self._pos = 0
+        self._stop = False
+
+    def _met(self, bound: float) -> bool:
+        return met(self.phi, bound)
+
+    def _scored(self) -> List[int]:
+        """The score order, computed on first use; empty when the
+        pending-interval bounds already meet φ (the metadata fast path:
+        zero reads, zero side effects)."""
+        if self._order is None:
+            self._bound = self.acc.query_bound()
+            done = not self.acc.pending or self._met(self._bound)
+            self._order = ([] if done else
+                           self.adapter.score_order(self.acc, self.alpha))
+        return self._order
+
+    @property
+    def finished(self) -> bool:
+        """The stopping rule has fired or no pending tile is left."""
+        order = self._scored()
+        return (self._stop or self._pos >= len(order)
+                or self._met(self._bound))
+
+    def run(self, *, sequential: bool = False) -> int:
+        """Refine until the bound meets φ (or pending is exhausted).
+
+        Returns the number of tiles processed (folded). Mutates the
+        accumulator and — through ``process_one`` / ``apply_batch`` —
+        the index.
+        """
+        with span(REFINE):
+            if sequential:
+                assert self.stage is None, \
+                    "epoch staging requires the batched path"
+                self._run_sequential()
             else:
-                index.apply_batch(payload, n_used, flags)
-        return processed
+                while (batch := self.next_batch()) is not None:
+                    self.fold(batch, *self.adapter.read_batch(batch))
+        return self.processed
+
+    def _run_sequential(self) -> None:
+        """Per-tile reference path: one read + one kernel per tile. The
+        batched path must match it bit-for-bit on counts and index
+        evolution, to f64 tolerance on sums."""
+        acc = self.acc
+        for t in self._scored():
+            if self._met(self._bound):
+                break
+            contrib = self.adapter.process_one(t)
+            if contrib is None:          # chunk retired mid-query
+                acc.drop_pending(t)
+                self.dropped += 1
+            else:
+                acc.fold_exact(t, *contrib)
+                self.processed += 1
+            self._bound = acc.query_bound()
+
+    def next_batch(self) -> Optional[List[int]]:
+        """The round head: the next round's tile ids in score order,
+        sized predictively or by the geometric ramp; ``None`` once the
+        query is done."""
+        if self.finished:
+            return None
+        order, pos = self._order, self._pos
+        if self._predictive:
+            self._size = self.acc.min_folds_needed(order[pos:], self.phi)
+        batch = order[pos:pos + min(self._size, self._k)]
+        self._pos = pos + len(batch)
+        if not self._predictive:
+            self._size = min(self._size * 2, self._k)
+        return batch
+
+    def fold(self, batch, contribs, payload) -> None:
+        """The round body: fold the read of ``batch`` tile by tile until
+        the stopping rule fires, account the rows read past it, and
+        apply (or stage) the refinement of exactly the folded prefix."""
+        acc, phi, index = self.acc, self.phi, self.index
+        bound = self._bound
+        n_used = 0
+        wholesale = all(c is not None for c in contribs)
+        if wholesale and not self._predictive and len(batch) > 1:
+            # the fused select pass's suffix widths extend the
+            # certainty fast path beyond predictive sizing: if the
+            # residual width entering the round's LAST fold already
+            # exceeds some bin's budget, no interim stopping check
+            # can pass (suffix rows are non-increasing) — covers
+            # φ=0 and full-size rounds the sizing argument doesn't.
+            # (Single-tile rounds have no interim check at all.)
+            row = round_residual(payload)
+            wholesale = row is not None and acc.round_certain(row, phi)
+        if wholesale:
+            # certainty fast path: the stopping rule provably cannot
+            # fire before the round's last fold (min_folds_needed is
+            # a CERTAIN lower bound; round_certain is its reverse) —
+            # every interim _met/query_bound of the loop below is a
+            # no-op. Fold the whole batch and re-derive the bound
+            # once. (Any dropped tile falls back to the per-fold
+            # loop: a drop removes width differently from a fold
+            # and the certainty arguments no longer cover it.)
+            for t, contrib in zip(batch, contribs):
+                acc.fold_exact(t, *contrib)
+            n_used = len(batch)
+            self.processed += len(batch)
+            bound = acc.query_bound()
+            contribs = ()            # consumed
+        for t, contrib in zip(batch, contribs):
+            if self._met(bound):
+                self._stop = True
+                break
+            if contrib is None:      # chunk retired mid-query: drop
+                # the tile from the answer set. It still counts into
+                # the applied prefix — its (dead) payload applies as
+                # a no-op, keeping the prefix aligned for live runs
+                acc.drop_pending(t)
+                self.dropped += 1
+                n_used += 1
+                bound = acc.query_bound()
+                continue
+            acc.fold_exact(t, *contrib)
+            n_used += 1
+            self.processed += 1
+            bound = acc.query_bound()
+        self._bound = bound
+        self.rounds += 1
+        # rows of tiles read this round but never folded were
+        # speculative — account them so predictive sizing's zero-
+        # overshoot guarantee is observable per query
+        bounds_ = payload["bounds"]
+        spec = int(bounds_[len(batch)] - bounds_[n_used])
+        index.adapt_stats.speculative_rows += spec
+        self.speculative_rows += spec
+        # refinement applies to exactly the folded prefix, so the
+        # index evolves bit-for-bit as under sequential processing —
+        # either in place, or staged for epoch publication when the
+        # serving layer holds the index frozen for concurrent readers
+        flags = self.adapter.split_flags(batch[:n_used])
+        if self.stage is not None:
+            self.stage.stage_apply(index, payload, n_used, flags)
+        else:
+            index.apply_batch(payload, n_used, flags)
 
 
 @dataclasses.dataclass

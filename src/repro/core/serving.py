@@ -31,13 +31,13 @@ per round even when many same-tick queries touch the same storage.
   scalar queries with ONE packed ``segment_window_agg_multi`` pass
   (per-segment windows; see :mod:`repro.kernels.segment_agg`) and all
   same-resolution heatmap queries with ONE
-  ``segment_window_bin_agg_multi`` pass. Per-query fold loops,
-  round sizing (predictive ``min_folds_needed`` / geometric ramp), and
-  stopping are byte-identical to the private
-  :class:`~repro.core.refine.RefinementDriver`, so a micro-batched
-  tick produces bit-for-bit the same answers AND the same published
-  index evolution as ``mode="sequential"`` (the per-query reference:
-  each ticket runs its own driver against the same frozen epoch).
+  ``segment_window_bin_agg_multi`` pass. Each query's round sizing
+  (predictive ``min_folds_needed`` / geometric ramp), folds and
+  stopping are its own :class:`~repro.core.refine.RefinementDriver`'s,
+  stepped by the tick, so a micro-batched tick produces bit-for-bit
+  the same answers AND the same published index evolution as
+  ``mode="sequential"`` (the per-query reference: each ticket runs its
+  own driver, reading for itself, against the same frozen epoch).
   Cost attribution differs by construction — that is the point.
 
 - **Skip-under-contention**: a query whose phase-1 pending-interval
@@ -92,7 +92,7 @@ import numpy as np
 
 from .. import spans
 from ..kernels import ops
-from ..kernels.segment_agg import MAX_SEGMENTS, MAX_GROUP_CELLS
+from ..kernels.segment_agg import MAX_SEGMENTS
 from ..spans import span
 from . import query as query_mod
 from .bounds import AccuracyPolicy, HeatmapResult, QueryResult, TickCounters
@@ -100,8 +100,6 @@ from .engine import AQPEngine, EngineTrace
 from .index import ChunkIndexSet, EpochStage, _chunk_overlaps
 from .predict import (TrajectoryStep, ViewportPredictor, prefetch_crack,
                       resolve_learned_salience)
-from .refine import (HeatmapQueryAdapter, ScalarQueryAdapter, met,
-                     round_residual)
 
 
 class NullStage:
@@ -194,163 +192,48 @@ class Session:
 
 
 class _QueryRun:
-    """Per-ticket refinement state machine of a micro-batched tick.
-
-    Replicates :meth:`RefinementDriver._run_batched` exactly — same
-    round sizing, same per-item stopping rule, same speculative
-    accounting, same staged prefix — but yields its round batches to
-    the scheduler instead of reading itself, so the scheduler can fuse
-    all active queries' reads and kernel passes."""
+    """One ticket of a micro-batched tick: its
+    :class:`~repro.core.refine.RefinementDriver`, stepped round by round
+    by the scheduler (which fuses all active queries' reads and kernel
+    passes), and the ticket's own cost attribution."""
 
     def __init__(self, arrival: int, ticket: Ticket, index, stage,
                  may_crack: bool):
         self.i = arrival
         self.tk = ticket
-        self.index = index
         self.stage = stage if may_crack else _NULL_STAGE
-        self.processed = 0
-        self.dropped = 0
-        self.speculative = 0
         self.objects_read = 0
         self.read_calls = 0
-        self.rounds = 0
         # the op calls of the tick's fused passes this query's items
         # rode in, and their H2D bytes, in equal shares per call
         self.op_calls = Fraction(0)
         self.op_bytes = Fraction(0)
         self.finish_time: Optional[float] = None
-
-        # ---- phase 1: build (frozen-epoch classification) ----
+        # frozen-epoch classification
         tk = ticket
-        prepare = getattr(index, "prepare", None)
-        if prepare is not None:
-            prepare(tk.window, tk.attr)
-        io_before = index.ds.stats.snapshot()
-        index.ensure_attr(tk.attr)
-        if tk.kind == "query":
-            acc, full_set, n_full, n_partial = \
-                query_mod._build_accumulator(index, tk.window, tk.agg,
-                                             tk.attr)
-            self.adapter = ScalarQueryAdapter(index, tk.window, tk.attr,
-                                              full_set)
-        else:
-            acc, n_full, n_partial = query_mod._build_grouped_accumulator(
-                index, tk.window, tk.agg, tk.attr, tk.bins)
-            if tk.policy is not None and tk.phi > 0.0:
-                acc.set_policy(tk.policy, tk.phi, tk.bins)
-            self.adapter = HeatmapQueryAdapter(index, tk.window, tk.attr,
-                                               tk.bins)
-        self.pruned = index.ds.stats.delta(io_before).pruned_calls
-        self.acc = acc
-        self.phi = tk.phi
-        self.n_full, self.n_partial = n_full, n_partial
-        self.bound = acc.query_bound()
-        # the metadata fast path: pending-interval bounds already meet
-        # φ → answer with zero reads, zero staged mutation (SKIP)
-        self.finished = (not acc.pending) or met(self.phi, self.bound)
-        self.stop = False
-        self.pos = 0
-        if not self.finished:
-            self.order = self.adapter.score_order(acc, tk.alpha)
-            k = (index.cfg.batch_k if tk.batch_k is None
-                 else int(tk.batch_k))
-            self.k = max(1, min(k, MAX_SEGMENTS,
-                                MAX_GROUP_CELLS // self.adapter.max_split_cells()))
-            self.predictive = tk.phi > 0.0 and acc.agg in ("sum", "mean")
-            self.size = 1 if tk.phi > 0.0 else self.k
-        else:
-            self.order = []
-
-    def next_batch(self):
-        """The driver's round-head logic; ``None`` once finished."""
-        if self.finished:
-            return None
-        if (self.pos >= len(self.order) or self.stop
-                or met(self.phi, self.bound)):
-            self.finished = True
-            return None
-        if self.predictive:
-            self.size = self.acc.min_folds_needed(self.order[self.pos:],
-                                                  self.phi)
-        batch = self.order[self.pos:self.pos + min(self.size, self.k)]
-        self.pos += len(batch)
-        if not self.predictive:
-            self.size = min(self.size * 2, self.k)
-        return batch
+        self.query = query_mod.classify(
+            index, tk.window, tk.agg, tk.attr, tk.phi, tk.alpha,
+            bins=tk.bins, policy=tk.policy, batch_k=tk.batch_k,
+            stage=self.stage)
+        self.driver = self.query.driver
+        self.pruned = index.ds.stats.delta(
+            self.query.io_before).pruned_calls
 
     def fold(self, batch, contribs, payload) -> None:
-        """The driver's per-round fold + stage epilogue, verbatim —
-        including its certainty fast paths (predictive sizing, and the
-        fused pass's suffix-width ``round_certain`` witness), which fold
-        a round wholesale exactly when the interim stopping checks
-        provably cannot fire."""
-        acc = self.acc
-        n_used = 0
-        wholesale = all(c is not None for c in contribs)
-        if wholesale and not self.predictive and len(batch) > 1:
-            row = round_residual(payload)
-            wholesale = (row is not None
-                         and acc.round_certain(row, self.phi))
-        if wholesale:
-            for t, contrib in zip(batch, contribs):
-                acc.fold_exact(t, *contrib)
-            n_used = len(batch)
-            self.processed += len(batch)
-            self.bound = acc.query_bound()
-            contribs = ()                # consumed
-        for t, contrib in zip(batch, contribs):
-            if met(self.phi, self.bound):
-                self.stop = True
-                break
-            if contrib is None:          # chunk retired mid-query
-                acc.drop_pending(t)
-                self.dropped += 1
-                n_used += 1
-                self.bound = acc.query_bound()
-                continue
-            acc.fold_exact(t, *contrib)
-            n_used += 1
-            self.processed += 1
-            self.bound = acc.query_bound()
-        bounds_ = payload["bounds"]
-        spec = int(bounds_[len(batch)] - bounds_[n_used])
-        self.index.adapt_stats.speculative_rows += spec
-        self.speculative += spec
-        self.objects_read += int(bounds_[-1])
-        self.rounds += 1
-        flags = self.adapter.split_flags(batch[:n_used])
+        self.objects_read += int(payload["bounds"][-1])
         self.stage.set_owner(self.i)
-        self.stage.stage_apply(self.index, payload, n_used, flags)
+        self.driver.fold(batch, contribs, payload)
 
     def build_result(self, now: float, t0: float, kernel_calls: int,
                      h2d_bytes: int):
-        tk = self.tk
         eval_s = (self.finish_time if self.finish_time is not None
                   else now) - t0
-        common = dict(
-            agg=tk.agg, attr=tk.attr, exact=not self.acc.pending,
-            tiles_full=self.n_full, tiles_partial=self.n_partial,
-            tiles_processed=self.processed,
+        return self.query.answer(
             objects_read=self.objects_read, read_calls=self.read_calls,
-            batch_rounds=self.rounds, speculative_rows=self.speculative,
+            batch_rounds=self.driver.rounds,
+            speculative_rows=self.driver.speculative_rows,
             kernel_calls=kernel_calls, h2d_bytes=h2d_bytes,
-            pruned_chunks=self.pruned,
-            retired_during_query=self.dropped > 0, eval_time_s=eval_s)
-        if tk.kind == "query":
-            value, lo, hi, bound = self.acc.interval()
-            return QueryResult(value=float(value), lo=float(lo),
-                               hi=float(hi), bound=float(bound), **common)
-        values, lo, hi, bin_bound, bound = self.acc.interval()
-        policy_active = self.acc.phi_b is not None
-        return HeatmapResult(
-            bins=tk.bins, values=np.asarray(values, np.float64),
-            lo=np.asarray(lo, np.float64), hi=np.asarray(hi, np.float64),
-            bin_bound=np.asarray(bin_bound, np.float64),
-            bound=float(bound),
-            phi_b=self.acc.phi_b.copy() if policy_active else None,
-            eps_abs=self.acc.eps_abs,
-            bin_met=(self.acc.bin_satisfied(tk.phi)
-                     if policy_active else None), **common)
+            pruned_chunks=self.pruned, eval_time_s=eval_s)
 
 
 class ServingEngine:
@@ -570,18 +453,11 @@ class ServingEngine:
                 for i, tk in enumerate(tickets)]
         now = time.perf_counter()
         for qr in runs:
-            if qr.finished:
+            if qr.driver.finished:
                 qr.finish_time = now
         while True:
-            entries = []
-            for qr in runs:
-                if qr.finished:
-                    continue
-                batch = qr.next_batch()
-                if batch is None:
-                    qr.finish_time = time.perf_counter()
-                    continue
-                entries.append((qr, np.asarray(batch, np.int64)))
+            entries = [(qr, np.asarray(batch, np.int64)) for qr in runs
+                       if (batch := qr.driver.next_batch()) is not None]
             if not entries:
                 break
             with span(spans.SERVE_ROUND):
@@ -589,9 +465,7 @@ class ServingEngine:
             now = time.perf_counter()
             for qr, _ in entries:
                 # stamp latency the moment the stopping rule fires
-                if ((qr.stop or qr.pos >= len(qr.order)
-                     or met(qr.phi, qr.bound))
-                        and qr.finish_time is None):
+                if qr.driver.finished:
                     qr.finish_time = now
         self._canonicalize_hm(tickets)
         now = time.perf_counter()

@@ -672,25 +672,3 @@ def segment_window_bin_agg_np(xs, ys, vals, boundaries, window, bx, by):
             out[c] = (0, 0.0, np.inf, -np.inf)
     return out.reshape(n_seg, k, 4)
 
-
-def flash_attention_ref(q, k, v, *, causal=True, scale=None):
-    """Reference attention: (B, H, S, D) x (B, Hkv, T, D) -> (B, H, S, D).
-
-    Supports GQA (H a multiple of Hkv) by repeating KV heads.
-    """
-    b, h, s, d = q.shape
-    hkv = k.shape[1]
-    t = k.shape[2]
-    if scale is None:
-        scale = d ** -0.5
-    if hkv != h:
-        rep = h // hkv
-        k = jnp.repeat(k, rep, axis=1)
-        v = jnp.repeat(v, rep, axis=1)
-    logits = jnp.einsum("bhsd,bhtd->bhst", q, k).astype(jnp.float32) * scale
-    if causal:
-        mask = jnp.tril(jnp.ones((s, t), dtype=bool), k=t - s)
-        logits = jnp.where(mask[None, None], logits, -jnp.inf)
-    p = jnp.exp(logits - jnp.max(logits, axis=-1, keepdims=True))
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
-    return jnp.einsum("bhst,bhtd->bhsd", p.astype(v.dtype), v)

@@ -2,16 +2,12 @@
 
 ``make_production_mesh`` is a FUNCTION (not a module-level constant) so
 importing this module never touches jax device state — device count is
-locked at first jax init, and only ``dryrun.py`` forces the 512-device
-host platform.
+locked at first jax init, and only ``dryrun_aqp.py`` forces the
+512-device host platform.
 
 Topology: v5e pods of 256 chips arranged (data=16, model=16); the
 multi-pod mesh prepends a ``pod`` axis (2 × 256 = 512 chips). ``model``
-is the innermost axis → maps onto the torus' fastest contiguous links
-(TP/EP collectives per layer); ``data`` carries FSDP all-gathers and the
-per-step gradient reduce-scatter; ``pod`` carries only the once-per-step
-cross-pod gradient reduction (optionally int8-compressed — see
-``repro.optim.compression``).
+is the innermost axis → maps onto the torus' fastest contiguous links.
 """
 from __future__ import annotations
 

@@ -1,46 +1,17 @@
-"""§Roofline: three-term analysis per (arch × shape × mesh) cell.
+"""Bandwidth roofline of the AQP kernels.
 
-Reads the dry-run artifacts (``launch.dryrun`` JSON records) and derives,
-with v5e hardware constants
-
-    PEAK = 197e12 FLOP/s (bf16)   HBM = 819e9 B/s   LINK = 50e9 B/s,
-
-the per-device time lower bounds
-
-    compute    = matmul_FLOPs_per_device / PEAK
-    memory     = HBM_traffic_per_device  / HBM
-    collective = collective_bytes_per_device / LINK
-
-where the per-device quantities come from the trip-count-corrected HLO
-analysis (``launch.hlo_analysis``; ``cost_analysis()`` counts loop bodies
-once — see EXPERIMENTS.md §Methodology). The dominant term is the
-bottleneck; roofline_fraction = compute/dominant is how close the cell
-is to compute-bound (the score optimized in §Perf).
-
-MODEL_FLOPS = k·N·D with k = 6 (train: fwd+bwd) or 2 (inference), N =
-active params (MoE: shared + top-k routed), D = tokens per step. The
-ratio MODEL_FLOPS / (HLO matmul FLOPs × chips) exposes remat/redundancy
-waste (>1 ⇒ compiled program does extra matmul work: remat recompute,
-one-hot embedding, routing).
-
-A second, simpler mode serves the AQP kernels
-(:func:`aqp_kernel_roofline`): the selection/aggregation family streams
-its operand planes once and does O(1) FLOPs per byte, so the only
-meaningful bound is bytes-streamed / bandwidth per backend —
-``benchmarks/kernels_bench.py`` emits ``achieved_GB_s`` /
-``roofline_fraction`` rows against it into the ``BENCH_*.json``
-artifacts, and CI smoke asserts the jnp grouped path stays above its
-floor.
+The selection/aggregation family streams its operand planes once and
+does O(1) FLOPs per byte, so the only meaningful bound is bytes
+streamed / bandwidth per backend — ``benchmarks/kernels_bench.py``
+emits ``achieved_GB_s`` / ``roofline_fraction`` rows against it into
+the ``BENCH_*.json`` artifacts, and CI smoke asserts the jnp grouped
+path stays above its floor.
 """
 from __future__ import annotations
 
-import json
-import os
-from typing import Dict, List, Optional
+from typing import Dict
 
-PEAK = 197e12
 HBM = 819e9
-LINK = 50e9
 
 # AQP-kernel roofline: the selection/aggregation kernels do O(1) FLOPs
 # per streamed byte, so their bound is pure bandwidth — HBM on the TPU
@@ -49,8 +20,6 @@ LINK = 50e9
 # container class. achieved/bound is the kernel's roofline fraction.
 CPU_BW = 25e9
 AQP_BW = {"pallas": HBM, "jnp": CPU_BW, "np": CPU_BW}
-
-_FACTOR = {"train": 6.0, "prefill": 2.0, "decode": 2.0}
 
 
 def aqp_kernel_roofline(n_bytes: float, seconds: float,
@@ -70,99 +39,3 @@ def aqp_kernel_roofline(n_bytes: float, seconds: float,
             "achieved_GB_s": achieved / 1e9,
             "bound_GB_s": bound / 1e9,
             "roofline_fraction": achieved / bound}
-
-
-def _model_flops(arch: str, shape: str) -> Optional[float]:
-    from repro import configs as cfgreg
-    from repro.configs.shapes import SHAPES
-    from repro.models.model import active_param_count
-    try:
-        cfg = cfgreg.get(arch)
-    except KeyError:
-        return None
-    sh = SHAPES[shape]
-    n_active = active_param_count(cfg)
-    tokens = sh.batch * (sh.seq if sh.kind != "decode" else 1)
-    return _FACTOR[sh.kind] * n_active * tokens
-
-
-def load_cells(dryrun_dir: str) -> List[Dict]:
-    cells = []
-    for f in sorted(os.listdir(dryrun_dir)):
-        if f.endswith(".json"):
-            with open(os.path.join(dryrun_dir, f)) as fh:
-                cells.append(json.load(fh))
-    return cells
-
-
-def analyze_cell(rec: Dict) -> Optional[Dict]:
-    if rec.get("status") != "ok":
-        return None
-    ana = rec["hlo_analysis"]
-    devices = rec["devices"]
-    compute = ana["matmul_flops"] / PEAK
-    memory = ana["hbm_traffic_bytes"] / HBM
-    collective = ana["collective_bytes"] / LINK
-    terms = {"compute": compute, "memory": memory,
-             "collective": collective}
-    dominant = max(terms, key=terms.get)
-    t_dom = terms[dominant]
-    mf = _model_flops(rec["arch"], rec["shape"])
-    hlo_global = ana["matmul_flops"] * devices
-    ratio = (mf / hlo_global) if (mf and hlo_global) else float("nan")
-    mfu_at_roofline = (mf / devices / PEAK) / t_dom \
-        if (mf and t_dom > 0) else float("nan")
-    return {
-        "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
-        "compute_s": compute, "memory_s": memory,
-        "collective_s": collective, "dominant": dominant,
-        "roofline_fraction": compute / t_dom if t_dom else float("nan"),
-        "mfu_at_roofline": mfu_at_roofline,
-        "model_flops": mf, "hlo_flops_global": hlo_global,
-        "useful_ratio": ratio,
-        "mem_gib_per_dev": (rec["memory"]["total_bytes"] / 2**30)
-        if rec.get("memory") else float("nan"),
-        "fix_hint": _hint(dominant, rec),
-    }
-
-
-def _hint(dominant: str, rec: Dict) -> str:
-    if dominant == "collective":
-        top = max(rec["hlo_analysis"]["collective_by_type"].items(),
-                  key=lambda kv: kv[1], default=("?", 0))
-        return (f"reduce {top[0]} traffic (overlap with compute, coarser "
-                f"grain, or reshard to avoid it)")
-    if dominant == "memory":
-        return ("raise arithmetic intensity: fuse elementwise chains, "
-                "keep bf16 end-to-end, avoid re-materialized temps")
-    return "compute-bound: improve MXU utilization / drop redundant FLOPs"
-
-
-def print_table(dryrun_dir: str, mesh_filter: str = "pod16x16"):
-    cells = load_cells(dryrun_dir)
-    print(f"# Roofline (single-pod {mesh_filter}; v5e: 197 TF/s bf16, "
-          f"819 GB/s HBM, 50 GB/s link)")
-    hdr = ("arch,shape,compute_s,memory_s,collective_s,dominant,"
-           "roofline_fraction,mfu_at_roofline,useful_flops_ratio,"
-           "mem_GiB_per_dev")
-    print(hdr)
-    for rec in cells:
-        if rec.get("mesh") != mesh_filter:
-            continue
-        if rec.get("status") == "skipped":
-            print(f"{rec['arch']},{rec['shape']},,,,skipped:"
-                  f"{rec['reason'][:60]},,,,")
-            continue
-        a = analyze_cell(rec)
-        if a is None:
-            continue
-        print(f"{a['arch']},{a['shape']},{a['compute_s']:.4e},"
-              f"{a['memory_s']:.4e},{a['collective_s']:.4e},"
-              f"{a['dominant']},{a['roofline_fraction']:.3f},"
-              f"{a['mfu_at_roofline']:.3f},{a['useful_ratio']:.2f},"
-              f"{a['mem_gib_per_dev']:.2f}")
-
-
-if __name__ == "__main__":
-    import sys
-    print_table(sys.argv[1] if len(sys.argv) > 1 else "experiments/dryrun")

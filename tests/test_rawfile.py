@@ -115,3 +115,27 @@ def test_engine_mmap_end_to_end(tmp_path):
     truth = eng.oracle(w, "mean", "a1")
     assert r.lo - 1e-3 <= truth <= r.hi + 1e-3
     eng.index.check_invariants("a1")
+
+
+def test_io_accounting():
+    from repro.data import make_synthetic_dataset
+    ds = make_synthetic_dataset(n=10_000, seed=1)
+    before = ds.stats.snapshot()
+    ds.read_values("a0", np.arange(500))
+    d = ds.stats.delta(before)
+    assert d.rows_read == 500
+    assert d.bytes_read == 500 * 4
+    assert d.read_calls == 1
+
+
+def test_exploration_path_selectivity():
+    from repro.data import make_synthetic_dataset
+    from repro.data.synthetic import exploration_path
+    ds = make_synthetic_dataset(n=100_000, seed=2)
+    wins = exploration_path(ds, n_queries=10, target_objects=10_000)
+    from repro.kernels.ops import window_mask_np
+    counts = [window_mask_np(ds.x, ds.y, w).sum() for w in wins]
+    # windows hold roughly the target object count (clustered data ⇒
+    # generous tolerance; the paper says "approximately 100K")
+    assert np.median(counts) > 2_000
+    assert max(counts) < 60_000

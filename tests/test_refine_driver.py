@@ -14,12 +14,17 @@ Three contracts introduced by the driver refactor:
   identical heatmap answers with strictly fewer objects read than under
   the even 2×2 split policy (children nest inside single bins after ONE
   split).
+
+And the driver's two ways to run: stepped by hand (``next_batch`` /
+``read_batch`` / ``fold``, as the serving tick does) it refines exactly
+as ``run()`` does.
 """
 import numpy as np
 import pytest
 
 from repro.core import AQPEngine, IndexConfig
-from repro.core.query import _build_accumulator, _build_grouped_accumulator
+from repro.core.query import (_build_accumulator, _build_grouped_accumulator,
+                              classify)
 from repro.core import adapt
 from repro.data import make_synthetic_dataset
 from repro.data.synthetic import exploration_path
@@ -260,3 +265,45 @@ def test_trace_totals_breaks_out_query_types():
     assert tot["total_speculative_rows"] == (r1.speculative_rows
                                              + r2.speculative_rows
                                              + r3.speculative_rows)
+
+
+def _index_state(idx):
+    n = idx.n_tiles
+    return ([n, idx.active[:n].copy(), idx.count[:n].copy(),
+             idx.perm.copy(), idx.bbox[:n].copy()]
+            + [m[a][:n].copy() for m in (idx.meta_sum, idx.meta_min,
+                                         idx.meta_max, idx.meta_valid)
+               for a in sorted(m)])
+
+
+@pytest.mark.parametrize("agg", ["count", "sum", "mean", "min", "max"])
+@pytest.mark.parametrize("bins", [None, (4, 4)], ids=["scalar", "heatmap"])
+@pytest.mark.parametrize("phi", [0.0, 0.05])
+def test_stepped_driver_equals_run(agg, bins, phi):
+    """Stepping the driver by hand with no stage gives the same answer,
+    speculative rows, rounds and index evolution as ``run()`` on a
+    second engine over the same data, query after query."""
+    e_step = small_engine(seed=5)
+    e_run = small_engine(seed=5)
+    wins = exploration_path(e_step.dataset, n_queries=4,
+                            target_objects=2000)
+    rounds = 0
+    for w in wins:
+        stepped = classify(e_step.index, w, agg, "a0", phi,
+                           bins=bins).driver
+        while (batch := stepped.next_batch()) is not None:
+            stepped.fold(batch, *stepped.adapter.read_batch(batch))
+        assert stepped.finished
+        ran = classify(e_run.index, w, agg, "a0", phi, bins=bins).driver
+        assert ran.run() == ran.processed == stepped.processed
+        for a, b in zip(stepped.acc.interval(), ran.acc.interval()):
+            np.testing.assert_array_equal(a, b)
+        assert stepped.speculative_rows == ran.speculative_rows
+        assert stepped.rounds == ran.rounds
+        for a, b in zip(_index_state(e_step.index),
+                        _index_state(e_run.index)):
+            np.testing.assert_array_equal(a, b)
+        rounds += stepped.rounds
+    e_step.index.check_invariants("a0")
+    # every case reads, but a count at φ > 0, which its metadata answers
+    assert (rounds > 0) == (agg != "count" or phi == 0.0)

@@ -55,15 +55,15 @@ def _script(rng):
     return ticks
 
 
-def _play(server, sessions, ticks, *, phi=PHI):
+def _play(server, sessions, ticks, *, agg="mean", phi=PHI):
     out = []
     for subs in ticks:
         for sid, kind, win, bins in subs:
             s = sessions[sid]
             if kind == "query":
-                s.query(win, "mean", "a0", phi=phi)
+                s.query(win, agg, "a0", phi=phi)
             else:
-                s.heatmap(win, "mean", "a0", bins=bins, phi=phi)
+                s.heatmap(win, agg, "a0", bins=bins, phi=phi)
         out.extend(server.tick())
     return out
 
@@ -114,11 +114,17 @@ def _assert_answers_equal(ra, rb):
 
 @pytest.mark.parametrize("chunked", [False, True])
 @pytest.mark.parametrize("crack_budget", [None, 1])
-def test_batched_tick_equals_sequential(chunked, crack_budget):
+@pytest.mark.parametrize("agg", ["count", "sum", "mean", "min", "max"])
+@pytest.mark.parametrize("phi", [0.0, PHI])
+def test_batched_tick_equals_sequential(chunked, crack_budget, agg, phi):
     """The tentpole contract: a micro-batched tick produces bit-for-bit
     the same answers AND the same published index evolution as the
     per-query sequential reference — with and without the
-    skip-under-contention budget."""
+    skip-under-contention budget. Each aggregate and φ takes its own
+    branch of the driver's round head and body: predictive sizing (sum,
+    mean at φ > 0), the geometric ramp (min, max at φ > 0), full-size
+    rounds and the suffix-width certainty check (φ = 0), and the
+    metadata fast path (count)."""
     sa = _server(chunked=chunked, mode="batched",
                  crack_budget=crack_budget)
     sb = _server(chunked=chunked, mode="sequential",
@@ -126,8 +132,8 @@ def test_batched_tick_equals_sequential(chunked, crack_budget):
     ses_a = [sa.open_session() for _ in range(2)]
     ses_b = [sb.open_session() for _ in range(2)]
     ticks = _script(np.random.default_rng(7))
-    ra = _play(sa, ses_a, ticks)
-    rb = _play(sb, ses_b, ticks)
+    ra = _play(sa, ses_a, ticks, agg=agg, phi=phi)
+    rb = _play(sb, ses_b, ticks, agg=agg, phi=phi)
     assert len(ra) == len(rb) == sum(len(t) for t in ticks)
     for x, y in zip(ra, rb):
         _assert_answers_equal(x, y)

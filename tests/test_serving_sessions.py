@@ -171,11 +171,13 @@ def test_tick_opens_every_serve_span(traced):
     assert calls[spans.SERVE_ROUND] == calls[spans.SERVE_FOLD] == rounds > 0
     assert calls[spans.GATHER] == sum(d.batch_rounds for *_, d in ticks)
     assert calls[spans.PREDICT] == STEPS * SESSIONS
+    assert calls[spans.CLASSIFY] == STEPS * SESSIONS
     assert calls[spans.KERNEL] == sum(d.kernel_calls for *_, d in ticks)
     assert len({t for *_, t in host}) == 1
 
 
 @pytest.mark.parametrize("inner,outer", [
+    (spans.CLASSIFY, spans.SERVE_TICK),
     (spans.SERVE_ROUND, spans.SERVE_TICK),
     (spans.SERVE_PUBLISH, spans.SERVE_TICK),
     (spans.SERVE_FOLD, spans.SERVE_ROUND),
