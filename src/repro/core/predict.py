@@ -10,13 +10,14 @@ This module closes that gap in three pieces:
   candidate predictors run side by side: a constant-velocity linear
   extrapolation (``2·w_last − w_prev`` — exact on linear pans) and a
   few-parameter MLP over the recent normalized window deltas, trained
-  online with plain-jax SGD (no optax). Each :meth:`~ViewportPredictor
-  .observe` scores both candidates' previous predictions against the
-  window that actually arrived (IoU ≥ ``hit_iou``), and
-  :meth:`~ViewportPredictor.predict` picks by rolling hit-rate — ties
-  go to the linear baseline, so smooth pans keep the exact
-  extrapolation and the model only takes over when it demonstrably
-  outperforms it.
+  online by SGD in NumPy float32 on the host (no device op: the model
+  has 140 parameters and trains on one sample a view). Each
+  :meth:`~ViewportPredictor.observe` scores both candidates' previous
+  predictions against the window that actually arrived (IoU ≥
+  ``hit_iou``), and :meth:`~ViewportPredictor.predict` picks by
+  rolling hit-rate — ties go to the linear baseline, so smooth pans
+  keep the exact extrapolation and the model only takes over when it
+  demonstrably outperforms it.
 
 - :func:`prefetch_crack` — cracks a (predicted) window under a HARD row
   budget, reusing the heatmap query machinery end to end: classify →
@@ -45,8 +46,6 @@ import dataclasses
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.segment_agg import MAX_SEGMENTS, MAX_GROUP_CELLS
@@ -68,38 +67,37 @@ class TrajectoryStep:
 
 # ----------------------------------------------------------------- #
 # the tiny in-repo model: a few-parameter MLP over recent window
-# deltas, trained online with plain-jax SGD (no optax)
+# deltas, trained online by SGD in NumPy float32 on the host
 # ----------------------------------------------------------------- #
 
 _HIDDEN = 8
 
 
-def _mlp_init(history: int) -> Dict[str, jnp.ndarray]:
-    """Deterministic small-scale init (seeded host RNG → device)."""
+def _mlp_init(history: int) -> Dict[str, np.ndarray]:
+    """Deterministic small-scale init (seeded host RNG), float32."""
     rng = np.random.default_rng(7)
     d_in = 4 * history
     return {
-        "w1": jnp.asarray(rng.normal(0.0, 0.1, (d_in, _HIDDEN)),
-                          jnp.float32),
-        "b1": jnp.zeros(_HIDDEN, jnp.float32),
-        "w2": jnp.asarray(rng.normal(0.0, 0.1, (_HIDDEN, 4)),
-                          jnp.float32),
-        "b2": jnp.zeros(4, jnp.float32),
+        "w1": rng.normal(0.0, 0.1, (d_in, _HIDDEN)).astype(np.float32),
+        "b1": np.zeros(_HIDDEN, np.float32),
+        "w2": rng.normal(0.0, 0.1, (_HIDDEN, 4)).astype(np.float32),
+        "b2": np.zeros(4, np.float32),
     }
 
 
 def _mlp_apply(params, x):
-    h = jnp.tanh(x @ params["w1"] + params["b1"])
+    h = np.tanh(x @ params["w1"] + params["b1"])
     return h @ params["w2"] + params["b2"]
 
 
-def _mlp_loss(params, x, y):
-    return jnp.mean((_mlp_apply(params, x) - y) ** 2)
-
-
-@jax.jit
 def _sgd_step(params, x, y, lr):
-    g = jax.grad(_mlp_loss)(params, x, y)
+    """One SGD step on ``mean((out − y)²)`` for one (x, y) pair, with
+    the backward pass written out; every array stays float32."""
+    h = np.tanh(x @ params["w1"] + params["b1"])
+    out = h @ params["w2"] + params["b2"]
+    d = (out - y) * np.float32(2.0 / out.size)
+    dh = (params["w2"] @ d) * (np.float32(1.0) - h * h)
+    g = {"w1": np.outer(x, dh), "b1": dh, "w2": np.outer(h, d), "b2": d}
     return {k: params[k] - lr * g[k] for k in params}
 
 
@@ -160,20 +158,18 @@ class ViewportPredictor:
     def _features(self) -> Optional[np.ndarray]:
         """The last ``history`` window deltas, normalized by the newest
         window's span; ``None`` until the trajectory is long enough."""
-        ws = [np.asarray(s.window, np.float64) for s in self.trajectory]
-        if len(ws) < self.history + 1:
+        if len(self.trajectory) < self.history + 1:
             return None
-        deltas = [ws[i + 1] - ws[i] for i in range(len(ws) - 1)]
-        scale = self._scale(ws[-1])
-        return np.concatenate(
-            [d / scale for d in deltas[-self.history:]]).astype(np.float32)
+        ws = np.asarray([s.window for s in
+                         self.trajectory[-(self.history + 1):]], np.float64)
+        return (np.diff(ws, axis=0)
+                / self._scale(ws[-1])).reshape(-1).astype(np.float32)
 
     def _model_pred(self) -> Optional[Window]:
         x = self._features()
         if x is None:
             return None
-        d = np.asarray(_mlp_apply(self._params, jnp.asarray(x)),
-                       np.float64)
+        d = _mlp_apply(self._params, x).astype(np.float64)
         last = np.asarray(self.trajectory[-1].window, np.float64)
         p = last + d * self._scale(last)
         x0, x1 = sorted((float(p[0]), float(p[2])))
@@ -204,10 +200,9 @@ class ViewportPredictor:
             prev = np.asarray(self.trajectory[-2].window, np.float64)
             y = ((np.asarray(window, np.float64) - prev)
                  / self._scale(prev)).astype(np.float32)
-            xs, ys = jnp.asarray(x), jnp.asarray(y)
+            lr = np.float32(self.lr)
             for _ in range(self.train_steps):
-                self._params = _sgd_step(self._params, xs, ys,
-                                         jnp.float32(self.lr))
+                self._params = _sgd_step(self._params, x, y, lr)
             self.n_trained += 1
 
     def hit_rate(self, source: str) -> float:

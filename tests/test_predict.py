@@ -17,13 +17,16 @@ The load-bearing guarantees:
   machinery: per-bin budgets still met, zero speculative rows, and the
   unresolved marker is rejected if a query bypasses the engines.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import (AccuracyPolicy, AQPEngine, IndexConfig,
                         ViewportPredictor)
 from repro.core import query as query_mod
-from repro.core.predict import resolve_learned_salience
+from repro.core.predict import (_mlp_init, _sgd_step,
+                                resolve_learned_salience)
 from repro.data import make_synthetic_dataset
 
 PHI = 0.05
@@ -102,6 +105,71 @@ def test_observe_records_trajectory_and_trains_online():
     # online SGD ran once the delta history was deep enough: 6 windows
     # = 5 deltas; training needs history+1 windows for the input
     assert p.n_trained == 6 - (3 + 1)
+
+
+@pytest.mark.parametrize("history", [1, 3])
+def test_features_are_the_newest_deltas(history):
+    """The model's input is the last ``history`` deltas of the whole
+    trajectory, each normalized by the newest window's span, however
+    long the trajectory has grown."""
+    rng = np.random.default_rng(history)
+    p = ViewportPredictor(history=history)
+    for n in range(1, 40):
+        x, y = rng.uniform(0, 900, 2)
+        s = rng.uniform(20.0, 60.0)
+        p.observe((x, y, x + s, y + 0.5 * s))
+        f = p._features()
+        if n < history + 1:
+            assert f is None
+            continue
+        ws = np.array([t.window for t in p.trajectory], np.float64)
+        last = ws[-1]
+        span = np.array([last[2] - last[0], last[3] - last[1]] * 2)
+        want = ((ws[1:] - ws[:-1])[-history:] / span).reshape(-1)
+        assert f.dtype == np.float32
+        np.testing.assert_array_equal(f, want.astype(np.float32))
+
+
+def test_host_sgd_step_matches_jax_grad():
+    """The hand-written backward pass is the gradient of
+    ``mean((out − y)²)``: 200 seeded random steps from the predictor's
+    init track a ``jax.grad`` reference of the same loss, and every
+    parameter stays float32."""
+    def loss(params, x, y):
+        h = jnp.tanh(x @ params["w1"] + params["b1"])
+        return jnp.mean((h @ params["w2"] + params["b2"] - y) ** 2)
+
+    @jax.jit
+    def ref_step(params, x, y, lr):
+        g = jax.grad(loss)(params, x, y)
+        return {k: params[k] - lr * g[k] for k in params}
+
+    rng = np.random.default_rng(11)
+    host = _mlp_init(3)
+    ref = {k: jnp.asarray(v) for k, v in host.items()}
+    lr = np.float32(0.1)
+    for _ in range(200):
+        x = rng.normal(0.0, 1.0, 12).astype(np.float32)
+        y = rng.normal(0.0, 0.5, 4).astype(np.float32)
+        host = _sgd_step(host, x, y, lr)
+        ref = ref_step(ref, x, y, lr)
+        for k in host:
+            assert host[k].dtype == np.float32, k
+            np.testing.assert_allclose(host[k], np.asarray(ref[k]),
+                                       rtol=0, atol=1e-6, err_msg=k)
+
+
+def test_predictor_touches_no_device():
+    """Init, 30 observations on a curved path and a prediction make no
+    host↔device transfer; the model's parameters are host arrays."""
+    with jax.transfer_guard("disallow"):
+        p = ViewportPredictor(history=3)
+        for i in range(30):
+            x, y = 100.0 + 12.0 * i, 150.0 + 0.8 * i * i
+            p.observe((x, y, x + 200.0, y + 200.0), bins=(4, 4))
+        assert p.predict() is not None
+    assert p.n_trained == 30 - (3 + 1)
+    assert all(type(v) is np.ndarray for v in p._params.values())
 
 
 def test_salience_map_dwell_histogram_properties():
